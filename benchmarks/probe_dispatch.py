@@ -130,7 +130,7 @@ def run_probe(nranks: int = 8, reps: int = 20) -> Dict:
         "device_us": dev["lat_us"],
         "host_us": host["lat_us"],
         # the per-op dispatch constant: smallest-payload device
-        # latency (the op itself is ~free there — BENCH_NOTES r5)
+        # latency (the op itself is ~free there)
         "dispatch_us": {k: dev["lat_us"][k][str(SIZES[0])]
                         for k in dev["lat_us"]},
         "crossover_bytes": {k: _crossover(dev["lat_us"][k],

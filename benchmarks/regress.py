@@ -129,8 +129,8 @@ def _json_lines(text: str):
 
 #: sanity bound on the device sweep's measured d2h read constant.  An
 #: idle box reads 4 bytes in tens of microseconds; ~100 ms means the
-#: quiet gate failed (polling peers / tunnel threads contaminated the
-#: probe — the r4 failure mode) and the constant-subtraction then
+#: quiet gate failed (polling peers or runtime threads contaminated
+#: the probe — the r4 failure mode) and the constant-subtraction then
 #: FABRICATES busbw.  Rounds in that state are not comparable.
 READ_CONST_SANE_US = 5000.0
 

@@ -6,10 +6,11 @@ thread — the deployment that makes coll/tpu reachable from mpirun):
     python -m ompi_tpu.tools.mpirun -np 8 --ranks-per-proc all \
         examples/device_allreduce.py
 
-Each rank allreduces / reduce-scatters a device-resident array via
-XLA mesh collectives, then rank 0 prints the coll/tpu offload pvar —
-which must be > 0, proving the collectives ran as compiled HLO over
-the mesh instead of the host-staged p2p fallback.
+Each rank allreduces / reduce-scatters a device-resident array, then
+rank 0 prints the engagement counters: the collectives must have been
+served by a device module — coll/tpu (one rank per device, XLA mesh
+collectives) or coll/hbm (ranks sharing one chip, stacked kernels) —
+and none by the host-staged arr_host fallback.
 """
 import numpy as np
 
@@ -36,18 +37,22 @@ sub = comm.split(rank % 2)
 sr = sub.allreduce_arr(x, mpi_op.MAX)
 assert float(np.asarray(sr)[0]) == float(size - 2 + (rank % 2) + 1)
 
-offloaded = 0
-for pv in registry.all_pvars():
-    if pv.full_name == "coll_tpu_offloaded_collectives":
-        offloaded = pv.read()
+comm.Barrier()  # every rank's collectives are counted
+pv = {p.full_name: p.read() for p in registry.all_pvars()}
+tpu = pv.get("coll_tpu_offloaded_collectives", 0)
+hbm = pv.get("coll_hbm_offloaded_collectives", 0)
+staged = pv.get("coll_arr_host_staged_collectives", 0)
 # one atomic write per line: every rank is a thread of ONE app-shell
 # process, and print()'s separate text/newline writes interleave
 # across ranks on the shared stdout
 import sys
 if rank == 0:
-    sys.stdout.write(f"coll_tpu_offloaded_collectives={offloaded}\n")
+    sys.stdout.write(f"coll_tpu_offloaded_collectives={tpu}\n"
+                     f"coll_hbm_offloaded_collectives={hbm}\n"
+                     f"coll_arr_host_staged_collectives={staged}\n")
     sys.stdout.flush()
-    assert offloaded > 0, "device collectives were not offloaded!"
+    assert tpu + hbm > 0, "device collectives were not offloaded!"
+    assert staged == 0, "device collectives were staged through the host!"
 sys.stdout.write(f"rank {rank} ok\n")
 sys.stdout.flush()
 ompi_tpu.finalize()

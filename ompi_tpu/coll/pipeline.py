@@ -196,8 +196,6 @@ def _build_seg_kernel(kind: str, mesh, seg_elems: int, dtype,
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ompi_tpu.coll import device
-
     size = mesh.devices.size
     ring = [(j, (j + 1) % size) for j in range(size)]
 
@@ -300,7 +298,8 @@ def _build_seg_kernel(kind: str, mesh, seg_elems: int, dtype,
     else:
         raise KeyError(kind)
 
-    return jax.jit(device.shard_map_compat(body, mesh, in_specs, out_specs))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 # ---------------------------------------------------------------------------

@@ -262,7 +262,6 @@ def _compile_mesh(alg: str, mesh, size: int, nsegs: int, seg: int,
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from ompi_tpu.coll import device
 
     binop = _pl._binop(opname)
     if native:
@@ -317,7 +316,8 @@ def _compile_mesh(alg: str, mesh, size: int, nsegs: int, seg: int,
                 s <<= 1
             return acc
 
-    fn = device.shard_map_compat(body, mesh, P("r"), P(None))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("r"),
+                       out_specs=P(None), check_vma=False)
     if donate:
         return jax.jit(fn, donate_argnums=(0,))
     return jax.jit(fn)

@@ -1,15 +1,18 @@
 """Loader for the native C++ data plane (ctypes; no pybind11).
 
-Builds native/libtpumpi_native.so with make on first use when the
-toolchain is present; every consumer has a pure-Python fallback, so
-a missing compiler only costs performance, never correctness.
+Builds native/libtpumpi_native.so with make on first use; make itself
+decides what is stale against the tracked sources.  Every consumer has
+a pure-Python fallback, so a missing compiler only costs performance,
+never correctness — but it says so on stderr, once.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional
 
@@ -24,11 +27,21 @@ _tried = False
 
 def _build() -> bool:
     try:
-        r = subprocess.run(["make", "-C", _NATIVE_DIR, "-j2"],
-                           capture_output=True, timeout=120)
-        return r.returncode == 0 and os.path.exists(_LIB_PATH)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+        # process-ranks of one job all reach here at once on a fresh
+        # checkout: one builds, the rest find nothing to do
+        with open(os.path.join(_NATIVE_DIR, "Makefile")) as lockf:
+            fcntl.flock(lockf, fcntl.LOCK_EX)
+            r = subprocess.run(["make", "-C", _NATIVE_DIR, "-j2"],
+                               capture_output=True, timeout=120)
+        why = r.stderr.decode(errors="replace").strip()[-400:]
+        ok = r.returncode == 0 and os.path.exists(_LIB_PATH)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        ok, why = False, repr(e)
+    if not ok:
+        sys.stderr.write(
+            f"ompi_tpu.native: build of {_LIB_PATH} failed ({why}); "
+            f"using the pure-Python fallback\n")
+    return ok
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -38,17 +51,14 @@ def load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        stale = True
-        if os.path.exists(_LIB_PATH):
-            lib_mtime = os.path.getmtime(_LIB_PATH)
-            stale = any(
-                os.path.getmtime(os.path.join(_NATIVE_DIR, f)) > lib_mtime
-                for f in os.listdir(_NATIVE_DIR) if f.endswith(".cpp"))
-        if stale and not _build():
+        if not _build():
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
+        except OSError as e:
+            sys.stderr.write(
+                f"ompi_tpu.native: cannot load {_LIB_PATH} ({e}); "
+                f"using the pure-Python fallback\n")
             return None
         u8p = ctypes.POINTER(ctypes.c_uint8)
         lib.tpumpi_ring_push.argtypes = [u8p, ctypes.c_uint64, u8p,

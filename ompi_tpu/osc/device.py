@@ -174,8 +174,9 @@ class _ShardTable:
 
 
 def _shmap(body, mesh, in_specs, out_specs):
-    from ompi_tpu.coll import device as _dc
-    return _dc.shard_map_compat(body, mesh, in_specs, out_specs)
+    import jax
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _build_put(mesh, cap: int, b: int, o: int, t: int):

@@ -62,6 +62,9 @@ def run_ranks(n: int, fn: Callable, devices: bool = False,
     devs = None
     if devices or device_map is not None:
         import jax
+
+        from ompi_tpu.runtime import jaxcache
+        jaxcache.enable()
         devs = jax.devices()
     respawn_cv = threading.Condition()
     respawn_q: List[int] = []  # killed ranks awaiting replacement

@@ -3378,9 +3378,9 @@ def serve(opts) -> int:
     devices = None
     if opts.devices != "none":
         import jax
-        if os.environ.get("JAX_PLATFORMS"):
-            jax.config.update("jax_platforms",
-                              os.environ["JAX_PLATFORMS"])
+
+        from ompi_tpu.runtime import jaxcache
+        jaxcache.enable()
         devices = jax.devices()  # PJRT bring-up happens HERE, once
     server = DVMServer(opts.np, devices=devices,
                        uri_file=opts.uri_file,

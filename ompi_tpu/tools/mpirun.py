@@ -217,6 +217,24 @@ def _errmgr_table(sm: smx.StateMachine, drain) -> None:
     })
 
 
+def _second_shell_refusal(opts, where: str, shells: int) -> Optional[str]:
+    """One process per chip host: the XLA runtime hands a host's chips
+    to the first process that asks, so a second device-owning app shell
+    on the same host fails or hangs at jax.devices().  Returns the
+    refusal message when ``shells`` app shells on ``where`` would each
+    want the chips; None when the split is harmless (no devices asked
+    for, or JAX held to the CPU, where every process gets its own)."""
+    if shells <= 1 or opts.devices == "none" \
+            or os.environ.get("JAX_PLATFORMS") == "cpu":
+        return None
+    return (f"--ranks-per-proc {opts.rpp} would start {shells} "
+            f"app shells on {where}, and each would claim its chips "
+            f"(--devices {opts.devices}); an accelerator belongs to one "
+            f"process at a time, so the second shell would fail or "
+            f"hang.  Use --ranks-per-proc all (one shell per host), or "
+            f"--devices none for host-only ranks")
+
+
 def run_multinode(opts, nodes, rpp: int, hybrid: bool) -> int:
     """The PLM path: per-node daemons, rmaps job map, tree launch —
     sequenced by the hnp-role state machine."""
@@ -243,6 +261,14 @@ def run_multinode(opts, nodes, rpp: int, hybrid: bool) -> int:
         except ValueError as e:
             sm.activate(smx.LAUNCH_FAILED, msg=str(e), code=2)
             return
+        for m in d["maps"]:
+            # simulated nodes are pinned to a CPU mesh of their own
+            why = None if m.node.simulated else _second_shell_refusal(
+                opts, f"node {m.node.name}",
+                sum(1 for p in m.procs if p.nlocal))
+            if why:
+                sm.activate(smx.LAUNCH_FAILED, msg=why, code=2)
+                return
         sm.activate(smx.LAUNCH_DAEMONS)
 
     def on_launch_daemons(sm, info):
@@ -1064,6 +1090,12 @@ def main(argv: Optional[List[str]] = None) -> int:
     # only the implicit local default uses the direct fork/exec path
     explicit = any(x is not None for x in (opts.hosts, opts.hostfile,
                                            opts.simulate))
+
+    if hybrid and not explicit:
+        why = _second_shell_refusal(opts, "this host", -(-opts.np // rpp))
+        if why:
+            sys.stderr.write(f"mpirun: {why}\n")
+            return 2
 
     def run_once() -> int:
         if explicit:
