@@ -57,8 +57,22 @@ _NAME_SEG_MEET = _trace.NAME_SEG_MEET
 _CAT_PHASE = _trace.CAT_PHASE
 _NAME_PH_RDV = _trace.NAME_PH_RDV
 _NAME_PH_DISPATCH = _trace.NAME_PH_DISPATCH
-_NAME_PH_EXECUTE = _trace.NAME_PH_EXECUTE
+_NAME_PH_ENTRY = _trace.NAME_PH_ENTRY
+_NAME_PH_ASSEMBLE = _trace.NAME_PH_ASSEMBLE
+_NAME_PH_LAUNCH = _trace.NAME_PH_LAUNCH
+_NAME_PH_SCATTER = _trace.NAME_PH_SCATTER
 _HIST_RDV = _trace.HIST_RDV_WAIT
+_L_ENTRY = _trace.L_ENTRY
+_L_RDV_SLOT = _trace.L_RDV_SLOT
+_L_RDV_SKEW = _trace.L_RDV_SKEW
+_L_RDV_SERVE = _trace.L_RDV_SERVE
+_L_RDV_WAKE = _trace.L_RDV_WAKE
+_L_EXIT = _trace.L_EXIT
+_L_ASSEMBLE = _trace.L_ASSEMBLE
+_L_LAUNCH = _trace.L_LAUNCH
+_L_SCATTER = _trace.L_SCATTER
+_L_RENDEZVOUS = _trace.L_RENDEZVOUS
+_now = time.perf_counter_ns
 
 _prio_tpu = registry.register(
     "coll", "tpu", "priority", 80, int,
@@ -351,61 +365,97 @@ def _sever_hold(abort_check) -> None:
 
 
 # -- phase profiler helpers (docs/DESIGN.md §18) ----------------------------
-# A "ph ctx" is the tuple (tracer, cid, seq, nbytes) a traced op builds
-# ONCE — only when tracer.phase is armed (the zero-cost-when-off gate
-# everywhere else is a single attribute check) AND the op samples IN
-# through the phase category (Tracer.gate_sampled at the build site:
-# armed-but-sampled-out costs the same two list ops as an unsampled
-# dispatch span and takes the exact ph=None path) — and threads
-# through the rendezvous so the waits, the dispatch, and the fenced
-# device execute decompose the op span into named phases.  The GATE
-# carries the sampling bookkeeping; a non-None ctx means every
-# sub-span records, so one op's decomposition is always coherent
-# (never a dispatch span whose execute sampled out) and the exactness
-# invariant (kept + sampled_out == seen) holds per category at op
-# granularity.
+# A "ph ctx" is the tuple (tracer, cid, seq, nbytes, kept) a traced op
+# builds ONCE, only when tracer.phase is armed (the zero-cost-when-off
+# gate everywhere else is a single attribute check).  ``seq`` is the
+# communicator's collective sequence number, the key of the
+# operation's coll span.  EVERY operation carries a ctx then: the
+# layer accumulators (trace.LAYERS) bank every boundary of every
+# operation.  ``kept`` (Tracer.keep on the sequence number, so the
+# same on every member) says whether the operation also writes its
+# phase spans, all of them or none, so one op's decomposition is
+# always coherent and whole on every rank.  Nothing here waits for the
+# device: a traced operation differs from an untraced one by clock
+# reads and ring stores.
 
-def _ph_rdv_start(ph):
-    """Open a rendezvous-wait phase span (0 when the ctx is absent —
-    a present ctx already sampled in at build time)."""
-    if ph is None:
-        return 0
-    return ph[0].start()
-
-
-def _ph_rdv_end(ph, t0) -> None:
-    """Close a rendezvous-wait phase span and feed the straggler-skew
-    histogram (rdv_wait is the one phase with its own gauge — it IS
-    the cross-rank skew signal)."""
-    tr = ph[0]
-    dur = tr.end(t0, _NAME_PH_RDV, _CAT_PHASE, ph[1], ph[2], ph[3])
-    tr.hist_add(_HIST_RDV, dur * 1e-9)
+def _pub_span(ph, direct: bool, name_id: int, t0: int, t1: int) -> None:
+    """A span of the publisher's work on a kept op, against the
+    triggering rank's tracer: stored straight when this thread owns
+    the tracer (the last arriver, inline), else handed to the owner
+    (the dispatcher thread; Tracer.file_done), so the ring keeps one
+    writer."""
+    if direct:
+        ph[0].end_at(t0, t1, name_id, _CAT_PHASE, ph[1], ph[2], ph[3])
+    else:
+        ph[0]._done.append((t0, t1, name_id, ph[1], ph[2], ph[3]))
 
 
-def _phase_fn(fn, shards, ph):
-    """Run a meeting's computation with dispatch/execute phases
-    recorded against the triggering rank's tracer.  The execute fence
-    (block_until_ready) runs ONLY for a sampled-in op (ph non-None)
-    — a sampled-out op keeps XLA's async dispatch untouched."""
+def _phase_fn(fn, shards, ph, direct: bool = True):
+    """Run a meeting's computation.  Untraced (``ph`` None) that is
+    ``fn(shards)`` and nothing else.  With a ctx, a computation that
+    brought a traced twin (``fn.traced``: the compiled plans and the
+    one-chip kernels attach one where they are built) runs that
+    instead, and its steps bank against the triggering rank's tracer;
+    a kept op records ph_dispatch around it.  The publisher never
+    waits for the device."""
     if ph is None:
         return fn(shards)
-    tr = ph[0]
-    t0 = tr.start()
-    res = fn(shards)
-    tr.end(t0, _NAME_PH_DISPATCH, _CAT_PHASE, ph[1], ph[2], ph[3])
-    t1 = tr.start()
-    _block_ready(res)
-    tr.end(t1, _NAME_PH_EXECUTE, _CAT_PHASE, ph[1], ph[2], ph[3])
+    t0 = _now()
+    tfn = getattr(fn, "traced", None)
+    res = fn(shards) if tfn is None else tfn(shards, ph, direct)
+    if ph[4]:
+        _pub_span(ph, direct, _NAME_PH_DISPATCH, t0, _now())
     return res
 
 
-def _block_ready(res) -> None:
-    """Fence a dispatched computation to device completion (the
-    device-execute phase boundary).  Non-jax leaves (host fallback
-    payloads) are skipped by jax itself — a zero-length execute span;
-    a device failure raises into the meeting's error path."""
-    import jax
-    jax.block_until_ready(res)
+def _mesh_exec(mesh, size: int, jfn, sharding, shards: List, ph,
+               direct: bool) -> List:
+    """The traced twin of a compiled mesh plan's computation: assemble
+    the global array, call the compiled collective, split the output
+    per rank; each step banks its accumulator against the publisher's
+    ctx and records its span on a kept op."""
+    lns = ph[0]._lns
+    t0 = _now()
+    g = _assemble(mesh, shards, sharding)
+    t1 = _now()
+    out = jfn(g)
+    t2 = _now()
+    parts = _scatter_out(out, mesh, size)
+    t3 = _now()
+    lns[_L_ASSEMBLE] += t1 - t0
+    lns[_L_LAUNCH] += t2 - t1
+    lns[_L_SCATTER] += t3 - t2
+    if ph[4]:
+        _pub_span(ph, direct, _NAME_PH_ASSEMBLE, t0, t1)
+        _pub_span(ph, direct, _NAME_PH_LAUNCH, t1, t2)
+        _pub_span(ph, direct, _NAME_PH_SCATTER, t2, t3)
+    return parts
+
+
+def _stacked_exec(jbody, out_map, n: int, shards: List, ph,
+                  direct: bool) -> List:
+    """The traced twin of a one-chip meeting's computation: the stacked
+    kernel, then the per-rank split of its result (``out(r, n)``),
+    with the same accounting as _mesh_exec (nothing to assemble: the
+    shards are the kernel's arguments)."""
+    lns = ph[0]._lns
+    t0 = _now()
+    r = jbody(*shards)
+    t1 = _now()
+    parts = out_map(r, n)
+    t2 = _now()
+    lns[_L_LAUNCH] += t1 - t0
+    lns[_L_SCATTER] += t2 - t1
+    if ph[4]:
+        if direct:
+            # the last arriver, under the meeting's lock: one store call
+            ph[0].end_at2(t0, t1, _NAME_PH_LAUNCH, _CAT_PHASE,
+                          t1, t2, _NAME_PH_SCATTER, _CAT_PHASE,
+                          ph[1], ph[2], ph[3])
+        else:
+            _pub_span(ph, False, _NAME_PH_LAUNCH, t0, t1)
+            _pub_span(ph, False, _NAME_PH_SCATTER, t1, t2)
+    return parts
 
 
 class Rendezvous:
@@ -427,6 +477,11 @@ class Rendezvous:
         self.errors: Dict[int, BaseException] = {}
         self.readers: Dict[int, int] = {}
         self._progs: Dict[int, Any] = {}  # rank -> Progress (wake targets)
+        # per-generation stamps of the phase profiler (perf_counter_ns;
+        # set only by a traced publisher, dropped with the results):
+        # when the meeting became full, when its results were published
+        self.t_full: Dict[int, int] = {}
+        self.t_rel: Dict[int, int] = {}
 
     def _wait_for(self, cond, what: str, abort_check, progress) -> None:
         """Wait (cv held on entry and exit) until cond() holds.  Polls
@@ -522,14 +577,29 @@ class Rendezvous:
             self._progs[rank] = progress
         if dispatch_async is None:
             dispatch_async = _dispatcher_var.value
+        ta = td = 0
+        handed = False
+        if ph is not None:
+            # layer account (trace.LAYERS; inline: this runs on every
+            # operation of every rank): the interval before the
+            # rendezvous (entry, or exit after a collect) ends, the
+            # wait for the slot and the meeting's lock starts
+            tr = ph[0]
+            lns = tr._lns
+            c = tr._t_cur
+            ta = _now()
+            if c:
+                lns[tr._cur_k] += ta - c
         with self.cv:
             # wait until my slot from the previous generation is consumed
-            tw = _ph_rdv_start(ph)
             self._wait_for(lambda: self.slots[rank] is self._SENTINEL,
                            "previous generation unconsumed",
                            abort_check, progress)
-            if tw:
-                _ph_rdv_end(ph, tw)
+            if ta:
+                # one clock read under the meeting's lock; the banking
+                # and the spans wait until it is released (every
+                # microsecond held here is one the next member waits)
+                td = _now()
             gen = self.gen
             self.slots[rank] = value
             self.count += 1
@@ -538,6 +608,8 @@ class Rendezvous:
                 self.count = 0
                 self.slots = [self._SENTINEL] * self.size
                 self.gen += 1
+                if td:
+                    self.t_full[gen] = td
                 if dispatch_async:
                     # hand the computation to the process-wide
                     # dispatcher thread; members park (or pipeline)
@@ -546,7 +618,7 @@ class Rendezvous:
 
                     def work() -> None:
                         try:
-                            res = _phase_fn(fn, shards, ph)
+                            res = _phase_fn(fn, shards, ph, False)
                             err = None
                         except BaseException as e:  # noqa: BLE001
                             res = [None] * rv.size
@@ -556,6 +628,8 @@ class Rendezvous:
                                 rv.errors[gen] = err
                             rv.results[gen] = res
                             rv.readers[gen] = rv.size
+                            if td:
+                                rv.t_rel[gen] = _now()
                             rv.cv.notify_all()
                             progs = list(rv._progs.items())
                         # wake members parked on their progress idle
@@ -564,6 +638,7 @@ class Rendezvous:
                             prog.wakeup()
 
                     _dispatcher().submit(work)
+                    handed = True
                 else:
                     # last arriver computes inline (under the cv, as
                     # before the r5 dispatcher experiment)
@@ -573,10 +648,40 @@ class Rendezvous:
                         self.errors[gen] = e
                         self.results[gen] = [None] * self.size
                     self.readers[gen] = self.size
+                    if td:
+                        # published: what follows (notify, doorbells,
+                        # the lock and the GIL changing hands) is the
+                        # hand-off, every member's rdv_wake
+                        self.t_rel[gen] = _now()
                     self.cv.notify_all()
                     for r, prog in self._progs.items():
                         if r != rank:
                             prog.wakeup()
+        if td:
+            if c:
+                # deposited at td: the wait for the slot, one more
+                # rendezvous; the rank's skew starts at td
+                lns[_L_RDV_SLOT] += td - ta
+                lns[_L_RENDEZVOUS] += 1
+                tr._t_cur = td
+                tr._cur_k = _L_ENTRY
+                if handed:
+                    # handing the computation over (and letting go of
+                    # the lock) is the triggering rank's share of the
+                    # serve
+                    tr.lap_to(_L_RDV_SERVE, _L_ENTRY)
+            if ph[4]:
+                # kept: ph_entry (the last boundary before, shim entry
+                # or the end of a pack, to the rendezvous) and the
+                # slot-side ph_rdv_wait, which also feeds the
+                # straggler-skew histogram
+                if c:
+                    tr.end_at2(c, ta, _NAME_PH_ENTRY, _CAT_PHASE,
+                               ta, td, _NAME_PH_RDV, _CAT_PHASE,
+                               ph[1], ph[2], ph[3], _HIST_RDV)
+                else:
+                    tr.end_at(ta, td, _NAME_PH_RDV, _CAT_PHASE,
+                              ph[1], ph[2], ph[3], 0, 0, _HIST_RDV)
         return gen
 
     def finish(self, rank: int, gen: int,
@@ -587,23 +692,63 @@ class Rendezvous:
         from ``begin``).  Each member must finish every generation it
         begins, exactly once — results are refcounted away after the
         last reader."""
+        tf = _now() if ph is not None and ph[4] else 0
         with self.cv:
-            tw = _ph_rdv_start(ph)
             self._wait_for(lambda: gen in self.results,
                            f"waiting for peers (gen {gen})",
                            abort_check, progress)
-            if tw:
-                _ph_rdv_end(ph, tw)
+            if ph is not None:
+                # running again: the reading and the generation's two
+                # stamps under the lock, the arithmetic after it
+                now = _now()
+                t_full = self.t_full.get(gen, 0)
+                t_rel = self.t_rel.get(gen, 0)
             err = self.errors.get(gen)
             out = self.results[gen][rank]
             self.readers[gen] -= 1
             if self.readers[gen] == 0:
                 del self.results[gen], self.readers[gen]
                 self.errors.pop(gen, None)
-            if err is not None:
-                raise RuntimeError(
-                    f"device collective failed on a peer: {err}") from err
-            return out
+                self.t_full.pop(gen, None)
+                self.t_rel.pop(gen, None)
+        if ph is not None:
+            tr = ph[0]
+            c = tr._t_cur
+            if c:
+                # the time since the last boundary (the deposit, or
+                # what the rank did since) splits at the generation's
+                # two stamps, clamped into it: until the meeting was
+                # full (skew), until the results were published
+                # (serve), until running again (wake)
+                if t_full < c:
+                    t_full = c
+                elif t_full > now:
+                    t_full = now
+                if t_rel < t_full:
+                    t_rel = t_full
+                elif t_rel > now:
+                    t_rel = now
+                lns = tr._lns
+                lns[_L_RDV_SKEW] += t_full - c
+                lns[_L_RDV_SERVE] += t_rel - t_full
+                lns[_L_RDV_WAKE] += now - t_rel
+                tr._t_cur = now
+                tr._cur_k = _L_EXIT
+            if tf:
+                # kept: the collect-side ph_rdv_wait, from the entry of
+                # finish to running again, with how the wait splits in
+                # its two free columns (skew_ns until the meeting was
+                # full, wake_ns from the publish to now); it feeds the
+                # straggler-skew histogram (rdv_wait IS the cross-rank
+                # skew signal)
+                sk = (t_full if t_full < now else now) - tf
+                tr.end_at(tf, now, _NAME_PH_RDV, _CAT_PHASE,
+                          ph[1], ph[2], ph[3], sk if sk > 0 else 0,
+                          now - (t_rel if t_rel > tf else tf), _HIST_RDV)
+        if err is not None:
+            raise RuntimeError(
+                f"device collective failed on a peer: {err}") from err
+        return out
 
     def run(self, rank: int, value: Any, fn: Callable[[List[Any]], List[Any]],
             abort_check: Optional[Callable[[], None]] = None,
@@ -638,7 +783,7 @@ class Rendezvous:
                 self.cv.release()
 
 
-def meet(comm, value, fn, abort_check, ck=None) -> Any:
+def meet(comm, value, fn, abort_check, ck=None, account=True) -> Any:
     """The one rendezvous entry point for offloaded collectives:
     reports the bypassed traffic to pml/monitoring (the offload fast
     paths must not blind the observability story), then runs the
@@ -647,7 +792,12 @@ def meet(comm, value, fn, abort_check, ck=None) -> Any:
     the plane is armed and the op is algebraically checkable — the
     sampled gate may then wrap (value, fn) in a digest-carrying pair.
     The spec depends only on (kind, op, dtype), so every rank passes
-    the same ck and the comm-consistent sampling invariant holds."""
+    the same ck and the comm-consistent sampling invariant holds.
+    ``account=False`` (coll/sm's host-buffer collectives, which borrow
+    this meeting point) keeps the operation out of the phase
+    profiler's layer account: that account is of device collectives,
+    and a job's own barriers around a measured region must not add to
+    it."""
     rv = _get_rendezvous(comm)
     track_state(comm.state)
     inj = _coll_delay_injector(comm.state)
@@ -673,41 +823,42 @@ def meet(comm, value, fn, abort_check, ck=None) -> Any:
         return rv.run(comm.rank, value, fn, abort_check,
                       progress=comm.state.progress)
     # dispatch span: entry->rendezvous-release of the device fast path
-    # (cat coll_dispatch feeds the dispatch-latency histogram); the
-    # per-comm sequence number is the straggler correlation key
+    # (cat coll_dispatch feeds the dispatch-latency histogram).  ``seq``
+    # (one per rendezvous) keys the span; ``op``, the communicator's
+    # collective sequence, decides keep-or-skip for the span and for
+    # the operation's phases, the same on every member
     seq = comm._dev_seq
     comm._dev_seq = seq + 1
-    # inlined start_sampled skip branch (the steady-state common case;
-    # see trace.coll_begin) — the sampled-out cost of the dispatch
-    # span is two list ops, no method call, no clock read
-    ctr = tr._ctr
-    c = ctr[_CAT_DISP]
-    if c:
-        ctr[_CAT_DISP] = c - 1
+    op = comm._coll_seq
+    # Tracer.keep, inlined (the sampled-out steady state makes no call
+    # and reads no clock)
+    if not tr._plo <= op < tr._phi:
+        tr._restep(op)
+    per = tr._period
+    if op % per[_CAT_DISP]:
         tr._skipped[_CAT_DISP] += 1
         t0 = 0
     else:
-        t0 = tr.start_sampled(_CAT_DISP)
+        t0 = _now()
     # phase ctx (docs/DESIGN.md §18): one tuple per op ONLY when the
-    # profiler is armed AND this op samples in — off, a single
-    # attribute check; armed-but-sampled-out, the same inlined
-    # two-list-op skip as the dispatch span above
+    # profiler is armed; off, a single attribute check
     ph = None
     if tr.phase:
-        c = ctr[_CAT_PHASE]
-        if c:
-            ctr[_CAT_PHASE] = c - 1
+        if op % per[_CAT_PHASE]:
             tr._skipped[_CAT_PHASE] += 1
-        elif tr.gate_sampled(_CAT_PHASE):
-            ph = (tr, comm.cid, seq, nbytes)
+            ph = (tr, comm.cid, op, nbytes, False)
+        else:
+            ph = (tr, comm.cid, op, nbytes, True)
+        if not account:
+            tr._t_cur = 0   # no open cursor: its boundaries bank nothing
     out = rv.run(comm.rank, value, fn, abort_check,
                  progress=comm.state.progress, ph=ph)
     if t0:
-        tr.end(t0, _NAME_MEET, _CAT_DISP, comm.cid, seq, nbytes)
+        tr.end(t0, _NAME_MEET, _CAT_DISP, comm.cid, seq, nbytes, op)
     return out
 
 
-def meet_begin(comm, value, fn, abort_check, ck=None):
+def meet_begin(comm, value, fn, abort_check, ck=None, ph=None):
     """Asynchronous rendezvous entry: deposit and return a handle
     without waiting for the result.  The last arriver's computation
     always runs on the dispatcher thread, so the caller's thread is
@@ -715,7 +866,9 @@ def meet_begin(comm, value, fn, abort_check, ck=None):
     — the overlap the segmented pipeline is built on.  Collect with
     ``meet_finish``; every begun handle MUST be finished (results are
     refcounted per generation).  ``ck`` is the integrity check spec,
-    exactly as in ``meet``."""
+    exactly as in ``meet``; ``ph`` is the OPERATION's phase ctx (the
+    pipeline builds one for all its segments, so a kept operation
+    keeps every segment's phases)."""
     rv = _get_rendezvous(comm)
     track_state(comm.state)
     inj = _coll_delay_injector(comm.state)
@@ -738,14 +891,14 @@ def meet_begin(comm, value, fn, abort_check, ck=None):
         value = _ig.flip_value(value)
     tr = comm.state.tracer
     t0 = 0
-    ph = None
     if tr is not None:
-        t0 = tr.start_sampled(_CAT_SEG)
-        if tr.phase and tr.gate_sampled(_CAT_PHASE):
-            # the final seq is assigned at meet_finish; the CURRENT
-            # _dev_seq is close enough for critpath's containment-
-            # based attribution (exact keys ride the seg_meet span)
-            ph = (tr, comm.cid, comm._dev_seq, nbytes)
+        op = comm._coll_seq
+        if not tr._plo <= op < tr._phi:
+            tr._restep(op)
+        if op % tr._period[_CAT_SEG]:
+            tr._skipped[_CAT_SEG] += 1
+        else:
+            t0 = _now()
     gen = rv.begin(comm.rank, value, fn, abort_check,
                    progress=comm.state.progress, dispatch_async=True,
                    ph=ph)
@@ -766,7 +919,8 @@ def meet_finish(comm, handle, abort_check) -> Any:
         seq = comm._dev_seq
         comm._dev_seq = seq + 1
         if t0:
-            tr.end(t0, _NAME_SEG_MEET, _CAT_SEG, comm.cid, seq, nbytes)
+            tr.end(t0, _NAME_SEG_MEET, _CAT_SEG, comm.cid, seq, nbytes,
+                   comm._coll_seq)
     return out
 
 
@@ -1433,6 +1587,9 @@ class HbmCollModule(CollModule):
             def fn(shards, _j=jbody, _o=out, _n=size):
                 return _o(_j(*shards), _n)
 
+            # the phase profiler's twin (_phase_fn), built once with
+            # the plan: the untraced body above is what runs otherwise
+            fn.traced = functools.partial(_stacked_exec, jbody, out, size)
             plans[pkey] = fn
         ck = _ig.spec(_CK_KINDS.get(kind, kind), opname, x) \
             if _ig.on else None

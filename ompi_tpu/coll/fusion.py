@@ -518,7 +518,18 @@ class _FusionEngine:
             return
         batch, self.pending = self.pending, []
         tr = self.comm.state.tracer
-        t0 = tr.start_sampled(_trace.CAT_COLL) if tr is not None else 0
+        t0 = 0
+        if tr is not None:
+            # a flush is an operation of its own: members flush in
+            # lockstep (each flush is one rendezvous), so it ticks the
+            # communicator's sequence number itself (a FusedRequest,
+            # unlike an NBCRequest, draws none) and its spans (this
+            # one, the pack, the meet and its phases) are kept or
+            # skipped on it, the same on every member, under a key
+            # critpath can tell from the next flush's
+            seq = _trace.coll_seq(self.comm)
+            if tr.keep(_trace.CAT_COLL, seq):
+                t0 = tr.start()
         try:
             outs = self._run(batch)
         except BaseException as e:  # noqa: BLE001
@@ -527,7 +538,7 @@ class _FusionEngine:
             raise
         if t0:
             tr.end(t0, _trace.NAME_FUSED_FLUSH, _trace.CAT_COLL,
-                   self.comm.cid, len(batch))
+                   self.comm.cid, len(batch), seq)
         nbytes = 0
         for p, out in zip(batch, outs):
             nbytes += p.nbytes
@@ -550,12 +561,13 @@ class _FusionEngine:
 
         comm = self.comm
         tr = comm.state.tracer
-        t0 = tr.start_sampled(_trace.CAT_COLL) if tr is not None else 0
+        seq = comm._coll_seq     # the flush's own (flush ticked it)
+        t0 = tr.start() if tr is not None and tr.keep(
+            _trace.CAT_COLL, seq) else 0
         # phase profiler (docs/DESIGN.md §18): the fused pack is the
-        # host-pack phase of the op the following meet() dispatches —
-        # comm._dev_seq is exactly the seq that meet will record
-        tp = tr.start_sampled(_trace.CAT_PHASE) \
-            if tr is not None and tr.phase else 0
+        # host-pack phase of the op the following meet() dispatches,
+        # which decides on the same sequence number
+        tp = tr.lap() if tr is not None and tr.phase else 0
         mesh = comm.mesh()
         my_dev = mesh.devices.reshape(-1)[comm.rank]
         groups, folds = _group_plan(sig)
@@ -577,8 +589,10 @@ class _FusionEngine:
                                         for a in args]))
         deposit.extend(batch[i].x for i in folds)
         if tp:
-            tr.end(tp, _trace.NAME_PH_PACK, _trace.CAT_PHASE,
-                   comm.cid, comm._dev_seq, 0)
+            t1 = tr.lap_to(_trace.L_PACK, _trace.L_ENTRY)
+            if tr.kept(_trace.CAT_PHASE, seq):
+                tr.end_at(tp, t1, _trace.NAME_PH_PACK, _trace.CAT_PHASE,
+                          comm.cid, seq)
         if t0:
             tr.end(t0, _trace.NAME_FUSED_PACK, _trace.CAT_COLL,
                    comm.cid, len(groups), len(sig))

@@ -54,6 +54,7 @@ binding discipline of the reference's comm_select.
 
 from __future__ import annotations
 
+import functools
 from collections import deque
 from typing import Any, Callable, List, Optional, Tuple
 
@@ -64,10 +65,10 @@ from ompi_tpu.mca.params import registry
 from ompi_tpu.obs import integrity as _ig
 
 # interned span names for the per-kind dispatch spans (args: cid,
-# payload bytes, interned algorithm tag)
+# payload bytes, interned algorithm tag, the operation's sequence)
 _PIPE_NAME = {
     kind: _trace.intern_name(f"pipeline_{kind}",
-                             ("cid", "nbytes", "alg$"))
+                             ("cid", "nbytes", "alg$", "op"))
     for kind in ("allreduce", "bcast", "alltoall")
 }
 
@@ -76,6 +77,10 @@ _PIPE_NAME = {
 _CAT_PHASE = _trace.CAT_PHASE
 _NAME_PH_PACK = _trace.NAME_PH_PACK
 _NAME_PH_UNPACK = _trace.NAME_PH_UNPACK
+_L_ENTRY = _trace.L_ENTRY
+_L_EXIT = _trace.L_EXIT
+_L_PACK = _trace.L_PACK
+_L_UNPACK = _trace.L_UNPACK
 
 _seg_size_var = registry.register(
     "coll", "seg", "size", 1 << 20, int,
@@ -318,18 +323,21 @@ def segment_elems(comm, itemsize: int) -> int:
 
 def _pull_segment(it, ph):
     """Pack stage: pull one (value, fn) job from the segment
-    generator.  The slice+pad work happens inside next(), so the span
-    around it IS the host-pack phase.  Hot (once per segment, per
-    rank): audited by hotpath_audit.  A non-None ctx sampled in at
-    build time (Tracer.gate_sampled), so every segment of a kept op
-    records — the whole-op decomposition stays coherent.  The
-    exhausted-iterator probe records one ~0 span."""
+    generator.  The slice+pad work happens inside next(), so the
+    interval around it IS the host-pack phase: banked in the ``pack``
+    accumulator on every op the phase profiler sees, recorded as
+    ph_pack on a kept one (the operation's ctx says which, the same
+    for every segment and on every member).  Hot (once per segment,
+    per rank): audited by hotpath_audit.  The exhausted-iterator probe
+    banks one ~0 interval."""
     if ph is None:
         return next(it, None)
     tr = ph[0]
-    t0 = tr.start()
+    t0 = tr.lap()
     job = next(it, None)
-    tr.end(t0, _NAME_PH_PACK, _CAT_PHASE, ph[1], ph[2], ph[3])
+    t1 = tr.lap_to(_L_PACK, _L_ENTRY)
+    if ph[4]:
+        tr.end_at(t0, t1, _NAME_PH_PACK, _CAT_PHASE, ph[1], ph[2], ph[3])
     return job
 
 
@@ -343,9 +351,13 @@ def _run_pipelined(module, comm, jobs, ck=None) -> List[Any]:
     depth = max(1, _depth_var.value)
     check = module._abort_check(comm)
     tr = comm.state.tracer
-    ph = ((tr, comm.cid, 0, 0)
-          if tr is not None and tr.phase and tr.gate_sampled(_CAT_PHASE)
-          else None)
+    ph = None
+    if tr is not None and tr.phase:
+        # ONE ctx, one keep-or-skip decision, for all the operation's
+        # segments: on the communicator's sequence number, so the same
+        # on every member
+        ph = (tr, comm.cid, comm._coll_seq, 0,
+              tr.keep(_CAT_PHASE, comm._coll_seq))
     it = iter(jobs)
     handles: deque = deque()
     outs: List[Any] = []
@@ -356,7 +368,7 @@ def _run_pipelined(module, comm, jobs, ck=None) -> List[Any]:
                 break
             value, fn = job
             handles.append(device.meet_begin(comm, value, fn, check,
-                                             ck))
+                                             ck, ph))
             pv_segments.add(1)
             if len(handles) > depth:
                 outs.append(device.meet_finish(comm, handles.popleft(),
@@ -395,16 +407,26 @@ def _concat_trim(outs: List[Any], n: int, seg: int):
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
 
 
+def _unpack_end(tr, comm, t0: int, nbytes: int = 0) -> None:
+    """The end of an unpack stage that started at ``t0`` (Tracer.lap):
+    banked in the ``unpack`` accumulator, recorded as ph_unpack on a
+    kept op.  Only reached with the phase profiler armed."""
+    t1 = tr.lap_to(_L_UNPACK, _L_EXIT)
+    seq = comm._coll_seq
+    if tr.kept(_CAT_PHASE, seq):
+        tr.end_at(t0, t1, _NAME_PH_UNPACK, _CAT_PHASE, comm.cid, seq,
+                  nbytes)
+
+
 def _unpack_trim(comm, outs: List[Any], n: int, seg: int):
-    """Unpack stage: trim the padded tail and concatenate, wrapped in
-    a ph_unpack phase span when the phase profiler is armed."""
+    """Unpack stage: trim the padded tail and concatenate, banked and
+    recorded as the unpack phase when the phase profiler is armed."""
     tr = comm.state.tracer
     if tr is None or not tr.phase:
         return _concat_trim(outs, n, seg)
-    t0 = tr.start_sampled(_CAT_PHASE)
+    t0 = tr.lap()
     out = _concat_trim(outs, n, seg)
-    if t0:
-        tr.end(t0, _NAME_PH_UNPACK, _CAT_PHASE, comm.cid, 0, 0)
+    _unpack_end(tr, comm, t0)
     return out
 
 
@@ -446,6 +468,14 @@ def _mesh_seg_reduce(module, comm, x, op, alg: str):
         jfn = _seg_kernel(kind, mesh, seg, dtype, opname)
         return device._scatter_out(jfn(g), mesh, size)
 
+    if comm.state.tracer is not None:
+        # the phase profiler's twin (device._phase_fn); its launch
+        # interval holds the kernel's resolution (a cache hit) too
+        fn.traced = functools.partial(
+            device._mesh_exec, mesh, size,
+            lambda g: _seg_kernel(kind, mesh, seg, dtype, opname)(g),
+            None)
+
     pad = _pad_value(opname, dtype)
     ck = _ig.spec("allreduce", opname, flat) if _ig.on else None
     outs = _run_pipelined(module, comm,
@@ -469,6 +499,14 @@ def _mesh_seg_bcast(module, comm, x, root: int):
         g = device._assemble(mesh, shards)
         jfn = _seg_kernel("segbcast", mesh, seg, dtype, root)
         return device._scatter_out(jfn(g), mesh, size)
+
+    if comm.state.tracer is not None:
+        # the phase profiler's twin (device._phase_fn); its launch
+        # interval holds the kernel's resolution (a cache hit) too
+        fn.traced = functools.partial(
+            device._mesh_exec, mesh, size,
+            lambda g: _seg_kernel("segbcast", mesh, seg, dtype, root)(g),
+            None)
 
     ck = _ig.spec("bcast", "", flat, root) if _ig.on else None
     outs = _run_pipelined(module, comm,
@@ -499,6 +537,14 @@ def _mesh_seg_alltoall(module, comm, x):
         jfn = _seg_kernel("sega2a", mesh, seg, dtype, None)
         return device._scatter_out(jfn(g), mesh, size)
 
+    if comm.state.tracer is not None:
+        # the phase profiler's twin (device._phase_fn); its launch
+        # interval holds the kernel's resolution (a cache hit) too
+        fn.traced = functools.partial(
+            device._mesh_exec, mesh, size,
+            lambda g: _seg_kernel("sega2a", mesh, seg, dtype, None)(g),
+            None)
+
     def jobs():
         for lo in range(0, cols, m):
             sub = rows[:, lo:lo + m]
@@ -510,13 +556,18 @@ def _mesh_seg_alltoall(module, comm, x):
 
     ck = _ig.spec("alltoall", "", rows) if _ig.on else None
     outs = _run_pipelined(module, comm, jobs(), ck)
+    tr = comm.state.tracer
+    t0 = tr.lap() if tr is not None and tr.phase else 0
     pieces = [o.reshape(size, m) for o in outs]
     tail = cols - (len(pieces) - 1) * m
     if tail != m:
         pieces = pieces[:-1] + [pieces[-1][:, :tail]]
     full = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces,
                                                               axis=1)
-    return full.reshape(shape)
+    full = full.reshape(shape)
+    if t0:
+        _unpack_end(tr, comm, t0)
+    return full
 
 
 # -- hbm (intra-chip) segmentation ------------------------------------------
@@ -545,6 +596,12 @@ def _hbm_seg_reduce(module, comm, x, op):
     def fn(shards):
         return out_map(jbody(*shards), size)
 
+    if comm.state.tracer is not None:
+        # the phase profiler's twin (device._phase_fn)
+        from ompi_tpu.coll import device
+        fn.traced = functools.partial(device._stacked_exec, jbody,
+                                      out_map, size)
+
     pad = _pad_value(opname, dtype)
     ck = _ig.spec("allreduce", opname, flat) if _ig.on else None
     outs = _run_pipelined(module, comm,
@@ -569,6 +626,12 @@ def _hbm_seg_alltoall(module, comm, x):
     def fn(shards):
         return out_map(jbody(*shards), size)
 
+    if comm.state.tracer is not None:
+        # the phase profiler's twin (device._phase_fn)
+        from ompi_tpu.coll import device
+        fn.traced = functools.partial(device._stacked_exec, jbody,
+                                      out_map, size)
+
     def jobs():
         for lo in range(0, cols, m):
             sub = rows[:, lo:lo + m]
@@ -580,13 +643,18 @@ def _hbm_seg_alltoall(module, comm, x):
 
     ck = _ig.spec("alltoall", "", rows) if _ig.on else None
     outs = _run_pipelined(module, comm, jobs(), ck)
+    tr = comm.state.tracer
+    t0 = tr.lap() if tr is not None and tr.phase else 0
     pieces = [o.reshape(size, m) for o in outs]
     tail = cols - (len(pieces) - 1) * m
     if tail != m:
         pieces = pieces[:-1] + [pieces[-1][:, :tail]]
     full = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces,
                                                               axis=1)
-    return full.reshape(shape)
+    full = full.reshape(shape)
+    if t0:
+        _unpack_end(tr, comm, t0)
+    return full
 
 
 # ---------------------------------------------------------------------------
@@ -680,8 +748,8 @@ def maybe_device_coll(module, comm, kind: str, x, op=None, root=None):
     if alg is None:
         return UNHANDLED
     tr = comm.state.tracer
-    t0 = tr.start_sampled(_trace.CAT_COLL_DISPATCH) \
-        if tr is not None else 0
+    t0 = tr.start() if tr is not None and tr.keep(
+        _trace.CAT_COLL_DISPATCH, comm._coll_seq) else 0
     if module.name == "hbm":
         if kind == "allreduce":
             out = _hbm_seg_reduce(module, comm, x, op)
@@ -702,5 +770,5 @@ def maybe_device_coll(module, comm, kind: str, x, op=None, root=None):
     pv_ops.add(1)
     if t0:
         tr.end(t0, _PIPE_NAME[kind], _trace.CAT_COLL_DISPATCH,
-               comm.cid, nbytes, _trace.intern_name(alg))
+               comm.cid, nbytes, _trace.intern_name(alg), comm._coll_seq)
     return out

@@ -52,12 +52,14 @@ DESIGN.md §22.
 from __future__ import annotations
 
 from collections import OrderedDict
+import functools
 import time
 
 import numpy as np
 
 from ompi_tpu import obs as _obs
 from ompi_tpu import trace as _trace
+from ompi_tpu.coll import device as _dev
 from ompi_tpu.coll import pipeline as _pl
 from ompi_tpu.obs import integrity as _ig
 from ompi_tpu.mca.params import registry
@@ -67,7 +69,8 @@ _CAT_SEG = _trace.CAT_COLL_SEGMENT
 _CAT_PHASE = _trace.CAT_PHASE
 _NAME_PLAN = _trace.NAME_PLAN_EXEC
 _NAME_PH_PACK = _trace.NAME_PH_PACK
-_NAME_PH_UNPACK = _trace.NAME_PH_UNPACK
+_L_ENTRY = _trace.L_ENTRY
+_L_PACK = _trace.L_PACK
 
 _enable_var = registry.register(
     "coll", "plan", "enable", True, bool,
@@ -168,7 +171,15 @@ class Plan:
         tr = comm.state.tracer
         t0 = 0
         if tr is not None:
-            t0 = tr.start_sampled(_CAT_SEG)
+            # Tracer.keep, inlined: on the sequence number, the same
+            # on every member; sampled out is no call, no clock read
+            op = comm._coll_seq
+            if not tr._plo <= op < tr._phi:
+                tr._restep(op)
+            if op % tr._period[_CAT_SEG]:
+                tr._skipped[_CAT_SEG] += 1
+            else:
+                t0 = tr.start()
         ns0 = time.perf_counter_ns()
         value = flat
         if n != self.total:
@@ -181,7 +192,8 @@ class Plan:
                        _obs.current_band())
         if t0:
             tr.end(t0, _NAME_PLAN, _CAT_SEG,
-                   comm.cid, n * self.itemsize, self.alg_id)
+                   comm.cid, n * self.itemsize, self.alg_id,
+                   comm._coll_seq)
         return out
 
 
@@ -193,8 +205,7 @@ def _pack(comm, flat, n: int, plan: Plan):
     program when the next op starts (unlike osc's lock-serialized
     mirror reuse).  Copying runtimes compose on device."""
     tr = comm.state.tracer
-    t0 = tr.start_sampled(_CAT_PHASE) \
-        if tr is not None and tr.phase else 0
+    t0 = tr.lap() if tr is not None and tr.phase else 0
     if _staging.runtime_zero_copy():
         import jax
         buf = _staging.aligned_empty(plan.total * plan.itemsize)
@@ -208,19 +219,20 @@ def _pack(comm, flat, n: int, plan: Plan):
             [jnp.asarray(flat),
              jnp.full((plan.total - n,), plan.pad_val, plan.np_dtype)])
     if t0:
-        tr.end(t0, _NAME_PH_PACK, _CAT_PHASE,
-               comm.cid, 0, n * plan.itemsize)
+        t1 = tr.lap_to(_L_PACK, _L_ENTRY)
+        seq = comm._coll_seq
+        if tr.kept(_CAT_PHASE, seq):
+            tr.end_at(t0, t1, _NAME_PH_PACK, _CAT_PHASE,
+                      comm.cid, seq, n * plan.itemsize)
     return value
 
 
 def _unpack(comm, out, n: int, plan: Plan):
     tr = comm.state.tracer
-    t0 = tr.start_sampled(_CAT_PHASE) \
-        if tr is not None and tr.phase else 0
+    t0 = tr.lap() if tr is not None and tr.phase else 0
     res = out[:n]
     if t0:
-        tr.end(t0, _NAME_PH_UNPACK, _CAT_PHASE,
-               comm.cid, 0, n * plan.itemsize)
+        _pl._unpack_end(tr, comm, t0, n * plan.itemsize)
     return res
 
 
@@ -326,8 +338,6 @@ def _compile_mesh(alg: str, mesh, size: int, nsegs: int, seg: int,
 def _build_mesh_plan(comm, alg: str, nsegs: int, seg: int, np_dtype,
                      opname: str, donate: bool) -> Plan:
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from ompi_tpu.coll import device
-
     mesh = comm.mesh()
     size = comm.size
     devs = list(mesh.devices.reshape(-1))
@@ -341,17 +351,21 @@ def _build_mesh_plan(comm, alg: str, nsegs: int, seg: int, np_dtype,
     else:
         ckey = ("plan_" + alg, dev_key, (nsegs, seg), np_dtype.str,
                 opname, donate)
-    jfn = device.compile_cache.get(
+    jfn = _dev.compile_cache.get(
         ckey, lambda: _compile_mesh(alg, mesh, size, nsegs, seg,
                                     np_dtype, opname, native, donate))
     sharding = NamedSharding(mesh, P("r"))
 
     def fn(shards, _m=mesh, _sh=sharding, _j=jfn, _n=size):
-        g = device._assemble(_m, shards, _sh)
-        return device._scatter_out(_j(g), _m, _n)
+        g = _dev._assemble(_m, shards, _sh)
+        return _dev._scatter_out(_j(g), _m, _n)
+
+    # the phase profiler's twin (device._phase_fn), built once with
+    # the plan; untraced, the body above is what runs
+    fn.traced = functools.partial(_dev._mesh_exec, mesh, size, jfn, sharding)
 
     return Plan(alg, nsegs, seg, np_dtype,
-                _pl._pad_value(opname, np_dtype), fn, device.meet,
+                _pl._pad_value(opname, np_dtype), fn, _dev.meet,
                 devs[comm.rank],
                 _ig.spec_static("allreduce", opname,
                                 np.empty(0, np_dtype)))
@@ -393,8 +407,6 @@ def mesh_reduce(module, comm, x, op, alg: str):
 
 def _build_hbm_plan(module, comm, nsegs: int, seg: int, np_dtype,
                     opname: str, device_hint) -> Plan:
-    from ompi_tpu.coll import device
-
     size = comm.size
     jbody, out_map = module._stacked("allreduce", opname, size,
                                      (nsegs * seg,), np_dtype)
@@ -402,8 +414,10 @@ def _build_hbm_plan(module, comm, nsegs: int, seg: int, np_dtype,
     def fn(shards, _j=jbody, _o=out_map, _n=size):
         return _o(_j(*shards), _n)
 
+    fn.traced = functools.partial(_dev._stacked_exec, jbody, out_map, size)
+
     return Plan("hbm", nsegs, seg, np_dtype,
-                _pl._pad_value(opname, np_dtype), fn, device.meet,
+                _pl._pad_value(opname, np_dtype), fn, _dev.meet,
                 device_hint,
                 _ig.spec_static("allreduce", opname,
                                 np.empty(0, np_dtype)))

@@ -60,7 +60,10 @@ class SmCollModule(TunedModule):
         return cached
 
     def _meet(self, comm, value, fn):
-        return meet(comm, value, fn, self._abort_check(comm))
+        # host buffers through the device meeting point: traced like
+        # any meeting, but no part of the device collectives' account
+        return meet(comm, value, fn, self._abort_check(comm),
+                    account=False)
 
     # -- collectives -----------------------------------------------------
     def barrier(self, comm) -> None:

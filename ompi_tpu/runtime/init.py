@@ -277,5 +277,6 @@ def mpi_finalize(state: ProcState) -> None:
     # trace dump LAST: teardown spans (flush rendezvous, btl close)
     # are part of the timeline (_trace imported above for sync_state)
     _trace.dump_state(state)
+    _trace.detach(state)
     state.finalized = True
     clear_current(state)

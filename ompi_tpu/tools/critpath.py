@@ -49,8 +49,12 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ompi_tpu.tools import traceview
 
-# span name -> phase label (mirrors trace.PHASE_LABELS; copied so the
-# tool keeps working against dump files with no package state)
+# span name -> phase label: trace.PHASE_LABELS when the package is
+# importable, so a span name added there is read here with no edit;
+# this copy keeps the tool working against dump files alone.  The
+# collect-side ph_rdv_wait also says how its wait splits (``skew_ns``
+# until the meeting was full, ``wake_ns`` from the publish to running
+# again): rendezvous_split() totals them.
 PHASE_OF = {
     "ph_rdv_wait": "rendezvous",
     "ph_pack": "pack",
@@ -59,7 +63,17 @@ PHASE_OF = {
     "ph_execute": "execute",
     "ph_unpack": "unpack",
     "xla_compile": "compile",
+    "ph_entry": "entry",
+    "ph_assemble": "assemble",
+    "ph_launch": "launch",
+    "ph_scatter": "scatter",
+    "ph_exit": "exit",
 }
+try:
+    from ompi_tpu.trace import PHASE_LABELS as _LIVE_LABELS
+    PHASE_OF.update(_LIVE_LABELS)
+except ImportError:          # dump-only use, no package state
+    pass
 
 #: categories whose spans are whole-op records correlated across ranks
 #: by the (cid, seq) key every member ticks in lockstep
@@ -119,6 +133,24 @@ def contained_phases(op: dict, idx: Dict[int, List[dict]],
         if e["ts"] >= lo and e["ts"] + e.get("dur", 0.0) <= hi + slack_us:
             out.append(e)
     return out
+
+
+def rendezvous_split(idx: Dict[int, List[dict]]) -> Dict[str, float]:
+    """Microseconds of the kept collect-side rendezvous waits, all
+    ranks, by what the rank waited for: a peer to arrive (``skew``),
+    the publisher's work (``serve``), the hand-off back to it after
+    the publish (``wake``: condition variable and GIL)."""
+    skew = wake = total = 0.0
+    for lst in idx.values():
+        for e in lst:
+            a = e.get("args") or {}
+            if e["name"] == "ph_rdv_wait" and "wake_ns" in a \
+                    and (a["wake_ns"] or a.get("skew_ns")):
+                skew += a.get("skew_ns", 0) / 1e3
+                wake += a["wake_ns"] / 1e3
+                total += e.get("dur", 0.0)
+    return {"skew": round(skew, 1), "wake": round(wake, 1),
+            "serve": round(max(0.0, total - skew - wake), 1)}
 
 
 def _clipped_phase_us(op: dict, phases: List[dict]) -> float:
@@ -282,6 +314,7 @@ def analyze(dumps: List[dict], offsets_us: List[float],
             for ph in sorted({PHASE_OF[e["name"]]
                               for lst in idx.values() for e in lst})
         },
+        "rendezvous_split_us": rendezvous_split(idx),
         "tax": dispatch_tax(events, idx),
     }
 
@@ -339,6 +372,11 @@ def report(res: Dict[str, Any], top: int = 5) -> str:
     for ph, us in sorted(res["phase_wall_us"].items(),
                          key=lambda kv: -kv[1]):
         lines.append(f"  {ph:<12} {us:12.1f}")
+    rs = res.get("rendezvous_split_us") or {}
+    if any(rs.values()):
+        lines.append(
+            f"rendezvous wait split (us, all ranks): skew {rs['skew']}  "
+            f"serve {rs['serve']}  wake {rs['wake']}")
     lines.append("dispatch tax (median us per phase per alg x size):")
     if not res["tax"]:
         lines.append("  (no whole-op dispatch spans with phases)")

@@ -44,6 +44,17 @@ HOT_FUNCTIONS: Dict[str, Tuple[str, ...]] = {
         "Tracer.req_mark",
         "coll_begin",
         "coll_end",
+        # the operation categories' keep-or-skip decision and the
+        # layer accumulators' boundaries (ISSUE 26): with
+        # trace_phase_enable on they run on EVERY blocking device
+        # collective of every rank, several times an operation (the
+        # rendezvous' own boundaries are inline in device.Rendezvous)
+        "Tracer.kept",
+        "Tracer.keep",
+        "Tracer.end_at",
+        "Tracer.end_at2",
+        "Tracer.lap",
+        "Tracer.lap_to",
     ),
     "ompi_tpu/pml/ob1.py": (
         "PmlOb1._trace_p2p_end",
@@ -54,9 +65,11 @@ HOT_FUNCTIONS: Dict[str, Tuple[str, ...]] = {
     # rules as the tracer itself — the ph context tuple is built ONCE
     # per op at the gate, never inside these
     "ompi_tpu/coll/device.py": (
-        "_ph_rdv_start",
-        "_ph_rdv_end",
         "_phase_fn",
+        # the publisher's steps of a traced meeting (ISSUE 26): the
+        # spans of a KEPT operation are built in _pub_span, off these
+        "_mesh_exec",
+        "_stacked_exec",
     ),
     "ompi_tpu/coll/pipeline.py": (
         "_pull_segment",

@@ -5,7 +5,7 @@ operations with a wall-clock start and a perf-counter duration) and
 *instants* (point annotations: fault injections, heartbeats) from the
 layers that matter — pml send/recv activate→complete, collective
 entry→rendezvous→dispatch (including the fused device path's
-pack/compile/execute phases), progress-loop tick latency, OOB
+pack/compile/dispatch phases), progress-loop tick latency, OOB
 heartbeat/reconnect.  ``ompi_tpu/tools/traceview.py`` merges per-rank
 dumps, applies mpisync clock offsets, and emits Chrome trace-event
 JSON.
@@ -27,14 +27,19 @@ functions so tuple/dict builds and ``time.time`` calls cannot
 silently return.
 
 On a GIL-bound box every nanosecond on the hot path is multiplied by
-the rank count, so recording is additionally *sampled per category*:
-``Tracer.start_sampled`` keeps 1-in-N spans (N starts at 1, doubles
-each time a category banks ``trace_sample_auto`` kept events, capped
-at ``trace_sample_max``) and the skip path is a counter decrement —
-no clock read, no ring write.  The unsampled remainder is counted
-EXACTLY per category (``trace_dropped_<cat>`` pvars, ``sampling`` /
-``dropped_by_cat`` dump sections), so sampled traces stay honest:
-``recorded == kept + sampled_out`` always holds.
+the rank count, so recording is additionally *sampled per category*.
+The per-rank categories (``p2p``, ``nbc``, ``rma``, ``compile``) go
+through ``Tracer.start_sampled``: 1-in-N spans kept (N starts at 1,
+doubles each time a category SEES ``trace_sample_auto`` more events,
+capped at ``trace_sample_max``), the skip path a counter decrement.
+The operation-structured categories (``coll``, ``coll_dispatch``,
+``coll_segment``, ``phase``: ``OP_CATS``) go through ``Tracer.keep``,
+a PURE function of the communicator's collective sequence number and
+of parameters every rank shares, so an operation kept on one member is
+kept on all, with all its segments and phases.  Either way the
+unsampled remainder is counted EXACTLY per category
+(``trace_dropped_<cat>`` pvars, ``sampling`` / ``dropped_by_cat`` dump
+sections): ``recorded == kept + sampled_out`` always holds.
 
 Correlation keys stitch ranks together in the merger:
 
@@ -46,10 +51,20 @@ Correlation keys stitch ranks together in the merger:
     shared counter (``coll_seq``), so rank 0's allreduce #7 lines up
     with rank 3's allreduce #7.
 
-Under sampling each rank keeps its own 1-in-N subset, so cross-rank
-correlation is complete only while every category still runs at
-period 1 (small traces never adapt: the default ``trace_sample_auto``
-threshold is far above what a test emits).
+The per-rank categories keep a 1-in-N subset of their own, so p2p
+correlation is complete only while they run at period 1; the
+operation-structured ones correlate at any period (see above).
+
+With ``trace_phase_enable`` on, every blocking device collective of
+every rank is additionally ACCOUNTED, not sampled: the layer
+accumulators (``LAYERS``, one ``array('q')`` of nanosecond totals per
+tracer) bank the interval between consecutive boundaries of the
+operation's host path (shim entry, deposit, meeting full, results
+published, woken, shim return, next shim entry), so a rank's
+accumulators sum to its wall time by construction.  They are
+published process-wide as the ``trace_layer_<name>_ns`` pvars and
+``trace_layer_rendezvous_count`` (summed over every live
+rank-thread's tracer): one pvar for each number a metric reads.
 
 On top of the same ring, fixed log2-bucket latency histograms
 (progress tick, collective dispatch, p2p completion, per-segment
@@ -71,7 +86,9 @@ import json
 import os
 import threading
 import time
+import weakref
 from array import array
+from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 from ompi_tpu import peruse
@@ -98,10 +115,11 @@ sample_spec_var = registry.register(
 sample_auto_var = registry.register(
     "trace", "", "sample_auto", 1024, int,
     help="Adaptive sampling: double a category's period each time it "
-         "SEES this many more events, kept or skipped (busy "
-         "categories back off geometrically to trace_sample_max "
-         "within a few thousand ops; quiet ones never leave full "
-         "fidelity).  0 disables adaptation")
+         "SEES this many more events, kept or skipped, per 8192 ring "
+         "slots (a ring N times the default backs off N times later: "
+         "about one step per ring-full of operations).  The "
+         "operation categories count in the communicator's sequence "
+         "number, the same on every member.  0 disables adaptation")
 sample_max_var = registry.register(
     "trace", "", "sample_max", 64, int,
     help="Ceiling for adaptive per-category sampling periods "
@@ -109,11 +127,13 @@ sample_max_var = registry.register(
 phase_enable_var = registry.register(
     "trace", "phase", "enable", False, bool,
     help="Record sub-op PHASE spans inside traced collectives "
-         "(rendezvous wait, host pack, dispatch, device execute, "
-         "unpack) for tools/critpath.py dispatch-tax attribution.  "
+         "(entry, rendezvous wait, host pack, dispatch with "
+         "assemble / launch / scatter, unpack, exit) for "
+         "tools/critpath.py dispatch-tax attribution.  "
          "Needs trace_enable; off = one extra attribute check per "
-         "traced op.  Device-execute spans fence with "
-         "block_until_ready on SAMPLED ops only")
+         "traced op.  Also banks the exact per-layer accumulators "
+         "(trace_layer_* pvars) on EVERY op.  Nothing fences: the "
+         "device's own time is the profiler's device plane")
 phase_sample_var = registry.register(
     "trace", "phase", "sample", 1, int,
     help="Initial 1-in-N sampling period of the 'phase' category "
@@ -227,18 +247,25 @@ CAT_RMA = intern_cat("rma")
 # categories whose spans are sampled / drop-accounted (pvar surface)
 SPAN_CATS = ("p2p", "coll", "nbc", "coll_dispatch", "coll_segment",
              "compile", "phase", "rma")
+#: the operation-structured ones: kept or skipped by Tracer.keep on the
+#: communicator's sequence number, identically on every member
+OP_CATS = ("coll", "coll_dispatch", "coll_segment", "phase")
+_OP_CAT_IDS = (CAT_COLL, CAT_COLL_DISPATCH, CAT_COLL_SEGMENT, CAT_PHASE)
 
 NAME_SEND = intern_name("send", ("cid", "src", "tag", "seq", "bytes"))
 NAME_RECV = intern_name("recv", ("cid", "src", "tag", "seq", "bytes"))
 NAME_NBC = intern_name("nbc", ("cid", "seq"))
-NAME_MEET = intern_name("meet", ("cid", "seq", "nbytes"))
-NAME_SEG_MEET = intern_name("seg_meet", ("cid", "seq", "nbytes"))
+# ``seq`` is the device-tier sequence (one per rendezvous, so segments
+# stay apart); ``op`` is the enclosing operation's collective sequence,
+# the key its coll span and its phase spans carry
+NAME_MEET = intern_name("meet", ("cid", "seq", "nbytes", "op"))
+NAME_SEG_MEET = intern_name("seg_meet", ("cid", "seq", "nbytes", "op"))
 # one span per compiled-plan collective (DESIGN.md §22): pack, the
 # single rendezvous and unpack all inside it.  Categorized under
 # coll_segment so HIST_COLL_SEGMENT keeps a latency pulse when the
 # plan path replaces per-segment meets
-NAME_PLAN_EXEC = intern_name("plan_exec", ("cid", "nbytes", "alg$"))
-NAME_FUSED_FLUSH = intern_name("fused_flush", ("cid", "ops"))
+NAME_PLAN_EXEC = intern_name("plan_exec", ("cid", "nbytes", "alg$", "op"))
+NAME_FUSED_FLUSH = intern_name("fused_flush", ("cid", "ops", "seq"))
 NAME_FUSED_PACK = intern_name("fused_pack", ("cid", "groups", "slots"))
 NAME_XLA_COMPILE = intern_name("xla_compile", ("key$",))
 NAME_RMA_PUT = intern_name("rma_put", ("cid", "target", "nbytes"))
@@ -246,27 +273,62 @@ NAME_RMA_GET = intern_name("rma_get", ("cid", "target", "nbytes"))
 NAME_RMA_ACC = intern_name("rma_acc", ("cid", "target", "nbytes"))
 
 # phase-span names share one arg schema: the op correlation keys.
-# (cid, seq) line phases up with their enclosing meet/seg_meet span;
-# critpath additionally attributes by time containment, so sites that
-# cannot know the final seq (pack/unpack of a pipelined segment) pass
-# their best approximation or 0.
-NAME_PH_RDV = intern_name("ph_rdv_wait", ("cid", "seq", "nbytes"))
-NAME_PH_PACK = intern_name("ph_pack", ("cid", "seq", "nbytes"))
-NAME_PH_DISPATCH = intern_name("ph_dispatch", ("cid", "seq", "nbytes"))
-NAME_PH_EXECUTE = intern_name("ph_execute", ("cid", "seq", "nbytes"))
-NAME_PH_UNPACK = intern_name("ph_unpack", ("cid", "seq", "nbytes"))
+# (cid, seq) is the key of the operation's ``coll`` span (the
+# communicator's collective sequence), the parent of every phase of
+# the operation; critpath additionally attributes by time containment.
+# ph_assemble / ph_launch / ph_scatter lie inside ph_dispatch.  The
+# collect-side ph_rdv_wait says how its wait splits: ``skew_ns`` until
+# the meeting was full, ``wake_ns`` from the results being published
+# to this rank running again (the rest is the serve).
+_PH_ARGS = ("cid", "seq", "nbytes")
+NAME_PH_RDV = intern_name("ph_rdv_wait", _PH_ARGS + ("skew_ns", "wake_ns"))
+NAME_PH_PACK = intern_name("ph_pack", _PH_ARGS)
+NAME_PH_DISPATCH = intern_name("ph_dispatch", _PH_ARGS)
+NAME_PH_UNPACK = intern_name("ph_unpack", _PH_ARGS)
+NAME_PH_ENTRY = intern_name("ph_entry", _PH_ARGS)
+NAME_PH_ASSEMBLE = intern_name("ph_assemble", _PH_ARGS)
+NAME_PH_LAUNCH = intern_name("ph_launch", _PH_ARGS)
+NAME_PH_SCATTER = intern_name("ph_scatter", _PH_ARGS)
+NAME_PH_EXIT = intern_name("ph_exit", _PH_ARGS)
 
-#: span name -> human phase label (tools/critpath.py keeps its own
-#: copy so it stays runnable against dump files alone)
+#: span name -> human phase label (tools/critpath.py reads this when
+#: the package is importable and keeps a copy for dump-only use)
 PHASE_LABELS = {
     "ph_rdv_wait": "rendezvous",
     "ph_pack": "pack",
     "fused_pack": "pack",
     "ph_dispatch": "dispatch",
-    "ph_execute": "execute",
+    "ph_execute": "execute",    # dumps from before PR 26 (the fence)
     "ph_unpack": "unpack",
     "xla_compile": "compile",
+    "ph_entry": "entry",
+    "ph_assemble": "assemble",
+    "ph_launch": "launch",
+    "ph_scatter": "scatter",
+    "ph_exit": "exit",
 }
+
+# -- layer accumulators (trace_phase_enable) --------------------------------
+# Exact nanosecond totals of the intervals between the boundaries of a
+# blocking device collective's host path, banked on EVERY operation of
+# every rank (never sampled).  The first nine (LAYER_CLOSURE) partition
+# a rank's wall time: a per-tracer cursor moves from boundary to
+# boundary and each boundary banks the time since the last one, so
+# nothing is counted twice and what no boundary covers shows as the
+# difference to the caller's own clock.  assemble / launch / scatter
+# are the publisher's work INSIDE rdv_serve (banked on the triggering
+# rank's tracer) and never enter the sum.  One more slot of the same
+# array counts rendezvous (L_RENDEZVOUS).  Each has a per-layer metric
+# that reads it (cellbench/metrics/); a counter nothing reads is not
+# kept.
+LAYERS = ("entry", "rdv_slot", "rdv_skew", "rdv_serve", "rdv_wake",
+          "exit", "caller", "pack", "unpack",
+          "assemble", "launch", "scatter")
+(L_ENTRY, L_RDV_SLOT, L_RDV_SKEW, L_RDV_SERVE, L_RDV_WAKE, L_EXIT,
+ L_CALLER, L_PACK, L_UNPACK, L_ASSEMBLE, L_LAUNCH, L_SCATTER,
+ L_RENDEZVOUS) = range(len(LAYERS) + 1)
+#: the accumulators whose sum is a rank's whole time
+LAYER_CLOSURE = LAYERS[:L_ASSEMBLE]
 
 _NO_ADAPT = 1 << 62  # _nxt sentinel when adaptation is disabled
 
@@ -330,9 +392,11 @@ class Tracer:
         "_ts", "_dur", "_name", "_cat", "_ph",
         "_a0", "_a1", "_a2", "_a3", "_a4", "_argobj",
         "_nrec", "_period", "_ctr", "_skipped", "_cnt", "_nxt",
-        "_over", "_auto", "_max_period",
+        "_over", "_auto", "_max_period", "_p0", "_plo", "_phi",
         "phase", "sync_offsets_us",
         "_req_tags", "_req_ts", "_req_n",
+        "_lns", "_t_cur", "_cur_k", "_t_ret", "_done",
+        "__weakref__",
     )
 
     def __init__(self, rank: int, capacity: int = 8192) -> None:
@@ -357,11 +421,17 @@ class Tracer:
         self._nrec = 0          # events stored in the ring (kept)
         ncat = len(_cats)
         self._period = [1] * ncat    # current 1-in-N period per cat
+        self._p0 = [1] * ncat        # ...and the one it started from
         self._ctr = [0] * ncat       # skips remaining in this period
         self._skipped = [0] * ncat   # exact sampled-out count per cat
         self._cnt = [0] * ncat       # exact kept count per cat
         self._over = [0] * ncat      # exact overwrite count per cat
-        self._auto = max(0, int(sample_auto_var.value))
+        # sightings per period doubling, counted per 8192 ring slots:
+        # at about 8 events an operation that is one step per
+        # ring-full, so a run that asks for a larger ring also keeps
+        # full fidelity for longer
+        self._auto = max(0, int(sample_auto_var.value)) \
+            * max(1, cap // 8192)
         self._max_period = max(1, int(sample_max_var.value))
         nxt = self._auto if self._auto else _NO_ADAPT
         self._nxt = [nxt] * ncat     # seen-count at next period double
@@ -370,6 +440,20 @@ class Tracer:
         # its own knob (trace_sample_spec 'phase:N' still overrides)
         self.phase = bool(phase_enable_var.value)
         self._period[CAT_PHASE] = max(1, int(phase_sample_var.value))
+        # layer accumulators (LAYERS, then the rendezvous count), and
+        # the cursor that walks an operation's boundaries: _t_cur is the
+        # last boundary (0 = none open), _cur_k the accumulator the
+        # time up to the NEXT boundary belongs to (entry before the
+        # deposit, exit after the wake), _t_ret the last shim return
+        # (the open caller interval)
+        self._lns = array("q", [0]) * (len(LAYERS) + 1)
+        self._t_cur = 0
+        self._cur_k = L_ENTRY
+        self._t_ret = 0
+        # phase spans closed by another thread (device.py's dispatcher
+        # thread, on this rank's behalf) wait here until this rank's
+        # own thread files them: the ring keeps one writer
+        self._done: deque = deque()
         # mpisync offsets measured at finalize (sync_state) ride the
         # dump so traceview/critpath need no hand-plumbed --sync file
         self.sync_offsets_us: Optional[List[float]] = None
@@ -383,6 +467,13 @@ class Tracer:
         for cid, per in _parse_sample_spec(sample_spec_var.value).items():
             self._ensure_cat(cid)
             self._period[cid] = min(per, self._max_period)
+        # the operation categories: _p0 holds the periods they start
+        # from, _period those in force for the communicator sequence
+        # numbers in [_plo, _phi) (_restep moves the window; hot sites
+        # test it inline)
+        self._p0[:] = self._period
+        self._plo = self._phi = 0
+        self._restep(0)
 
     def _ensure_cat(self, cat_id: int) -> None:
         """Grow the per-category tables to cover a cat interned after
@@ -392,6 +483,7 @@ class Tracer:
         if grow > 0:
             nxt = self._auto if self._auto else _NO_ADAPT
             self._period.extend([1] * grow)
+            self._p0.extend([1] * grow)
             self._ctr.extend([0] * grow)
             self._skipped.extend([0] * grow)
             self._cnt.extend([0] * grow)
@@ -447,30 +539,53 @@ class Tracer:
         self._ctr[cat_id] = p - 1
         return _pcns()
 
-    def gate_sampled(self, cat_id: int) -> bool:
-        """Sampling decision WITHOUT a clock read: start_sampled's
-        1-in-period keep/skip logic for call sites that gate a whole
-        STRUCTURE of spans — the §18 per-op phase ctx — rather than
-        one span.  A skipped sighting is a counter decrement counted
-        sampled-out (the category's exact counters still see every
-        op); a kept one runs the same geometric adaptation and
-        reloads the counter.  The sub-spans of a kept structure then
-        record unconditionally (``start()``/``end()``), so one op's
-        decomposition is always coherent — never a dispatch span
-        whose execute sampled out."""
-        c = self._ctr[cat_id]
-        if c:
-            self._ctr[cat_id] = c - 1
+    def _restep(self, seq: int) -> None:
+        """Put the operation categories' periods in force at sequence
+        number ``seq`` into ``_period`` and the run of sequence
+        numbers they hold for into ``[_plo, _phi)``.  A PURE function
+        of the sequence number and of what every member shares (the
+        initial periods from trace_sample_spec / trace_phase_sample,
+        the back-off step from trace_sample_auto and
+        trace_buffer_events, the cap trace_sample_max): a period
+        doubles every ``_auto`` operations of the communicator.  Cold:
+        once per step (hot sites test ``_plo <= seq < _phi`` inline)."""
+        step = self._auto
+        k = seq // step if step else 0
+        cap = self._max_period
+        top = True
+        for c in _OP_CAT_IDS:
+            p = self._p0[c] << k if k < 16 else cap
+            if p >= cap:
+                p = cap
+            else:
+                top = False
+            self._period[c] = p
+        self._plo = k * step
+        self._phi = (k + 1) * step if step and not top else _NO_ADAPT
+
+    def kept(self, cat_id: int, seq: int) -> bool:
+        """Whether operation ``seq`` of a communicator keeps its spans
+        of category ``cat_id``: its number divides by the period in
+        force at it (_restep), so an operation kept on one member is
+        kept on all.  No counter moves: sites inside an operation that
+        was already judged ask here."""
+        if not self._plo <= seq < self._phi:
+            self._restep(seq)
+        return not seq % self._period[cat_id]
+
+    def keep(self, cat_id: int, seq: int) -> bool:
+        """kept() with the exact accounting: a skipped sighting counts
+        sampled-out, a kept one is counted by the end() that follows,
+        so kept + sampled_out == seen per category on every rank.  The
+        sites that run on every blocking collective (coll_begin,
+        device.meet, meet_begin, the pipeline and plan entries) carry
+        the same test inline: the sampled-out steady state is two
+        compares, a modulo and a list store, no call, no clock read."""
+        if not self._plo <= seq < self._phi:
+            self._restep(seq)
+        if seq % self._period[cat_id]:
             self._skipped[cat_id] += 1
             return False
-        p = self._period[cat_id]
-        seen = self._cnt[cat_id] + self._skipped[cat_id]
-        if seen >= self._nxt[cat_id]:
-            self._nxt[cat_id] = seen + self._auto
-            if p < self._max_period:
-                p += p
-                self._period[cat_id] = p
-        self._ctr[cat_id] = p - 1
         return True
 
     def end(self, t0: int, name_id: int, cat_id: int,
@@ -507,6 +622,134 @@ class Tracer:
         self._nrec += 1
         self._cnt[cat_id] += 1
         return dur
+
+    def end_at(self, t0: int, t1: int, name_id: int, cat_id: int,
+               a0: int = 0, a1: int = 0, a2: int = 0, a3: int = 0,
+               a4: int = 0, hist: int = -1) -> None:
+        """Store a span whose two clock readings the caller already
+        holds (the layer boundaries read the clock once each): end()
+        without the clock read.  ``hist`` names a histogram the span
+        also feeds (the category's own binding is end()'s)."""
+        if hist >= 0:
+            b = ((t1 - t0) // 1000).bit_length()
+            self.hists[hist][b if b < N_BUCKETS else N_BUCKETS - 1] += 1
+        cur = self.cursor
+        cap = self.capacity
+        i = cur % cap
+        if cur >= cap:
+            self._over[self._cat[i]] += 1
+        self._ts[i] = t0
+        self._dur[i] = t1 - t0
+        self._name[i] = name_id
+        self._cat[i] = cat_id
+        self._ph[i] = 0
+        self._a0[i] = a0
+        self._a1[i] = a1
+        self._a2[i] = a2
+        self._a3[i] = a3
+        self._a4[i] = a4
+        self._argobj[i] = None
+        self.cursor = cur + 1
+        self._nrec += 1
+        self._cnt[cat_id] += 1
+
+    def end_at2(self, ta0: int, ta1: int, name_a: int, cat_a: int,
+                tb0: int, tb1: int, name_b: int, cat_b: int,
+                a0: int = 0, a1: int = 0, a2: int = 0,
+                hist_b: int = -1) -> None:
+        """Two end_at stores in one call, for the boundaries that close
+        two spans of one operation at once (ph_entry and the slot-side
+        ph_rdv_wait at the deposit; ph_exit and the coll span at the
+        shim's return).  They share their args (cid, seq, nbytes);
+        ``hist_b`` names a histogram the second also feeds.
+        With 8 rank-threads taking turns under one GIL a Python call
+        costs several times what it does alone (3 us against 0.3 us
+        measured on a v5e host, PERF.md), so the traced path counts
+        its calls."""
+        if hist_b >= 0:
+            b = ((tb1 - tb0) // 1000).bit_length()
+            self.hists[hist_b][b if b < N_BUCKETS else N_BUCKETS - 1] += 1
+        cur = self.cursor
+        cap = self.capacity
+        i = cur % cap
+        if cur >= cap:
+            self._over[self._cat[i]] += 1
+        self._ts[i] = ta0
+        self._dur[i] = ta1 - ta0
+        self._name[i] = name_a
+        self._cat[i] = cat_a
+        self._ph[i] = 0
+        self._a0[i] = a0
+        self._a1[i] = a1
+        self._a2[i] = a2
+        self._a3[i] = 0
+        self._a4[i] = 0
+        self._argobj[i] = None
+        cur += 1
+        i = cur % cap
+        if cur >= cap:
+            self._over[self._cat[i]] += 1
+        self._ts[i] = tb0
+        self._dur[i] = tb1 - tb0
+        self._name[i] = name_b
+        self._cat[i] = cat_b
+        self._ph[i] = 0
+        self._a0[i] = a0
+        self._a1[i] = a1
+        self._a2[i] = a2
+        self._a3[i] = 0
+        self._a4[i] = 0
+        self._argobj[i] = None
+        self.cursor = cur + 1
+        self._nrec += 2
+        self._cnt[cat_a] += 1
+        self._cnt[cat_b] += 1
+
+    # -- layer accounting (trace_phase_enable) ---------------------------
+    # One clock read per boundary.  The cursor belongs to the rank's
+    # own thread; the publisher's steps (another thread when the
+    # dispatcher runs them, on the triggering rank's behalf) bank into
+    # accumulators the cursor never touches.  The rendezvous' own
+    # boundaries (deposit, woken) and the shim's are banked inline
+    # where they are read (device.Rendezvous, coll_begin / coll_end):
+    # they run on every operation of every rank.
+    def lap(self, _pcns=time.perf_counter_ns) -> int:
+        """A boundary that starts a named interval (pack, unpack):
+        banks the time since the last boundary where it belongs (entry
+        or exit) and returns the reading."""
+        now = _pcns()
+        c = self._t_cur
+        if c:
+            self._lns[self._cur_k] += now - c
+            self._t_cur = now
+        return now
+
+    def lap_to(self, which: int, then: int,
+               _pcns=time.perf_counter_ns) -> int:
+        """A boundary that ends the named interval ``which``; the time
+        up to the next boundary then belongs to ``then``."""
+        now = _pcns()
+        c = self._t_cur
+        if c:
+            self._lns[which] += now - c
+            self._t_cur = now
+            self._cur_k = then
+        return now
+
+    def file_done(self) -> None:
+        """File the phase spans the dispatcher thread closed on this
+        rank's behalf (start, end, name, cid, seq, nbytes: ph_dispatch
+        and its parts) into the ring, from this rank's own thread."""
+        done = self._done
+        while done:
+            t0, t1, name_id, cid, seq, nb = done.popleft()
+            self.end_at(t0, t1, name_id, CAT_PHASE, cid, seq, nb)
+
+    def layer_totals(self) -> Dict[str, int]:
+        """{layer: ns} of this tracer, and the rendezvous count (cold)."""
+        out = {n: self._lns[i] for i, n in enumerate(LAYERS)}
+        out["rendezvous"] = self._lns[L_RENDEZVOUS]
+        return out
 
     def _store_slot(self, ts: int, dur: int, name_id: int, cat_id: int,
                     ph: int, argobj: Optional[dict]) -> None:
@@ -653,6 +896,7 @@ class Tracer:
         """Events oldest-first, materialized as span dicts (the dump
         schema — id decode and string synthesis happen here, off the
         hot path).  Timestamps become epoch seconds via the anchor."""
+        self.file_done()
         out = []
         for i in self._live_range():
             e = {"name": _names[self._name[i]],
@@ -670,6 +914,7 @@ class Tracer:
         spans, which ARE the compile phase) from the live ring — the
         obs_critpath_phase_us gauge.  Cold path: pvar reads and the
         probe harness only."""
+        self.file_done()
         compile_cid = _cat_ids.get("compile", -1)
         out: Dict[str, int] = {}
         for i in self._live_range():
@@ -685,6 +930,7 @@ class Tracer:
         return out
 
     def span_count(self, cat) -> int:
+        self.file_done()
         cid = _cat_ids.get(cat, -1) if isinstance(cat, str) else cat
         n = 0
         for i in self._live_range():
@@ -729,12 +975,27 @@ class Tracer:
 
 # -- per-rank attach / dump -------------------------------------------------
 
+# every attached rank-thread's tracer, for the process-wide layer
+# pvars: a reader (one thread) sums the accumulators of all of them.
+# A rank leaves at finalize (detach); weak, so that a world that never
+# finalized does not pin its rings.
+_live: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_live_lock = threading.Lock()
+
+
+def live_tracers() -> List[Tracer]:
+    with _live_lock:
+        return list(_live)
+
+
 def force_attach(state) -> Tracer:
     """Attach a tracer regardless of trace_enable (the autotuner runs
     on trace histograms, so enabling it implies a tracer)."""
     tr = Tracer(state.rank, buffer_var.value)
     state.tracer = tr
     state.progress.tracer = tr
+    with _live_lock:
+        _live.add(tr)
     return tr
 
 
@@ -747,6 +1008,16 @@ def attach(state) -> Optional[Tracer]:
         state.tracer = None
         return None
     return force_attach(state)
+
+
+def detach(state) -> None:
+    """Finalize: the rank's tracer leaves the process-wide layer pvars
+    (they sum LIVE rank-threads; a finalized world's totals stay
+    readable on its tracer and in its dump)."""
+    tr = getattr(state, "tracer", None)
+    if tr is not None:
+        with _live_lock:
+            _live.discard(tr)
 
 
 def _resolve_dump_path(base: str, tag: str) -> str:
@@ -892,6 +1163,28 @@ for _cat in SPAN_CATS:
         help=f"Exact count of '{_cat}' spans not in the ring "
              "(sampled out + overwritten)",
         getter=_tr_dropped_cat(_cat))
+
+
+def _layer_sum(which: int):
+    def getter():
+        return sum(tr._lns[which] for tr in live_tracers())
+    return getter
+
+
+for _i, _layer in enumerate(LAYERS):
+    registry.register_pvar(
+        "trace", "layer", f"{_layer}_ns",
+        help=f"Nanoseconds banked in the '{_layer}' interval of "
+             "blocking device collectives, summed over every "
+             "rank-thread of the process (trace_phase_enable; "
+             "exact, every operation)",
+        getter=_layer_sum(_i))
+registry.register_pvar(
+    "trace", "layer", "rendezvous_count",
+    help="Rendezvous the layer account saw (one per unsegmented "
+         "operation, one per segment of a pipelined one), summed over "
+         "every rank-thread of the process (trace_phase_enable)",
+    getter=_layer_sum(L_RENDEZVOUS))
 registry.register_pvar(
     "trace", "", "hist_bucket_bounds_us", var_class="size",
     help="Upper bounds (us) of the fixed log2 latency buckets shared "
@@ -942,63 +1235,112 @@ def coll_seq(comm) -> int:
     return s
 
 
-def coll_begin(comm, name_id: int, _peruse=peruse, _CAT=CAT_COLL):
+def coll_begin(comm, name_id: int, _peruse=peruse, _CAT=CAT_COLL,
+               _pcns=time.perf_counter_ns):
     """Blocking-collective entry.  ``name_id`` is the collective's
     interned span name (the merged-vtable shim interns once at wrap
     time).  Returns an opaque token for coll_end: None when both
     observability systems are off (the shim passes straight through),
-    0 when the span was sampled out (the seq still advanced — the
-    cross-rank counter must tick identically on every member, and the
-    shim skips coll_end entirely), a positive ns start otherwise, or
-    a tuple on the cold peruse path.
+    0 when the span was sampled out (the seq still advanced: the
+    cross-rank counter must tick identically on every member; the
+    shim then skips coll_end), -1 when it was sampled out but the
+    phase profiler has a layer account to close at the return, a
+    positive ns start otherwise, or a tuple when PERUSE listens.
 
-    The default-arg bindings turn module-global lookups into local
-    loads, and the sampled-out branch of start_sampled is inlined: in
-    steady state (63-in-64 once a category is hot) this path is the
-    whole per-op cost of tracing, and on the 1-core bench box every
-    GIL-held instruction here is multiplied by the rank count."""
-    if _peruse.enabled:
-        return _coll_begin_slow(comm, name_id)
+    The keep-or-skip decision is Tracer.keep's, inlined: on the
+    sequence number, the same on every member; sampled out with the
+    phase profiler off it takes no clock read and makes no call.  With
+    the profiler armed this is also the first boundary of the
+    operation's layer account: it closes the open caller interval,
+    opens the entry interval, and files the spans the dispatcher
+    thread closed for this rank since its last operation.  Not a
+    Tracer method: the shim runs on every collective of every rank."""
     tr = comm.state.tracer
     if tr is None:
+        if _peruse.enabled:
+            return _coll_begin_slow(comm, name_id, coll_seq(comm), 0)
         return None
-    comm._coll_seq = comm._coll_seq + 1
-    ctr = tr._ctr
-    c = ctr[_CAT]
-    if c:
-        ctr[_CAT] = c - 1
+    seq = comm._coll_seq + 1
+    comm._coll_seq = seq
+    if not tr._plo <= seq < tr._phi:
+        tr._restep(seq)
+    if tr.phase:
+        now = _pcns()
+        r = tr._t_ret
+        if r:
+            tr._lns[L_CALLER] += now - r
+            tr._t_ret = 0
+        tr._t_cur = now
+        tr._cur_k = L_ENTRY
+        if tr._done:
+            tr.file_done()
+        if seq % tr._period[_CAT]:
+            tr._skipped[_CAT] += 1
+            now = -1
+    elif seq % tr._period[_CAT]:
         tr._skipped[_CAT] += 1
-        return 0
-    return tr.start_sampled(_CAT)
+        now = 0
+    else:
+        now = _pcns()
+    if _peruse.enabled:
+        return _coll_begin_slow(comm, name_id, seq, now)
+    return now
 
 
-def coll_end(comm, name_id: int, token) -> None:
+def coll_end(comm, name_id: int, token, _pcns=time.perf_counter_ns) -> None:
+    """Blocking-collective return (the shim calls it for every truthy
+    token).  With the phase profiler armed this is the last boundary:
+    an operation that woke from a rendezvous banks its exit interval
+    and opens the caller interval; one that never reached a rendezvous
+    (a host collective) banks nothing and opens none."""
     if type(token) is int:
-        if token:
-            tr = comm.state.tracer
-            if tr is not None:
-                tr.end(token, name_id, CAT_COLL, comm.cid,
-                       comm._coll_seq)
+        seq = comm._coll_seq
+        fire = False
+    elif token is None:
         return
-    if token is not None:
-        _coll_end_slow(comm, name_id, token)
+    else:
+        seq = token[0]
+        token = token[1]
+        fire = True
+    tr = comm.state.tracer
+    if tr is not None and token:
+        now = _pcns()
+        c = 0
+        if tr.phase:
+            c = tr._t_cur
+            tr._t_cur = 0
+            if c and tr._cur_k == L_EXIT:
+                tr._lns[L_EXIT] += now - c
+                tr._t_ret = now
+                if not tr._plo <= seq < tr._phi:
+                    tr._restep(seq)
+                if seq % tr._period[CAT_PHASE]:
+                    c = 0
+            else:
+                c = 0
+        # c: the start of a ph_exit span to record; token > 0: of the
+        # coll span.  Both at once take one store call
+        if c:
+            if token > 0:
+                tr.end_at2(c, now, NAME_PH_EXIT, CAT_PHASE,
+                           token, now, name_id, CAT_COLL, comm.cid, seq)
+            else:
+                tr.end_at(c, now, NAME_PH_EXIT, CAT_PHASE, comm.cid, seq)
+        elif token > 0:
+            tr.end_at(token, now, name_id, CAT_COLL, comm.cid, seq)
+    if fire:
+        _coll_end_slow(comm, name_id, seq)
 
 
-def _coll_begin_slow(comm, name_id: int):
-    seq = coll_seq(comm)
+def _coll_begin_slow(comm, name_id: int, seq: int, t0: int):
+    """The PERUSE half of coll_begin (cold: builds the event's kwargs
+    and the token tuple)."""
     peruse.fire("coll_begin", cid=comm.cid, coll=_names[name_id],
                 seq=seq)
-    tr = comm.state.tracer
-    t0 = tr.start_sampled(CAT_COLL) if tr is not None else 0
     return (seq, t0)
 
 
-def _coll_end_slow(comm, name_id: int, token) -> None:
-    seq, t0 = token
-    if t0:
-        tr = comm.state.tracer
-        if tr is not None:
-            tr.end(t0, name_id, CAT_COLL, comm.cid, seq)
+def _coll_end_slow(comm, name_id: int, seq: int) -> None:
     peruse.fire("coll_end", cid=comm.cid, coll=_names[name_id],
                 seq=seq)
 
