@@ -22,7 +22,9 @@ rendezvous machinery:
   selected by rank parity (the MPICH operand-order discipline), so
   all ranks evaluate the identical expression tree.
 * **ring bcast / pairwise alltoall** — segmented data movement on the
-  same machinery (bit-exact by construction).
+  same machinery (bit-exact by construction), over the mesh only: on
+  one chip (coll/hbm) there is no wire to overlap with, and both stay
+  one rendezvous and one kernel or handoff at every size.
 
 **Pipelining**: segments run through the asynchronous rendezvous
 (``device.meet_begin``/``meet_finish``): a rank deposits segment k and
@@ -610,53 +612,6 @@ def _hbm_seg_reduce(module, comm, x, op):
     return _unpack_trim(comm, outs, n, seg).reshape(shape)
 
 
-def _hbm_seg_alltoall(module, comm, x):
-    import jax.numpy as jnp
-    x = module._deposit(comm, x)
-    size = comm.size
-    shape = x.shape
-    rows = x.reshape(size, -1)
-    cols = rows.shape[1]
-    dtype = rows.dtype
-    seg = segment_elems(comm, dtype.itemsize)
-    m = max(1, seg // size)
-    seg = m * size
-    jbody, out_map = module._stacked("alltoall", "", size, (seg,), dtype)
-
-    def fn(shards):
-        return out_map(jbody(*shards), size)
-
-    if comm.state.tracer is not None:
-        # the phase profiler's twin (device._phase_fn)
-        from ompi_tpu.coll import device
-        fn.traced = functools.partial(device._stacked_exec, jbody,
-                                      out_map, size)
-
-    def jobs():
-        for lo in range(0, cols, m):
-            sub = rows[:, lo:lo + m]
-            if sub.shape[1] < m:
-                sub = jnp.concatenate(
-                    [sub, jnp.zeros((size, m - sub.shape[1]), dtype)],
-                    axis=1)
-            yield sub.reshape(-1), fn
-
-    ck = _ig.spec("alltoall", "", rows) if _ig.on else None
-    outs = _run_pipelined(module, comm, jobs(), ck)
-    tr = comm.state.tracer
-    t0 = tr.lap() if tr is not None and tr.phase else 0
-    pieces = [o.reshape(size, m) for o in outs]
-    tail = cols - (len(pieces) - 1) * m
-    if tail != m:
-        pieces = pieces[:-1] + [pieces[-1][:, :tail]]
-    full = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces,
-                                                              axis=1)
-    full = full.reshape(shape)
-    if t0:
-        _unpack_end(tr, comm, t0)
-    return full
-
-
 # ---------------------------------------------------------------------------
 # hierarchical tier
 # ---------------------------------------------------------------------------
@@ -751,12 +706,10 @@ def maybe_device_coll(module, comm, kind: str, x, op=None, root=None):
     t0 = tr.start() if tr is not None and tr.keep(
         _trace.CAT_COLL_DISPATCH, comm._coll_seq) else 0
     if module.name == "hbm":
-        if kind == "allreduce":
-            out = _hbm_seg_reduce(module, comm, x, op)
-        elif kind == "alltoall":
-            out = _hbm_seg_alltoall(module, comm, x)
-        else:
-            return UNHANDLED  # hbm bcast: one shared-HBM handoff already
+        # coll/hbm consults the tier for allreduce alone: with no wire
+        # to overlap, its alltoall is one stacked kernel and its bcast
+        # one shared-HBM handoff at every size
+        out = _hbm_seg_reduce(module, comm, x, op)
     elif alg == "hier":
         out = _hier_allreduce(module, comm, x, op)
     elif kind == "allreduce":

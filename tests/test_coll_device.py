@@ -268,6 +268,91 @@ def test_hbm_alltoall_2d():
                 + src * 100)
 
 
+_A2A_SHAPES = {
+    "flat": lambda p: (1024 * p,),
+    "trailing": lambda p: (64 * p, 3, 5),
+    "odd_block": lambda p: (1009 * p,),
+}
+_A2A_OPS = 3
+
+
+def _a2a_input(shape, dtype, rank):
+    return (np.arange(int(np.prod(shape))) % 251 + rank) \
+        .reshape(shape).astype(dtype)
+
+
+def _a2a_world(shape, dtype, min_bytes):
+    """One 4-rank world on one device: _A2A_OPS alltoall_arr calls of
+    ``shape`` with the large-message tier starting at ``min_bytes``,
+    traced so that the layer account counts the rendezvous.  Per rank:
+    the bytes, what the result is, and what moved."""
+    from ompi_tpu.coll import pipeline
+    from ompi_tpu.mca.params import registry
+    hbm = registry.register_pvar("coll", "hbm", "offloaded_collectives")
+
+    def fn(comm):
+        assert comm.coll.providers["alltoall_arr"] == "hbm"
+        tr = comm.state.tracer
+        x = _put(comm, _a2a_input(shape, dtype, comm.rank))
+        assert x.nbytes >= 2048
+
+        def counters():
+            return (pipeline.pv_ops.read(), pipeline.pv_segments.read(),
+                    hbm.read(), tr.layer_totals()["rendezvous"])
+
+        comm.Barrier()
+        before = counters()
+        for _ in range(_A2A_OPS):
+            r = comm.alltoall_arr(x)
+        comm.Barrier()
+        return (np.asarray(r).tobytes(), r.shape, str(r.dtype),
+                isinstance(r, jax.Array) and r.devices() == {comm.device},
+                [b - a for a, b in zip(before, counters())])
+
+    knobs = {"coll_pipeline_enable": True, "coll_seg_size": 4096,
+             "coll_pipeline_min_bytes": min_bytes, "trace_enable": True,
+             "trace_phase_enable": True, "trace_dump_path": ""}
+    saved = {k: registry.get(k) for k in knobs}
+    for k, v in knobs.items():
+        registry.set(k, v)
+    try:
+        return run_ranks(4, fn, device_map=_one_dev)
+    finally:
+        for k, v in saved.items():
+            registry.set(k, v)
+
+
+@pytest.mark.parametrize("dtype", ["int32", "float32", "bfloat16"])
+@pytest.mark.parametrize("shape_name", sorted(_A2A_SHAPES))
+def test_hbm_alltoall_is_one_stacked_kernel_at_every_size(shape_name, dtype):
+    """On one device an alltoall above coll_pipeline_min_bytes is the
+    stacked path too (ISSUE 27): the bytes of numpy's exchange and of
+    the below-threshold call, in the caller's shape, a jax.Array on
+    the rank's device, one rendezvous an operation, and the pipeline's
+    counters at rest."""
+    P = 4
+    shape = _A2A_SHAPES[shape_name](P)
+    dt = jnp.dtype(dtype)
+    above = _a2a_world(shape, dt, 2048)
+    below = _a2a_world(shape, dt, 1 << 40)
+    send = [_a2a_input(shape, dt, k) for k in range(P)]
+    m = shape[0] // P
+    for k, (got, want) in enumerate(zip(above, below)):
+        data, rshape, rdtype, on_device, moved = got
+        ref = np.concatenate([send[src][k * m:(k + 1) * m]
+                              for src in range(P)])
+        assert data == ref.tobytes()
+        assert data == want[0]
+        assert rshape == shape and rdtype == dtype
+        assert on_device
+        d_ops, d_segs, d_hbm, d_rdv = moved
+        assert d_ops == 0 and d_segs == 0
+        assert d_rdv == _A2A_OPS == want[4][3]
+        # a process-wide counter, bumped by every rank-thread (a plain
+        # += shared by the threads: allow it a lost update)
+        assert abs(d_hbm - P * _A2A_OPS) <= 1
+
+
 def test_arr_shapes_consistent_across_providers():
     """allgather/alltoall/reduce_scatter must return identical shapes
     whether served by tpu, hbm, or the host fallback."""

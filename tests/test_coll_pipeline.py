@@ -149,8 +149,11 @@ def test_segmented_mixed_dtypes():
 
 
 def test_segmented_hbm_byte_identical():
-    """Co-located ranks (one shared device): the hbm segmentation path
-    — per-segment stacked kernels — is bytewise the monolithic one."""
+    """Co-located ranks (one shared device): the segmented hbm
+    allreduce (per-segment stacked kernels) is bytewise the monolithic
+    one.  The hbm alltoall is the stacked whole-payload path on both
+    sides (never segmented on one device): its bytes must not depend
+    on the tier's knobs."""
     def _one_dev(r):
         return jax.devices()[0]
 

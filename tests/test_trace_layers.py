@@ -293,17 +293,21 @@ def test_keep_is_a_pure_function_of_shared_parameters():
 
 # -- A: the layer accumulators close ----------------------------------------
 
-def _closure_world(n_ops, make_x, knobs, **kw):
+def _allreduce_sum(comm, x):
+    return comm.allreduce_arr(x, mpi_op.SUM)
+
+
+def _closure_world(n_ops, make_x, knobs, call=_allreduce_sum, **kw):
     def fn(comm):
         tr = comm.state.tracer
         x = make_x(comm)
         for _ in range(5):
-            jax.block_until_ready(comm.allreduce_arr(x, mpi_op.SUM))
+            jax.block_until_ready(call(comm, x))
         comm.Barrier()
         before = tr.layer_totals()
         t0 = time.perf_counter_ns()
         for _ in range(n_ops):
-            jax.block_until_ready(comm.allreduce_arr(x, mpi_op.SUM))
+            jax.block_until_ready(call(comm, x))
         comm.Barrier()    # its entry closes the last caller interval
         wall = time.perf_counter_ns() - t0
         after = tr.layer_totals()
@@ -387,6 +391,31 @@ def test_layer_account_on_one_chip_plan_path():
     serve = max(d["rdv_serve"] for _w, d in res)
     assert 0 < launch <= serve
     assert sum(d["scatter"] for _w, d in res) > 0
+
+
+def test_layer_account_on_one_chip_alltoall_above_threshold():
+    """coll/hbm alltoall above coll_pipeline_min_bytes (the benchmark's
+    alltoall cell, test-sized): the stacked path at every size, so one
+    rendezvous per operation, nothing packed or unpacked, the
+    pipeline's counters at rest, and closure within 3%."""
+    from ompi_tpu.coll import pipeline
+    moved0 = (pipeline.pv_ops.read(), pipeline.pv_segments.read())
+    knobs = {"coll_pipeline_enable": True, "coll_pipeline_min_bytes": 2048,
+             "coll_seg_size": 4096}
+    res = _closure_world(
+        100, lambda comm: jax.device_put(
+            jnp.arange(4096, dtype=jnp.float32) + comm.rank, comm.device),
+        knobs, call=lambda comm, x: comm.alltoall_arr(x),
+        device_map=_one_dev)
+    for wall, d in res:
+        total = sum(d[k] for k in trace.LAYER_CLOSURE)
+        assert abs(total - wall) <= 0.03 * wall, (total, wall, d)
+        assert d["rendezvous"] == 100
+        assert d["pack"] == 0 and d["unpack"] == 0
+        assert d["assemble"] == 0
+    assert 0 < sum(d["launch"] for _w, d in res) <= max(
+        d["rdv_serve"] for _w, d in res)
+    assert (pipeline.pv_ops.read(), pipeline.pv_segments.read()) == moved0
 
 
 def test_host_collectives_stay_out_of_the_account():
@@ -482,7 +511,9 @@ def test_new_sites_read_no_clock_when_tracing_is_off(monkeypatch):
     """trace_enable off: the rendezvous, the publisher's steps, the
     plan resolution and the pipeline's pack and unpack stages take no
     timestamp (coll/device's clock explodes; no Tracer exists to read
-    its own)."""
+    its own).  The pipeline's stages run under the allreduce with the
+    plan off and under the mesh alltoall; the one-device alltoall is
+    the stacked path in every variant."""
     assert not trace.enable_var.value
 
     def boom():
