@@ -130,6 +130,12 @@ _hier_min_var = registry.register(
 pv_segments = registry.register_pvar(
     "coll", "pipeline", "segments",
     help="Segments dispatched through the pipelined rendezvous")
+pv_inflight = registry.register_pvar(
+    "coll", "pipeline", "inflight",
+    help="Segments outstanding on the calling rank, the new one "
+         "included, summed at every segment begun: over "
+         "coll_pipeline_segments it is the mean depth the pipeline "
+         "reached (1.0 = nothing overlapped)")
 pv_ops = registry.register_pvar(
     "coll", "pipeline", "ops",
     help="Collectives routed to the segmented large-message tier")
@@ -305,6 +311,10 @@ def _build_seg_kernel(kind: str, mesh, seg_elems: int, dtype,
     else:
         raise KeyError(kind)
 
+    # a stable program name: the profiler's device plane shows
+    # jit_ompi_<kind>(<fingerprint>), so a trace reduction can tell the
+    # exchange from the pack and unpack programs around it
+    body.__name__ = body.__qualname__ = "ompi_" + kind
     return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=False))
 
@@ -372,6 +382,7 @@ def _run_pipelined(module, comm, jobs, ck=None) -> List[Any]:
             handles.append(device.meet_begin(comm, value, fn, check,
                                              ck, ph))
             pv_segments.add(1)
+            pv_inflight.add(len(handles))
             if len(handles) > depth:
                 outs.append(device.meet_finish(comm, handles.popleft(),
                                                check))
