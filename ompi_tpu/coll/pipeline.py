@@ -4,12 +4,11 @@ engine — segmented, pipelined, topology-aware algorithms.
 The fused fast path (docs/DESIGN.md §8) owns the small-message regime:
 ONE assembled shard_map per collective, dispatch constant amortized by
 batching.  Large messages invert the trade — the payload dominates and
-the single monolithic dispatch serializes host packing, device compute
-and unpacking end to end.  This module is the re-design of the
-reference's segmented algorithms (ref: coll_tuned_decision_fixed.c:72
-segmented ring above 1 MiB; coll_base_allreduce.c:343 ring
-reduce-scatter + allgather; Rabenseifner's decomposition) on the
-rendezvous machinery:
+the operation is worth an algorithm chosen for its size.  This module
+is the re-design of the reference's segmented algorithms (ref:
+coll_tuned_decision_fixed.c:72 segmented ring above 1 MiB;
+coll_base_allreduce.c:343 ring reduce-scatter + allgather;
+Rabenseifner's decomposition) on the rendezvous machinery:
 
 * **segring** — chunked ``ppermute`` ring allreduce: inside one
   compiled kernel per segment, P-1 reduce-scatter steps (each rank
@@ -26,12 +25,18 @@ rendezvous machinery:
   one chip (coll/hbm) there is no wire to overlap with, and both stay
   one rendezvous and one kernel or handoff at every size.
 
-**Pipelining**: segments run through the asynchronous rendezvous
-(``device.meet_begin``/``meet_finish``): a rank deposits segment k and
-immediately starts packing (slice + pad) segment k+1 on its own thread
-while the dispatcher thread drives the device through segment k — the
-pack → dispatch → unpack stages of consecutive segments overlap, depth
-bounded by ``coll_pipeline_depth``.
+**One program, one rendezvous** is what serves every operation routed
+here by default (coll/plan.py, DESIGN.md §22): the allreduce
+algorithms, and since PR 29 the mesh bcast and alltoall too.  The
+per-segment bodies below run only with ``coll_plan_enable=0``.
+
+**Pipelining** (the per-segment path): segments run through the
+asynchronous rendezvous (``device.meet_begin``/``meet_finish``), a
+rank depositing segment k and then slicing segment k+1 while the
+dispatcher thread launches segment k, depth bounded by
+``coll_pipeline_depth``.  On the chip the stages did not overlap the
+wire: a 1 MiB slice costs a rank 1.1 to 2.9 ms of host time for 25 us
+of wire, so the device idled 93 to 98% (PERF.md §6, PR 28 and PR 29).
 
 **Segment-size discipline**: every segment of every message is padded
 to ONE fixed per-host segment shape (op identity elements; sliced off
@@ -498,6 +503,12 @@ def _mesh_seg_reduce(module, comm, x, op, alg: str):
 
 
 def _mesh_seg_bcast(module, comm, x, root: int):
+    """Large mesh bcast: the compiled-plan path (one whole-payload
+    program, one rendezvous) when enabled, else the ring-circulation
+    kernel pipelined per segment."""
+    pl = _plan()
+    if pl.enabled():
+        return pl.mesh_move(module, comm, x, "segbcast", root)
     import jax.numpy as jnp
     from ompi_tpu.coll import device
     mesh = comm.mesh()
@@ -530,9 +541,14 @@ def _mesh_seg_bcast(module, comm, x, root: int):
 
 
 def _mesh_seg_alltoall(module, comm, x):
-    """Segmented pairwise alltoall: segment k covers columns
-    [k*m, (k+1)*m) of EVERY destination block, so each segment is a
-    (P, m) exchange hitting one compiled shape."""
+    """Large mesh alltoall: the compiled-plan path (one whole-payload
+    program, one rendezvous) when enabled, else the segmented pairwise
+    exchange: segment k covers columns [k*m, (k+1)*m) of EVERY
+    destination block, so each segment is a (P, m) exchange hitting
+    one compiled shape."""
+    pl = _plan()
+    if pl.enabled():
+        return pl.mesh_move(module, comm, x, "sega2a")
     import jax.numpy as jnp
     from ompi_tpu.coll import device
     mesh = comm.mesh()

@@ -46,6 +46,12 @@ one.  Other ops, and all ops with the knob off, keep the faithful
 batched ring / recursive-doubling schedule, which real multi-slice
 topologies may prefer.
 
+Data movement: a mesh bcast or alltoall that ``tuned.device_algorithm``
+routes to the tier (``segbcast`` / ``sega2a``) is planned the same way
+(``mesh_move``): one program named for its algorithm, every slice
+inside the jit, one rendezvous.  Bit-exact for every bit pattern (no
+arithmetic touches the payload), so not the fused path's masked psum.
+
 DESIGN.md §22.
 """
 
@@ -76,7 +82,8 @@ _enable_var = registry.register(
     "coll", "plan", "enable", True, bool,
     help="Compile one jitted multi-segment program per (alg, mesh, "
          "segment geometry, dtype, op) and run each large-message "
-         "allreduce as ONE rendezvous + ONE dispatch (DESIGN.md §22); "
+         "allreduce, mesh bcast and mesh alltoall as ONE rendezvous + "
+         "ONE dispatch (DESIGN.md §22); "
          "0 = the per-segment pipelined rendezvous path")
 
 _cache_max_var = registry.register(
@@ -112,6 +119,8 @@ _ALG_ID = {
     "segring": _trace.intern_name("segring"),
     "segrd": _trace.intern_name("segrd"),
     "hbm": _trace.intern_name("hbm"),
+    "segbcast": _trace.intern_name("segbcast"),
+    "sega2a": _trace.intern_name("sega2a"),
 }
 
 
@@ -219,18 +228,52 @@ def _pack(comm, flat, n: int, plan: Plan):
             [jnp.asarray(flat),
              jnp.full((plan.total - n,), plan.pad_val, plan.np_dtype)])
     if t0:
-        t1 = tr.lap_to(_L_PACK, _L_ENTRY)
-        seq = comm._coll_seq
-        if tr.kept(_CAT_PHASE, seq):
-            tr.end_at(t0, t1, _NAME_PH_PACK, _CAT_PHASE,
-                      comm.cid, seq, n * plan.itemsize)
+        _pack_end(tr, comm, t0, n * plan.itemsize)
     return value
+
+
+def _pack_end(tr, comm, t0: int, nbytes: int) -> None:
+    """The end of a pack stage that started at ``t0`` (Tracer.lap):
+    banked in the ``pack`` accumulator, recorded as ph_pack on a kept
+    op.  Only reached with the phase profiler armed."""
+    t1 = tr.lap_to(_L_PACK, _L_ENTRY)
+    seq = comm._coll_seq
+    if tr.kept(_CAT_PHASE, seq):
+        tr.end_at(t0, t1, _NAME_PH_PACK, _CAT_PHASE, comm.cid, seq, nbytes)
 
 
 def _unpack(comm, out, n: int, plan: Plan):
     tr = comm.state.tracer
     t0 = tr.lap() if tr is not None and tr.phase else 0
     res = out[:n]
+    if t0:
+        _pl._unpack_end(tr, comm, t0, n * plan.itemsize)
+    return res
+
+
+def _pack_rows(comm, flat, n: int, plan: Plan):
+    """``_pack`` for an alltoall: ``flat`` is comm.size destination
+    blocks of n // size elements, and each block is zero-padded at its
+    own end to the plan's total // size (a pad at the vector's end
+    would shift every block but the first)."""
+    tr = comm.state.tracer
+    t0 = tr.lap() if tr is not None and tr.phase else 0
+    import jax.numpy as jnp
+    size = comm.size
+    rows = jnp.asarray(flat).reshape(size, n // size)
+    value = jnp.pad(
+        rows, ((0, 0), (0, (plan.total - n) // size))).reshape(-1)
+    if t0:
+        _pack_end(tr, comm, t0, n * plan.itemsize)
+    return value
+
+
+def _unpack_rows(comm, out, n: int, plan: Plan):
+    """``_unpack`` for an alltoall: trim each source block's pad."""
+    tr = comm.state.tracer
+    t0 = tr.lap() if tr is not None and tr.phase else 0
+    size = comm.size
+    res = out.reshape(size, plan.total // size)[:, :n // size].reshape(-1)
     if t0:
         _pl._unpack_end(tr, comm, t0, n * plan.itemsize)
     return res
@@ -337,7 +380,6 @@ def _compile_mesh(alg: str, mesh, size: int, nsegs: int, seg: int,
 
 def _build_mesh_plan(comm, alg: str, nsegs: int, seg: int, np_dtype,
                      opname: str, donate: bool) -> Plan:
-    from jax.sharding import NamedSharding, PartitionSpec as P
     mesh = comm.mesh()
     size = comm.size
     devs = list(mesh.devices.reshape(-1))
@@ -354,6 +396,19 @@ def _build_mesh_plan(comm, alg: str, nsegs: int, seg: int, np_dtype,
     jfn = _dev.compile_cache.get(
         ckey, lambda: _compile_mesh(alg, mesh, size, nsegs, seg,
                                     np_dtype, opname, native, donate))
+    return Plan(alg, nsegs, seg, np_dtype,
+                _pl._pad_value(opname, np_dtype),
+                _mesh_meet_fn(mesh, size, jfn), _dev.meet,
+                devs[comm.rank],
+                _ig.spec_static("allreduce", opname,
+                                np.empty(0, np_dtype)))
+
+
+def _mesh_meet_fn(mesh, size: int, jfn):
+    """The meeting's computation of a mesh plan: assemble the global
+    array on the prebuilt sharding, call the compiled program, hand
+    each rank the part on its own device."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
     sharding = NamedSharding(mesh, P("r"))
 
     def fn(shards, _m=mesh, _sh=sharding, _j=jfn, _n=size):
@@ -363,12 +418,7 @@ def _build_mesh_plan(comm, alg: str, nsegs: int, seg: int, np_dtype,
     # the phase profiler's twin (device._phase_fn), built once with
     # the plan; untraced, the body above is what runs
     fn.traced = functools.partial(_dev._mesh_exec, mesh, size, jfn, sharding)
-
-    return Plan(alg, nsegs, seg, np_dtype,
-                _pl._pad_value(opname, np_dtype), fn, _dev.meet,
-                devs[comm.rank],
-                _ig.spec_static("allreduce", opname,
-                                np.empty(0, np_dtype)))
+    return fn
 
 
 def mesh_reduce(module, comm, x, op, alg: str):
@@ -391,8 +441,10 @@ def mesh_reduce(module, comm, x, op, alg: str):
     nsegs, seg = _plan_segments(
         comm, n, _pl.segment_elems(comm, np_dtype.itemsize))
     # donation is only sound when the pack stage owns the padded
-    # buffer; exact-fit payloads flow the caller's array straight in
-    donate = nsegs * seg != n
+    # buffer; exact-fit payloads flow the caller's array straight in.
+    # Never while the integrity plane is armed: after a mismatch it
+    # re-reads every deposited operand
+    donate = nsegs * seg != n and not _ig.on
     pkey = ("mesh", alg, nsegs, seg, np_dtype.str, op.name, donate)
     plan = _resolve(
         comm, pkey,
@@ -400,6 +452,110 @@ def mesh_reduce(module, comm, x, op, alg: str):
                                  op.name, donate))
     _pl.pv_segments.add(nsegs)
     out = plan.execute(module, comm, flat, n)
+    return out if shape is None else out.reshape(shape)
+
+
+# -- mesh data-movement plans (bcast, alltoall) -----------------------------
+
+def _compile_mesh_move(alg: str, mesh, size: int, total: int, root):
+    """The ONE jitted program of a large mesh bcast or alltoall: a
+    rank's (total,) in P("r").  Named for the algorithm whose schedule
+    it fuses (the profiler's device plane shows jit_ompi_<alg>), as the
+    per-segment kernels of pipeline._build_seg_kernel are.  The payload
+    stays 1-D throughout: a (size, m) view of a tiled 1-D array is a
+    relayout, which compiles to a copy loop over the whole payload.
+    Nothing is donated: the integrity plane re-reads a deposited
+    operand after a mismatch."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    if alg == "segbcast":
+        # scatter then allgather: stripe j of the root's payload goes
+        # to rank j over one one-pair permute (the others receive
+        # zeros and keep what they had: a select, never an add, so
+        # -0.0 and NaN payloads arrive as sent), then one tiled
+        # all-gather.  Each chip receives about `total` over the wire,
+        # the least a bcast needs
+        m = total // size
+
+        def body(x):
+            i = lax.axis_index("r")
+
+            def stripe(j):
+                return lax.slice_in_dim(x, j * m, (j + 1) * m)
+
+            mine = stripe(root)
+            for j in range(size):
+                if j != root:
+                    got = lax.ppermute(stripe(j), "r", perm=[(root, j)])
+                    mine = jnp.where(i == j, got, mine)
+            return lax.all_gather(mine, "r", tiled=True)
+
+        out_specs = P(None)
+    else:
+        # block j of every rank to rank j, filed by source: the
+        # runtime's own all-to-all, which the path under the crossover
+        # already trusts
+        def body(x):
+            return lax.all_to_all(x, "r", split_axis=0, concat_axis=0,
+                                  tiled=True)
+
+        out_specs = P("r")
+
+    body.__name__ = body.__qualname__ = "ompi_" + alg
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("r"),
+                                 out_specs=out_specs, check_vma=False))
+
+
+def _build_move_plan(comm, alg: str, nsegs: int, seg: int, np_dtype,
+                     root) -> Plan:
+    mesh = comm.mesh()
+    size = comm.size
+    devs = list(mesh.devices.reshape(-1))
+    dev_key = tuple(d.id for d in devs)
+    ckey = ("plan_" + alg, dev_key, (nsegs * seg,), np_dtype.str, root)
+    jfn = _dev.compile_cache.get(
+        ckey, lambda: _compile_mesh_move(alg, mesh, size, nsegs * seg,
+                                         root))
+    kind = "bcast" if alg == "segbcast" else "alltoall"
+    return Plan(alg, nsegs, seg, np_dtype, np_dtype.type(0),
+                _mesh_meet_fn(mesh, size, jfn), _dev.meet,
+                devs[comm.rank],
+                _ig.spec_static(kind, "", np.empty(0, np_dtype),
+                                root or 0))
+
+
+def mesh_move(module, comm, x, alg: str, root=None):
+    """Plan-path mesh bcast (``segbcast``, from ``root``) or alltoall
+    (``sega2a``): one program over the whole payload behind one
+    rendezvous.  A size that is not a whole number of segments is
+    zero-padded to the plan's shape (an alltoall's blocks each at
+    their own end) and trimmed after, so the compiled programs stay
+    keyed by segment count, never by message size."""
+    import jax.numpy as jnp
+
+    if getattr(x, "ndim", None) == 1:
+        shape, flat = None, x  # no same-shape reshape dispatch
+    else:
+        shape = x.shape
+        flat = jnp.asarray(x).reshape(-1)
+    n = int(flat.shape[0])
+    np_dtype = np.dtype(flat.dtype)
+    nsegs, seg = _plan_segments(
+        comm, n, _pl.segment_elems(comm, np_dtype.itemsize))
+    pkey = ("mesh", alg, nsegs, seg, np_dtype.str, root)
+    plan = _resolve(
+        comm, pkey,
+        lambda: _build_move_plan(comm, alg, nsegs, seg, np_dtype, root))
+    if alg == "sega2a" and n != plan.total:
+        out = _unpack_rows(
+            comm, plan.execute(module, comm,
+                               _pack_rows(comm, flat, n, plan), plan.total),
+            n, plan)
+    else:
+        out = plan.execute(module, comm, flat, n)
     return out if shape is None else out.reshape(shape)
 
 

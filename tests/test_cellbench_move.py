@@ -7,6 +7,10 @@ plain references on the CPU.
   count that leaves a tail; ``alltoall_arr`` equals
   cellbench/reference.py on every rank; both equal the fused
   single-dispatch path byte for byte;
+* the same through the compiled plans (ISSUE 29, the default): equal to
+  the references and to the per-segment path, every bit pattern
+  delivered as sent, one rendezvous a call, the pipeline's counters at
+  rest, programs named for their algorithms and free of arithmetic;
 * the lower-precision control (inputs handed over in bfloat16) and an
   answer that is the rank's own input are NOT correct;
 * the rooted reference against a two-line numpy statement of itself,
@@ -52,7 +56,11 @@ SEGMENTED = {"coll_pipeline_enable": True, "coll_pipeline_min_bytes": 2048,
              "coll_seg_size": 4 * SEG_ELEMS, "coll_pipeline_depth": DEPTH,
              "coll_pipeline_rd_max_bytes": 0, "coll_hier_enable": False,
              "coll_plan_enable": False}
+# the default knobs but for the test-sized crossover and segment: the
+# compiled plans (coll/plan.mesh_move) serve both operations
+PLANNED = {"coll_pipeline_min_bytes": 2048, "coll_seg_size": 4 * SEG_ELEMS}
 FUSED = {"coll_pipeline_enable": False, "coll_hier_enable": False}
+TIERS = {"segmented": SEGMENTED, "planned": PLANNED, "fused": FUSED}
 # elements per rank: (bcast, alltoall); the tail case leaves 3 elements
 # of a bcast segment and 7 columns of an alltoall segment over
 COUNTS = {"whole": (4 * SEG_ELEMS, 4 * SEG_ELEMS),
@@ -88,7 +96,7 @@ def answers(tier: str, count: str, control=None):
         out["provider"] = comm.coll.providers.get("bcast_arr")
         return out
 
-    saved = knobs_set(SEGMENTED if tier == "segmented" else FUSED)
+    saved = knobs_set(TIERS[tier])
     try:
         return run_ranks(P, body, devices=True, timeout=240)
     finally:
@@ -106,6 +114,34 @@ def alltoall_gap(got, rank, n):
 
 
 # -- the library against the references --------------------------------------
+
+@pytest.mark.parametrize("count", ["whole", "tail"])
+@pytest.mark.parametrize("root", range(P))
+def test_planned_bcast_equals_the_reference_and_the_segments(root, count):
+    n = COUNTS[count][0]
+    plan, seg = answers("planned", count), answers("segmented", count)
+    for rank in range(P):
+        got = plan[rank]["bcast", root]
+        assert got.dtype == np.float32 and got.shape == (n,)
+        assert bcast_gap(got, rank, root, n) == 0.0
+        assert got.tobytes() == reference.values(SEED, root, 0, n).tobytes()
+        assert got.tobytes() == seg[rank]["bcast", root].tobytes()
+    ops, segs, inflight = plan[0]["moved"]
+    assert plan[0]["provider"] == "tpu"
+    assert ops == P * (P + 1)              # the tier counted every call
+    assert (segs, inflight) == (0, 0)      # and no segment ran
+
+
+@pytest.mark.parametrize("count", ["whole", "tail"])
+def test_planned_alltoall_equals_the_reference_and_the_segments(count):
+    n = COUNTS[count][1]
+    plan, seg = answers("planned", count), answers("segmented", count)
+    for rank in range(P):
+        got = plan[rank]["alltoall"]
+        assert got.dtype == np.float32 and got.shape == (n,)
+        assert alltoall_gap(got, rank, n) == 0.0
+        assert got.tobytes() == seg[rank]["alltoall"].tobytes()
+
 
 @pytest.mark.parametrize("count", ["whole", "tail"])
 @pytest.mark.parametrize("root", range(P))
@@ -236,6 +272,96 @@ def test_compared_ranks_hold_two_that_are_not_the_root(root):
         assert (root - 1) % P in pick and pick <= set(range(P))
     assert blocking_rooted.picked_ranks(
         np.random.default_rng(0), 2, root % 2, 3) == {0, 1}
+
+
+# -- the compiled plans of the two operations (ISSUE 29) ------------------------
+
+def odd_bits(n: int) -> np.ndarray:
+    """float32 patterns arithmetic would not keep: both zeros, NaNs
+    with payloads (quiet and signalling, either sign), denormals,
+    infinities; repeated to n elements."""
+    bits = np.array([0x80000000, 0x00000000, 0x7FC12345, 0xFFA00001,
+                     0x7F800001, 0x00000001, 0x807FFFFF, 0x7F800000,
+                     0xFF800000, 0x3F800000], np.uint32)
+    return np.resize(bits, n).view(np.float32)
+
+
+@pytest.mark.parametrize("root", [0, P - 1])
+def test_planned_bcast_delivers_every_bit_pattern(root):
+    n = COUNTS["tail"][0]
+
+    def body(comm):
+        mine = odd_bits(n) if comm.rank == root else np.full(
+            n, comm.rank + 1.0, np.float32)
+        out = comm.bcast_arr(jax.device_put(mine, comm.device), root)
+        return np.asarray(out).view(np.uint32).tobytes()
+
+    saved = knobs_set(PLANNED)
+    try:
+        res = run_ranks(P, body, devices=True, timeout=240)
+    finally:
+        knobs_set(saved)
+    assert set(res) == {odd_bits(n).view(np.uint32).tobytes()}
+
+
+@pytest.mark.parametrize("op", ["bcast", "alltoall"])
+def test_a_planned_call_is_one_rendezvous(op):
+    """One call is one meeting on every rank, nothing passes through
+    the segment pipeline, and the second call of a shape is served by
+    the plan the first resolved."""
+    from ompi_tpu.coll import plan
+    n = COUNTS["tail"][op == "alltoall"]
+
+    def body(comm):
+        tr = comm.state.tracer
+        x = make_input(jax, jnp, comm, SEED, n, None)
+        call = (lambda: comm.bcast_arr(x, 1)) if op == "bcast" else (
+            lambda: comm.alltoall_arr(x))
+
+        def counted():
+            return [tr.layer_totals()["rendezvous"]] + [v.read() for v in (
+                pipeline.pv_ops, pipeline.pv_segments, pipeline.pv_inflight,
+                plan.pv_builds, plan.pv_hits)]
+
+        jax.block_until_ready(call())
+        comm.Barrier()
+        before = counted()
+        comm.Barrier()     # nobody counts before everybody has read
+        jax.block_until_ready(call())
+        comm.Barrier()
+        return [a - b for a, b in zip(counted(), before)]
+
+    saved = knobs_set(dict(PLANNED, trace_enable=True,
+                           trace_phase_enable=True, trace_dump_path=""))
+    try:
+        res = run_ranks(P, body, devices=True, timeout=240)
+    finally:
+        knobs_set(saved)
+    # the rank's own meetings; the process-wide counters, all four ranks'
+    assert res == [[1, P, 0, 0, 0, P]] * P
+
+
+@pytest.mark.parametrize("alg,root", [("segbcast", 1), ("sega2a", None)])
+def test_planned_programs_are_named_and_move_data_only(alg, root):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from ompi_tpu.coll import plan
+    mesh = Mesh(np.array(jax.devices()[:P]), ("r",))
+    total = 5 * SEG_ELEMS
+    jfn = plan._compile_mesh_move(alg, mesh, P, total, root)
+    x = jax.ShapeDtypeStruct((P * total,), jnp.float32,
+                             sharding=NamedSharding(mesh,
+                                                    PartitionSpec("r")))
+    lowered = jfn.lower(x)
+    text = lowered.as_text()
+    assert f"@jit_ompi_{alg} " in text
+    # nothing is added, multiplied or reduced: a select keeps what a
+    # rank had, so -0.0 and a NaN's payload arrive as they were sent
+    for arithmetic in ("stablehlo.add", "stablehlo.multiply",
+                       "stablehlo.all_reduce", "stablehlo.reduce"):
+        assert arithmetic not in text
+    assert ("stablehlo.all_gather" if alg == "segbcast"
+            else "stablehlo.all_to_all") in text
+    assert "all-reduce" not in lowered.compile().as_text()
 
 
 # -- what the program brings for the cells -------------------------------------

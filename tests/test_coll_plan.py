@@ -109,6 +109,37 @@ def test_plan_mesh_byte_identical_mixed_dtypes():
         assert fh == 0                    # fused run untouched
 
 
+@pytest.mark.parametrize("nranks", [4, 3])
+def test_plan_movers_byte_identical_mixed_dtypes(nranks):
+    """Plan-path mesh bcast and alltoall (coll/plan.mesh_move): every
+    dtype the tier takes, 1-D and 2-D, whole and ragged counts, every
+    root, on a power-of-two and an odd comm: bytes equal to fused."""
+    def fn(comm):
+        r, size = comm.rank, comm.size
+        out = []
+        for dt, n in ((jnp.int8, 4097), (jnp.float16, 2050),
+                      (jnp.bfloat16, 1500), (jnp.int32, 1024),
+                      (jnp.uint32, 700)):
+            x = _put(comm, ((jnp.arange(n) % 13) * (r + 1)).astype(dt))
+            for root in range(size):
+                out.append(np.asarray(comm.bcast_arr(x, root)).tobytes())
+            a = _put(comm, ((jnp.arange(size * n) % 11) + 17 * r)
+                     .astype(dt))
+            out.append(np.asarray(comm.alltoall_arr(a)).tobytes())
+        m = _put(comm, jnp.arange(4 * size * 300, dtype=jnp.float32)
+                 .reshape(4 * size, 300) + 1000 * r)
+        got = comm.alltoall_arr(m)
+        assert got.shape == m.shape
+        out.append(np.asarray(got).tobytes())
+        got = comm.bcast_arr(m, size - 1)
+        assert got.shape == m.shape
+        out.append(np.asarray(got).tobytes())
+        return b"".join(out)
+
+    plan_res, fused = _run_vs_fused(fn, nranks, devices=True)
+    assert plan_res == fused
+
+
 def test_plan_segrd_and_hop_explicit_byte_identical():
     """The recursive-doubling pick and the hop-explicit (native off)
     lowering of both algs: still byte-identical to fused."""
@@ -219,25 +250,42 @@ def test_plan_under_delay_faults():
     assert len({b for b, *_ in clean}) >= 1
 
 
-def test_plan_across_shrink_epoch():
+# the planned operations of the epoch tests: the allreduce plans and
+# the two data movers of coll/plan.mesh_move; 4092 divides by the 4
+# ranks of the old epoch and the 3 of the shrunk one
+_EPOCH_OPS = {
+    "allreduce": (4099, lambda comm, x: comm.allreduce_arr(x, mpi_op.SUM)),
+    "bcast": (4099, lambda comm, x: comm.bcast_arr(x, 1)),
+    "alltoall": (4092, lambda comm, x: comm.alltoall_arr(x)),
+}
+
+
+@pytest.mark.parametrize("kind", list(_EPOCH_OPS))
+def test_plan_across_shrink_epoch(kind):
     """A rank dies mid-job: the shrink epoch must purge the resolved
     plan cache AND evict the old mesh's plan executables from the
-    compile cache — then the shrunk world recomputes fresh, byte-
-    identical to a never-failed world of the survivor size."""
+    compile cache, so no plan resolved before the epoch is served
+    after it; then the shrunk world recomputes fresh, byte-identical
+    to a never-failed world of the survivor size."""
     import time
     from ompi_tpu.coll.device import compile_cache
     from ompi_tpu.ft import ulfm
+    n, call = _EPOCH_OPS[kind]
 
     def survivor(comm):
         old_dev_key = tuple(
             d.id for d in comm.mesh().devices.reshape(-1))
-        _ = np.asarray(comm.allreduce_arr(
-            _put(comm, (jnp.arange(4099, dtype=jnp.float32) % 11)
-                 + comm.rank), mpi_op.SUM))  # old-epoch plan op
-        assert "_coll_plans" in comm.__dict__
+        _ = np.asarray(call(
+            comm, _put(comm, (jnp.arange(n, dtype=jnp.float32) % 11)
+                       + comm.rank)))  # old-epoch plan op
+        old = list(comm.__dict__["_coll_plans"].values())
+        assert old
         if comm.rank == 0:
+            # a peer still leaving the op's one meeting would see the
+            # death inside the old-epoch op, which is not this test
+            time.sleep(0.2)
             ulfm.kill_now(comm.state)
-        time.sleep(0.3)
+        time.sleep(0.5)
         new = comm.shrink()
         assert "_coll_plans" not in comm.__dict__  # epoch hygiene
         stale = [k for k in list(compile_cache._d)
@@ -245,14 +293,17 @@ def test_plan_across_shrink_epoch():
                  and isinstance(k[0], str) and k[0].startswith("plan_")
                  and old_dev_key in k]
         assert not stale  # no stale-mesh executables survive
-        x = _put(new, (jnp.arange(4099, dtype=jnp.float32) % 11)
+        x = _put(new, (jnp.arange(n, dtype=jnp.float32) % 11)
                  + new.rank)
-        return np.asarray(new.allreduce_arr(x, mpi_op.SUM)).tobytes()
+        out = np.asarray(call(new, x)).tobytes()
+        served = new.__dict__["_coll_plans"].values()
+        assert served and not any(p is q for p in served for q in old)
+        return out
 
     def fresh(comm):
-        x = _put(comm, (jnp.arange(4099, dtype=jnp.float32) % 11)
+        x = _put(comm, (jnp.arange(n, dtype=jnp.float32) % 11)
                  + comm.rank)
-        return np.asarray(comm.allreduce_arr(x, mpi_op.SUM)).tobytes()
+        return np.asarray(call(comm, x)).tobytes()
 
     saved = _set(PLAN_ON)
     try:
@@ -261,13 +312,15 @@ def test_plan_across_shrink_epoch():
     finally:
         _restore(saved)
     assert got[0] is None
-    assert got[1] == got[2] == got[3] == ref[0]
+    assert got[1:] == ref
 
 
-def test_plan_across_respawn_epoch():
+@pytest.mark.parametrize("kind", list(_EPOCH_OPS))
+def test_plan_across_respawn_epoch(kind):
     """Kill + in-job respawn between plan-path collectives: the
     replacement's epoch sees no stale plans and the completed job's
     bytes match a fault-free run exactly."""
+    n, call = _EPOCH_OPS[kind]
     from ompi_tpu import errhandler as eh
     from ompi_tpu.cr import buddy
     from ompi_tpu.errhandler import MPIException
@@ -287,9 +340,9 @@ def test_plan_across_respawn_epoch():
                 st = buddy.restore(comm)
                 i, acc = int(st["i"]), np.asarray(st["acc"])
             else:
-                i, acc = 0, np.zeros(4099, np.float32)
+                i, acc = 0, np.zeros(n, np.float32)
             did_kill = False
-            base = (jnp.arange(4099, dtype=jnp.float32) % 11)
+            base = (jnp.arange(n, dtype=jnp.float32) % 11)
             while i < iters:
                 try:
                     buddy.checkpoint(comm, {"i": i, "acc": acc})
@@ -298,8 +351,7 @@ def test_plan_across_respawn_epoch():
                         did_kill = True
                         ulfm.kill_now(state)
                     x = _put(comm, base * (i + 1) + comm.rank)
-                    acc = np.asarray(
-                        comm.allreduce_arr(x, mpi_op.SUM))
+                    acc = np.asarray(call(comm, x))
                     i += 1
                 except MPIException as e:
                     if e.code not in ft_codes:

@@ -237,6 +237,62 @@ def test_mesh_detect_convict_retry_across_op_kinds():
         _restore(saved)
 
 
+@pytest.mark.parametrize("inject", [False, True])
+def test_planned_mesh_movers_take_one_decision_a_call(inject):
+    """A large mesh bcast or alltoall is one compiled plan behind one
+    rendezvous (coll/plan.mesh_move), so the integrity plane takes one
+    decision a call where the per-segment path took one a segment;
+    a flip on the root is caught, rank 1 convicted and the answers
+    exact.  The ragged allreduce beside them is padded by the plan's
+    pack stage, which must not donate what the plane re-reads after
+    a mismatch."""
+    import ompi_tpu.coll.pipeline  # noqa: F401  (registers the knobs)
+    import ompi_tpu.coll.plan  # noqa: F401
+    tier = {"coll_pipeline_min_bytes": 2048, "coll_seg_size": 4096}
+    n = 4 * 1024 + 4            # five 4 KiB segments a call, a tail
+
+    def fn(comm):
+        import jax.numpy as jnp
+        rank, size = comm.rank, comm.size
+        b = comm.bcast_arr(
+            jnp.full((n,), float(rank * 10 + 7), jnp.float32), root=1)
+        at = comm.alltoall_arr(
+            jnp.repeat(jnp.arange(size, dtype=jnp.int32) + 10 * rank,
+                       n // size))
+        s = comm.allreduce_arr(
+            jnp.full((n,), rank + 1, jnp.int32), mpi_op.SUM)
+        return (np.array_equal(np.asarray(b), np.full(n, 17.0, np.float32))
+                and np.array_equal(np.asarray(s), np.full(n, 10, np.int32))
+                and np.array_equal(
+                    np.asarray(at),
+                    np.repeat(np.arange(size, dtype=np.int32) * 10 + rank,
+                              n // size)))
+
+    checks = {}
+    for planned in (True, False):
+        saved = _set(dict(INJECT if inject else ARM,
+                          coll_plan_enable=planned, **tier))
+        ig.refresh()
+        ig.reset()
+        base_k, base_m = _pv("integrity_checks"), _pv(
+            "integrity_mismatches")
+        try:
+            assert all(run_ranks(4, fn, devices=True))
+            checks[planned] = _pv("integrity_checks") - base_k
+            if inject:
+                assert _conviction_ranks() == [1]
+                assert _pv("integrity_mismatches") > base_m
+            else:
+                assert _pv("integrity_mismatches") == base_m
+                assert ig.convicted_snapshot() == []
+        finally:
+            ig.reset()
+            _restore(saved)
+    if not inject:
+        # three calls on four ranks, against three of five segments
+        assert checks == {True: 3 * 4, False: 3 * 5 * 4}
+
+
 def test_hbm_detect_convict_retry():
     """Same contract on the co-located (hbm) dispatcher: every rank on
     one chip, victim rank 1 flipping — detection, attribution to rank
