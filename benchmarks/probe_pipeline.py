@@ -41,14 +41,13 @@ ALGS = ("fused", "segring", "segrd", "hier")
 _CONFIGS: Dict[str, Dict[str, object]] = {
     "fused": {"coll_pipeline_enable": False, "coll_hier_enable": False},
     "segring": {"coll_pipeline_enable": True, "coll_hier_enable": False,
-                "coll_pipeline_min_bytes": 1, "coll_plan_enable": True,
+                "coll_pipeline_min_bytes": 1,
                 "coll_pipeline_rd_max_bytes": 0},
     "segrd": {"coll_pipeline_enable": True, "coll_hier_enable": False,
-              "coll_pipeline_min_bytes": 1, "coll_plan_enable": True,
+              "coll_pipeline_min_bytes": 1,
               "coll_pipeline_rd_max_bytes": 1 << 62},
     "hier": {"coll_pipeline_enable": True, "coll_hier_enable": True,
              "coll_pipeline_min_bytes": 1, "coll_hier_min_bytes": 1,
-             "coll_plan_enable": True,
              "coll_pipeline_rd_max_bytes": 0},
 }
 
@@ -104,7 +103,6 @@ def run_probe(nranks: int = 8, reps: int = 7,
     def fn(comm):
         import jax
         import jax.numpy as jnp
-        from ompi_tpu.coll import pipeline
         from ompi_tpu.coll import plan as coll_plan
         from ompi_tpu.op.op import SUM
 
@@ -115,7 +113,7 @@ def run_probe(nranks: int = 8, reps: int = 7,
         # builds >> nranks for a single size
         plan_cache: Dict[str, Dict[str, Dict[str, int]]] = \
             {a: {} for a in ALGS}
-        seg_before = pipeline.pv_segments.read()
+        seg_before = coll_plan.pv_segments.read()
         for alg in ALGS:
             for nb in sizes:
                 _apply(comm, alg, comm.size)
@@ -178,7 +176,7 @@ def run_probe(nranks: int = 8, reps: int = 7,
         _apply(comm, "fused", comm.size)  # leave the world at defaults
         return {"lat_us": curve, "phase_raw": raw,
                 "plan_cache": plan_cache,
-                "segments": pipeline.pv_segments.read() - seg_before}
+                "segments": coll_plan.pv_segments.read() - seg_before}
 
     res = run_ranks(nranks, fn, devices=True, timeout=1800)
     lat = res[0]["lat_us"]

@@ -35,7 +35,7 @@ import functools
 import threading
 import time
 from collections import OrderedDict
-from typing import Any, Callable, Dict, List, Optional, Set, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -51,9 +51,7 @@ from ompi_tpu.op.op import MAX, MIN, PROD, SUM, Op
 # trace ids as module constants: meet() runs once per device
 # collective and must not pay module-attribute lookups for them
 _CAT_DISP = _trace.CAT_COLL_DISPATCH
-_CAT_SEG = _trace.CAT_COLL_SEGMENT
 _NAME_MEET = _trace.NAME_MEET
-_NAME_SEG_MEET = _trace.NAME_SEG_MEET
 _CAT_PHASE = _trace.CAT_PHASE
 _NAME_PH_RDV = _trace.NAME_PH_RDV
 _NAME_PH_DISPATCH = _trace.NAME_PH_DISPATCH
@@ -88,13 +86,6 @@ _rv_timeout_var = registry.register(
     "coll", "device", "rendezvous_timeout", 300.0, float,
     help="Seconds a device-collective rendezvous may stall before "
          "raising (dead/diverged peer diagnosis)")
-_dispatcher_var = registry.register(
-    "coll", "device", "dispatcher", False, bool,
-    help="Run every device-collective computation on one dedicated "
-         "thread instead of the rendezvous's last arriver.  Off by "
-         "default: the dedicated thread measured worse in the r05 "
-         "record's A/B; on a directly attached chip the difference "
-         "is not measured (ROADMAP D3).")
 _cache_max_var = registry.register(
     "coll", "device", "cache_max", 256, int,
     help="Bound on the compiled-collective LRU cache (distinct "
@@ -158,132 +149,22 @@ def _fold_fn(opname: str):
     }[opname]
 
 
-class _DeviceDispatcher:
-    """One thread per process runs EVERY device-collective
-    computation.
-
-    The rendezvous's natural "last arriver computes" rotation
-    dispatches consecutive ops of one dependency chain from different
-    host threads; with the dispatcher the last arriver hands the
-    computation to this one thread and parks with everyone else.
-    What thread rotation costs on a directly attached chip is not
-    measured; the knob is off by default and the segmented pipeline
-    (meet_begin) is the one caller that always uses the thread, to
-    overlap host packing with device dispatch."""
-
-    def __init__(self) -> None:
-        import queue
-        self.q: "queue.SimpleQueue" = queue.SimpleQueue()
-        self.closed = False
-        self._submit_lock = threading.Lock()
-        self.thread = threading.Thread(
-            target=self._loop, daemon=True, name="coll-device-dispatch")
-        self.thread.start()
-
-    def _loop(self) -> None:
-        while True:
-            work = self.q.get()
-            if work is None:
-                return
-            work()  # never raises: work wraps its own error capture
-
-    def submit(self, work: Callable[[], None]) -> None:
-        # the lock orders submit against close(): a submit that wins
-        # the race lands BEFORE the close sentinel and is flushed; one
-        # that loses gets the clear error instead of silently dying
-        # with the daemon thread
-        with self._submit_lock:
-            if self.closed:
-                raise RuntimeError(
-                    "device-collective dispatcher is closed (MPI "
-                    "finalized): late collective work rejected — "
-                    "pending work was flushed at finalize")
-            self.q.put(work)
-
-    def close(self, timeout: float = 10.0) -> None:
-        """Drain at finalize: reject new submits, then run everything
-        already queued and join the worker.  Pending submitted work
-        must complete — rendezvous peers are parked on its results."""
-        with self._submit_lock:
-            if self.closed:
-                return
-            self.closed = True
-            self.q.put(None)
-        self.thread.join(timeout)
-
-
-_dispatcher_singleton: Optional[_DeviceDispatcher] = None
-_dispatcher_lock = threading.Lock()
-
-# rank states that have used the device-collective plane this world;
-# the LAST one to finalize drains the dispatcher (thread-rank worlds
-# share one process-wide dispatcher across all ranks)
-_live_states: Set[Any] = set()
-_live_lock = threading.Lock()
-
-
-def _prune_dead_locked() -> bool:
-    """Drop tracked states that can never finalize — their world
-    aborted, or they already finalized without the hook (a replayed
-    hook list) — and report whether any live state remains.  Without
-    the prune a rank killed mid-abort would hold the dispatcher open
-    for the rest of the process.  Caller holds _live_lock."""
-    for s in list(_live_states):
-        w = getattr(s.rte, "world", None)
-        if getattr(s, "finalized", False) or \
-                getattr(s, "ulfm_dead", False) or \
-                getattr(w, "aborted", None):
-            _live_states.discard(s)
-    return bool(_live_states)
-
-
-def _dispatcher() -> _DeviceDispatcher:
-    global _dispatcher_singleton
-    d = _dispatcher_singleton
-    if d is not None and not d.closed:
-        return d
-    with _dispatcher_lock:
-        d = _dispatcher_singleton
-        if d is None or d.closed:
-            with _live_lock:
-                live = _prune_dead_locked()
-            if d is not None and d.closed and not live:
-                raise RuntimeError(
-                    "device-collective dispatcher used after MPI "
-                    "finalize (no live ranks): call MPI_Init first")
-            # fresh world in the same process (tests run many): revive
-            d = _dispatcher_singleton = _DeviceDispatcher()
-    return d
-
-
 def track_state(state) -> None:
     """First device-collective touch by a rank: register its finalize
-    hook so pending fused batches flush and — when the LAST tracked
-    rank finalizes — the dispatcher drains instead of dying with the
-    daemon thread mid-work (finalize racing a last collective)."""
+    hook so pending fused batches flush before the finalize fence."""
     if state.__dict__.get("_device_coll_tracked"):
         return
     state._device_coll_tracked = True
-    with _live_lock:
-        _live_states.add(state)
     state.progress.register_finalize_hook(
         functools.partial(_finalize_state, state))
 
 
 def _finalize_state(state) -> None:
-    # flush pending fused batches first: every member rank's hook runs
+    # flush pending fused batches: every member rank's hook runs
     # before its finalize fence, so the flush rendezvous still meets
     from ompi_tpu.coll import fusion
     fusion.flush_state(state)
-    with _live_lock:
-        _live_states.discard(state)
-        state._device_coll_tracked = False
-        last = not _prune_dead_locked()
-    if last:
-        with _dispatcher_lock:
-            d = _dispatcher_singleton
-        if d is not None:
-            d.close()
+    state._device_coll_tracked = False
 
 
 def _coll_delay_injector(state):
@@ -378,19 +259,7 @@ def _sever_hold(abort_check) -> None:
 # device: a traced operation differs from an untraced one by clock
 # reads and ring stores.
 
-def _pub_span(ph, direct: bool, name_id: int, t0: int, t1: int) -> None:
-    """A span of the publisher's work on a kept op, against the
-    triggering rank's tracer: stored straight when this thread owns
-    the tracer (the last arriver, inline), else handed to the owner
-    (the dispatcher thread; Tracer.file_done), so the ring keeps one
-    writer."""
-    if direct:
-        ph[0].end_at(t0, t1, name_id, _CAT_PHASE, ph[1], ph[2], ph[3])
-    else:
-        ph[0]._done.append((t0, t1, name_id, ph[1], ph[2], ph[3]))
-
-
-def _phase_fn(fn, shards, ph, direct: bool = True):
+def _phase_fn(fn, shards, ph):
     """Run a meeting's computation.  Untraced (``ph`` None) that is
     ``fn(shards)`` and nothing else.  With a ctx, a computation that
     brought a traced twin (``fn.traced``: the compiled plans and the
@@ -402,14 +271,14 @@ def _phase_fn(fn, shards, ph, direct: bool = True):
         return fn(shards)
     t0 = _now()
     tfn = getattr(fn, "traced", None)
-    res = fn(shards) if tfn is None else tfn(shards, ph, direct)
+    res = fn(shards) if tfn is None else tfn(shards, ph)
     if ph[4]:
-        _pub_span(ph, direct, _NAME_PH_DISPATCH, t0, _now())
+        ph[0].end_at(t0, _now(), _NAME_PH_DISPATCH, _CAT_PHASE,
+                     ph[1], ph[2], ph[3])
     return res
 
 
-def _mesh_exec(mesh, size: int, jfn, sharding, shards: List, ph,
-               direct: bool) -> List:
+def _mesh_exec(mesh, size: int, jfn, sharding, shards: List, ph) -> List:
     """The traced twin of a compiled mesh plan's computation: assemble
     the global array, call the compiled collective, split the output
     per rank; each step banks its accumulator against the publisher's
@@ -426,14 +295,14 @@ def _mesh_exec(mesh, size: int, jfn, sharding, shards: List, ph,
     lns[_L_LAUNCH] += t2 - t1
     lns[_L_SCATTER] += t3 - t2
     if ph[4]:
-        _pub_span(ph, direct, _NAME_PH_ASSEMBLE, t0, t1)
-        _pub_span(ph, direct, _NAME_PH_LAUNCH, t1, t2)
-        _pub_span(ph, direct, _NAME_PH_SCATTER, t2, t3)
+        end_at = ph[0].end_at
+        end_at(t0, t1, _NAME_PH_ASSEMBLE, _CAT_PHASE, ph[1], ph[2], ph[3])
+        end_at(t1, t2, _NAME_PH_LAUNCH, _CAT_PHASE, ph[1], ph[2], ph[3])
+        end_at(t2, t3, _NAME_PH_SCATTER, _CAT_PHASE, ph[1], ph[2], ph[3])
     return parts
 
 
-def _stacked_exec(jbody, out_map, n: int, shards: List, ph,
-                  direct: bool) -> List:
+def _stacked_exec(jbody, out_map, n: int, shards: List, ph) -> List:
     """The traced twin of a one-chip meeting's computation: the stacked
     kernel, then the per-rank split of its result (``out(r, n)``),
     with the same accounting as _mesh_exec (nothing to assemble: the
@@ -447,14 +316,10 @@ def _stacked_exec(jbody, out_map, n: int, shards: List, ph,
     lns[_L_LAUNCH] += t1 - t0
     lns[_L_SCATTER] += t2 - t1
     if ph[4]:
-        if direct:
-            # the last arriver, under the meeting's lock: one store call
-            ph[0].end_at2(t0, t1, _NAME_PH_LAUNCH, _CAT_PHASE,
-                          t1, t2, _NAME_PH_SCATTER, _CAT_PHASE,
-                          ph[1], ph[2], ph[3])
-        else:
-            _pub_span(ph, False, _NAME_PH_LAUNCH, t0, t1)
-            _pub_span(ph, False, _NAME_PH_SCATTER, t1, t2)
+        # the last arriver, under the meeting's lock: one store call
+        ph[0].end_at2(t0, t1, _NAME_PH_LAUNCH, _CAT_PHASE,
+                      t1, t2, _NAME_PH_SCATTER, _CAT_PHASE,
+                      ph[1], ph[2], ph[3])
     return parts
 
 
@@ -557,28 +422,15 @@ class Rendezvous:
               fn: Callable[[List[Any]], List[Any]],
               abort_check: Optional[Callable[[], None]] = None,
               progress: Any = None,
-              dispatch_async: Optional[bool] = None,
               ph: Optional[tuple] = None) -> int:
         """Deposit `value` for the next generation; the last arriver
-        triggers fn(slots) -> outputs.  Returns the generation token
-        to collect with ``finish``.
-
-        ``dispatch_async=None`` follows the coll_device_dispatcher
-        knob (the classic blocking behavior); ``True`` forces the last
-        arriver to hand fn to the process-wide dispatcher thread so
-        begin() returns while the device computes — the hook the
-        segmented pipeline uses to overlap host packing of segment
-        k+1 with device dispatch of segment k (docs/DESIGN.md §12).
-        Slots recycle as soon as the meeting is full, so generation
-        g+1 deposits may land while g still computes — pipelining
-        depth is bounded only by how far a caller runs ahead of its
-        own finish() calls."""
+        runs fn(slots) -> outputs, inline.  Returns the generation
+        token to collect with ``finish``.  Slots recycle as soon as
+        the meeting is full, so a fast rank may deposit for generation
+        g+1 while stragglers still read the results of g."""
         if progress is not None:
             self._progs[rank] = progress
-        if dispatch_async is None:
-            dispatch_async = _dispatcher_var.value
         ta = td = 0
-        handed = False
         if ph is not None:
             # layer account (trace.LAYERS; inline: this runs on every
             # operation of every rank): the interval before the
@@ -610,53 +462,22 @@ class Rendezvous:
                 self.gen += 1
                 if td:
                     self.t_full[gen] = td
-                if dispatch_async:
-                    # hand the computation to the process-wide
-                    # dispatcher thread; members park (or pipeline)
-                    # until it publishes the generation's results
-                    rv = self
-
-                    def work() -> None:
-                        try:
-                            res = _phase_fn(fn, shards, ph, False)
-                            err = None
-                        except BaseException as e:  # noqa: BLE001
-                            res = [None] * rv.size
-                            err = e
-                        with rv.cv:
-                            if err is not None:
-                                rv.errors[gen] = err
-                            rv.results[gen] = res
-                            rv.readers[gen] = rv.size
-                            if td:
-                                rv.t_rel[gen] = _now()
-                            rv.cv.notify_all()
-                            progs = list(rv._progs.items())
-                        # wake members parked on their progress idle
-                        # selector (outside the meeting lock)
-                        for _r, prog in progs:
-                            prog.wakeup()
-
-                    _dispatcher().submit(work)
-                    handed = True
-                else:
-                    # last arriver computes inline (under the cv, as
-                    # before the r5 dispatcher experiment)
-                    try:
-                        self.results[gen] = _phase_fn(fn, shards, ph)
-                    except BaseException as e:  # noqa: BLE001
-                        self.errors[gen] = e
-                        self.results[gen] = [None] * self.size
-                    self.readers[gen] = self.size
-                    if td:
-                        # published: what follows (notify, doorbells,
-                        # the lock and the GIL changing hands) is the
-                        # hand-off, every member's rdv_wake
-                        self.t_rel[gen] = _now()
-                    self.cv.notify_all()
-                    for r, prog in self._progs.items():
-                        if r != rank:
-                            prog.wakeup()
+                # the last arriver computes inline, under the cv
+                try:
+                    self.results[gen] = _phase_fn(fn, shards, ph)
+                except BaseException as e:  # noqa: BLE001
+                    self.errors[gen] = e
+                    self.results[gen] = [None] * self.size
+                self.readers[gen] = self.size
+                if td:
+                    # published: what follows (notify, doorbells,
+                    # the lock and the GIL changing hands) is the
+                    # hand-off, every member's rdv_wake
+                    self.t_rel[gen] = _now()
+                self.cv.notify_all()
+                for r, prog in self._progs.items():
+                    if r != rank:
+                        prog.wakeup()
         if td:
             if c:
                 # deposited at td: the wait for the slot, one more
@@ -665,11 +486,6 @@ class Rendezvous:
                 lns[_L_RENDEZVOUS] += 1
                 tr._t_cur = td
                 tr._cur_k = _L_ENTRY
-                if handed:
-                    # handing the computation over (and letting go of
-                    # the lock) is the triggering rank's share of the
-                    # serve
-                    tr.lap_to(_L_RDV_SERVE, _L_ENTRY)
             if ph[4]:
                 # kept: ph_entry (the last boundary before, shim entry
                 # or the end of a pack, to the rendezvous) and the
@@ -855,72 +671,6 @@ def meet(comm, value, fn, abort_check, ck=None, account=True) -> Any:
                  progress=comm.state.progress, ph=ph)
     if t0:
         tr.end(t0, _NAME_MEET, _CAT_DISP, comm.cid, seq, nbytes, op)
-    return out
-
-
-def meet_begin(comm, value, fn, abort_check, ck=None, ph=None):
-    """Asynchronous rendezvous entry: deposit and return a handle
-    without waiting for the result.  The last arriver's computation
-    always runs on the dispatcher thread, so the caller's thread is
-    free to pack the NEXT segment while the device computes this one
-    — the overlap the segmented pipeline is built on.  Collect with
-    ``meet_finish``; every begun handle MUST be finished (results are
-    refcounted per generation).  ``ck`` is the integrity check spec,
-    exactly as in ``meet``; ``ph`` is the OPERATION's phase ctx (the
-    pipeline builds one for all its segments, so a kept operation
-    keeps every segment's phases)."""
-    rv = _get_rendezvous(comm)
-    track_state(comm.state)
-    inj = _coll_delay_injector(comm.state)
-    if inj:
-        d = inj.maybe_delay()
-        if d:
-            time.sleep(d)
-    sl = _coll_slow_injector(comm.state)
-    if sl:
-        time.sleep(sl.delay_s())
-    sv = _coll_sever_injector(comm.state)
-    if sv and sv.should_sever():
-        _sever_hold(abort_check)
-    nbytes = int(getattr(value, "nbytes", 0) or 0)
-    count_offload(comm, nbytes)
-    if ck is not None:
-        value, fn = _ig.gate(comm, value, fn, ck)
-    sj = _coll_sdc_injector(comm.state)
-    if sj and sj.should_flip():
-        value = _ig.flip_value(value)
-    tr = comm.state.tracer
-    t0 = 0
-    if tr is not None:
-        op = comm._coll_seq
-        if not tr._plo <= op < tr._phi:
-            tr._restep(op)
-        if op % tr._period[_CAT_SEG]:
-            tr._skipped[_CAT_SEG] += 1
-        else:
-            t0 = _now()
-    gen = rv.begin(comm.rank, value, fn, abort_check,
-                   progress=comm.state.progress, dispatch_async=True,
-                   ph=ph)
-    return (rv, gen, t0, nbytes, ph)
-
-
-def meet_finish(comm, handle, abort_check) -> Any:
-    """Collect one ``meet_begin`` handle.  The deposit→collect span is
-    recorded under cat ``coll_segment`` (its own latency histogram —
-    per-segment latency, unlike coll_dispatch's whole-op latency)."""
-    rv, gen, t0, nbytes, ph = handle
-    out = rv.finish(comm.rank, gen, abort_check,
-                    progress=comm.state.progress, ph=ph)
-    tr = comm.state.tracer
-    if tr is not None:
-        # the seq ticks on EVERY traced segment (sampled out or not)
-        # so surviving spans keep cross-rank-aligned correlation keys
-        seq = comm._dev_seq
-        comm._dev_seq = seq + 1
-        if t0:
-            tr.end(t0, _NAME_SEG_MEET, _CAT_SEG, comm.cid, seq, nbytes,
-                   comm._coll_seq)
     return out
 
 

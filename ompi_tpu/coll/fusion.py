@@ -18,9 +18,10 @@ Surface: ``comm.iallreduce_arr`` / ``comm.ibcast_arr`` return a
 ``FusedRequest``; pending ops coalesce until an explicit
 ``comm.flush_arr()``, a ``wait()``/``test()`` on any request of the
 batch, the ``coll_device_fusion_max_ops`` bound, or MPI_Finalize
-(dispatcher-drain hook) flushes them.  Ineligible ops (big payloads,
-host-only comms, exotic ops) execute immediately through the blocking
-vtable and return an already-complete request — callers never branch.
+(the hook ``device.track_state`` registers) flushes them.
+Ineligible ops (big payloads, host-only comms, exotic ops) execute
+immediately through the blocking vtable and return an
+already-complete request — callers never branch.
 
 Batch symmetry: the flush is one rendezvous per batch, so every member
 rank must enqueue the SAME sequence of collectives between flushes

@@ -1,26 +1,30 @@
-"""coll/plan: compiled collective plans — ONE jitted multi-segment
+"""coll/plan: compiled collective plans — ONE jitted whole-payload
 program and ONE rendezvous per large-message collective.
 
-The pipelined tier (coll/pipeline.py) proved the segmented schedules
-but pays N per-segment rendezvous + N host dispatches + N
-``NamedSharding``/assemble constructions per op.  On a fast mesh the
-op becomes orchestration-bound: the device finishes a segment long
-before the host has packed, met and dispatched the next one.
-
-The plan compiler moves every decision out of steady state.  For each
-(alg, mesh, segment geometry, dtype, op) it compiles ONE jitted
-program covering the WHOLE multi-segment schedule — the full
-reduce-scatter + allgather ring (segring) or the recursive-doubling
-exchange (segrd) as a single shard_map with buffer donation — and
-binds it into a ``Plan`` holding the prebuilt sharding, the meet-fn
-closure and the pad identity.  Executing a plan is pure data motion:
+Every operation that ``pipeline.maybe_device_coll`` routes to the
+large-message tier runs here.  For each (alg, mesh, segment geometry,
+dtype, op) the plan compiler builds ONE jitted program covering the
+whole payload — the full reduce-scatter + allgather ring (segring) or
+the recursive-doubling exchange (segrd) as a single shard_map with
+buffer donation, the stacked one-chip kernel (hbm), or a data mover
+(segbcast / sega2a) — and binds it into a ``Plan`` holding the
+prebuilt sharding, the meet-fn closure and the pad identity.
+Executing a plan is pure data motion:
 
     pack (identity-pad to the plan's fixed shape, zero-copy staging
     bypass where the runtime aliases aligned host buffers)
-      -> ONE ``device.meet`` (rendezvous collapses from N per op to 1;
-         the ULFM abort check rides the meet, so fault handling sits
-         at the plan boundary instead of per segment)
+      -> ONE ``device.meet`` (the ULFM abort check rides the meet, so
+         fault handling sits at the plan boundary)
       -> unpack (trim) + pvar/trace accounting.
+
+**Segment-size discipline**: a payload is padded (op identity
+elements; sliced off at unpack) to a whole number of fixed per-host
+segments (``segment_elems``: ``coll_seg_size``, or the calibrated
+size), so the compiled programs are keyed by segment COUNT, never by
+message size, and a sweep of message sizes cannot blow the bounded
+cache.  Sub-segment payloads quantize the plan shape to the next pow2
+(multiple of comm size).  ``coll_pipeline_segments`` advances by the
+segment count of every planned allreduce.
 
 Keying and lifetime:
 
@@ -33,9 +37,6 @@ Keying and lifetime:
   ``_COMM_CACHE_KEYS`` at shrink/respawn epochs and by
   ``SELECTION_CACHE_KEYS`` when an autotune fold moves the calibrated
   segment size out from under the plan geometry.
-* sub-segment payloads quantize the plan shape to the next pow2
-  (multiple of comm size), full payloads use the calibrated segment —
-  the identity padding keeps every size on a log-bounded key set.
 
 Reduce lowering: with ``coll_plan_native_reduce`` (default), plans
 for SUM/MAX/MIN lower to the runtime's native cross-replica reduction
@@ -43,8 +44,17 @@ for SUM/MAX/MIN lower to the runtime's native cross-replica reduction
 path's bcast-as-masked-psum — because a compiler-scheduled fused
 reduction beats a hop-explicit schedule wherever the runtime provides
 one.  Other ops, and all ops with the knob off, keep the faithful
-batched ring / recursive-doubling schedule, which real multi-slice
-topologies may prefer.
+batched schedule, which real multi-slice topologies may prefer:
+
+* **segring** — chunked ``ppermute`` ring allreduce: P-1
+  reduce-scatter steps (each rank accumulates one stripe per hop) then
+  P-1 allgather steps.  Per-chunk accumulation is a rank-ordered left
+  fold computed by exactly ONE rank and circulated verbatim, so every
+  rank's output is byte identical by construction.
+* **segrd** — recursive doubling (power-of-two comms): log2(P)
+  exchange rounds; both operand orders are computed and selected by
+  rank parity (the MPICH operand-order discipline), so all ranks
+  evaluate the identical expression tree.
 
 Data movement: a mesh bcast or alltoall that ``tuned.device_algorithm``
 routes to the tier (``segbcast`` / ``sega2a``) is planned the same way
@@ -52,7 +62,7 @@ routes to the tier (``segbcast`` / ``sega2a``) is planned the same way
 inside the jit, one rendezvous.  Bit-exact for every bit pattern (no
 arithmetic touches the payload), so not the fused path's masked psum.
 
-DESIGN.md §22.
+DESIGN.md §12.
 """
 
 from __future__ import annotations
@@ -60,13 +70,13 @@ from __future__ import annotations
 from collections import OrderedDict
 import functools
 import time
+from typing import Any, Callable, Optional
 
 import numpy as np
 
 from ompi_tpu import obs as _obs
 from ompi_tpu import trace as _trace
 from ompi_tpu.coll import device as _dev
-from ompi_tpu.coll import pipeline as _pl
 from ompi_tpu.obs import integrity as _ig
 from ompi_tpu.mca.params import registry
 from ompi_tpu.runtime import staging as _staging
@@ -75,16 +85,19 @@ _CAT_SEG = _trace.CAT_COLL_SEGMENT
 _CAT_PHASE = _trace.CAT_PHASE
 _NAME_PLAN = _trace.NAME_PLAN_EXEC
 _NAME_PH_PACK = _trace.NAME_PH_PACK
+_NAME_PH_UNPACK = _trace.NAME_PH_UNPACK
 _L_ENTRY = _trace.L_ENTRY
+_L_EXIT = _trace.L_EXIT
 _L_PACK = _trace.L_PACK
+_L_UNPACK = _trace.L_UNPACK
 
-_enable_var = registry.register(
-    "coll", "plan", "enable", True, bool,
-    help="Compile one jitted multi-segment program per (alg, mesh, "
-         "segment geometry, dtype, op) and run each large-message "
-         "allreduce, mesh bcast and mesh alltoall as ONE rendezvous + "
-         "ONE dispatch (DESIGN.md §22); "
-         "0 = the per-segment pipelined rendezvous path")
+_seg_size_var = registry.register(
+    "coll", "seg", "size", 1 << 20, int,
+    help="Segment size (bytes) for the segmented/pipelined large-"
+         "message device algorithms (ref: "
+         "coll_tuned_decision_fixed.c:72).  Rounded up so ring "
+         "stripes stay equal; coll_tuned_use_measured_rules replaces "
+         "this with the calibrated per-host segment size")
 
 _cache_max_var = registry.register(
     "coll", "plan", "cache_max", 32, int,
@@ -99,6 +112,10 @@ _native_var = registry.register(
          "the hop-explicit batched ring / recursive-doubling "
          "schedule for every op")
 
+pv_segments = registry.register_pvar(
+    "coll", "pipeline", "segments",
+    help="Segments covered by planned allreduces (a plan's segment "
+         "count, added once per operation)")
 pv_builds = _obs.scoped_pvar(
     "coll", "plan", "builds",
     help="collective plans resolved (per rank): a Plan object built "
@@ -124,8 +141,56 @@ _ALG_ID = {
 }
 
 
-def enabled() -> bool:
-    return bool(_enable_var.value)
+# ops with a pairwise accumulation step (segring/segrd); every XLA-
+# lowerable reducer and gather-fold op has one
+_BINOPS = {
+    "MPI_SUM": "add", "MPI_MAX": "maximum", "MPI_MIN": "minimum",
+    "MPI_PROD": "multiply", "MPI_BAND": "bitwise_and",
+    "MPI_BOR": "bitwise_or", "MPI_BXOR": "bitwise_xor",
+    "MPI_LAND": None, "MPI_LOR": None, "MPI_LXOR": None,
+}
+
+
+def _binop(opname: str) -> Callable:
+    import jax.numpy as jnp
+    name = _BINOPS[opname]
+    if name is not None:
+        return getattr(jnp, name)
+    # logical ops: normalize to 0/1 in the input dtype at every step
+    if opname == "MPI_LAND":
+        return lambda a, b: ((a != 0) & (b != 0)).astype(a.dtype)
+    if opname == "MPI_LOR":
+        return lambda a, b: ((a != 0) | (b != 0)).astype(a.dtype)
+    return lambda a, b: ((a != 0) ^ (b != 0)).astype(a.dtype)
+
+
+def _pad_value(opname: Optional[str], dtype) -> Any:
+    """Identity element of the op — a ragged payload is padded with it
+    so every size hits a compiled shape keyed by segment count and the
+    padding cannot perturb real elements."""
+    dt = np.dtype(dtype)
+    if opname in ("MPI_MAX",):
+        return dt.type(np.iinfo(dt).min) if dt.kind in "iu" \
+            else dt.type(-np.inf)
+    if opname in ("MPI_MIN",):
+        return dt.type(np.iinfo(dt).max) if dt.kind in "iu" \
+            else dt.type(np.inf)
+    if opname in ("MPI_PROD", "MPI_LAND"):
+        return dt.type(1)
+    if opname == "MPI_BAND":
+        return dt.type(~dt.type(0)) if dt.kind in "iu" else dt.type(1)
+    # SUM, OR/XOR families, and data-movement kinds (bcast/alltoall)
+    return dt.type(0)
+
+
+def segment_elems(comm, itemsize: int) -> int:
+    """Per-host segment size in elements, rounded UP to a multiple of
+    the comm size so ring stripes and alltoall blocks stay equal."""
+    from ompi_tpu.coll import calibrate
+    seg_bytes = calibrate.segment_bytes(comm.size, _seg_size_var.value)
+    elems = max(comm.size, seg_bytes // max(1, itemsize))
+    rem = elems % comm.size
+    return elems + (comm.size - rem) if rem else elems
 
 
 def _plan_segments(comm, n: int, seg: int):
@@ -242,12 +307,23 @@ def _pack_end(tr, comm, t0: int, nbytes: int) -> None:
         tr.end_at(t0, t1, _NAME_PH_PACK, _CAT_PHASE, comm.cid, seq, nbytes)
 
 
+def _unpack_end(tr, comm, t0: int, nbytes: int) -> None:
+    """The end of an unpack stage that started at ``t0`` (Tracer.lap):
+    banked in the ``unpack`` accumulator, recorded as ph_unpack on a
+    kept op.  Only reached with the phase profiler armed."""
+    t1 = tr.lap_to(_L_UNPACK, _L_EXIT)
+    seq = comm._coll_seq
+    if tr.kept(_CAT_PHASE, seq):
+        tr.end_at(t0, t1, _NAME_PH_UNPACK, _CAT_PHASE, comm.cid, seq,
+                  nbytes)
+
+
 def _unpack(comm, out, n: int, plan: Plan):
     tr = comm.state.tracer
     t0 = tr.lap() if tr is not None and tr.phase else 0
     res = out[:n]
     if t0:
-        _pl._unpack_end(tr, comm, t0, n * plan.itemsize)
+        _unpack_end(tr, comm, t0, n * plan.itemsize)
     return res
 
 
@@ -275,7 +351,7 @@ def _unpack_rows(comm, out, n: int, plan: Plan):
     size = comm.size
     res = out.reshape(size, plan.total // size)[:, :n // size].reshape(-1)
     if t0:
-        _pl._unpack_end(tr, comm, t0, n * plan.itemsize)
+        _unpack_end(tr, comm, t0, n * plan.itemsize)
     return res
 
 
@@ -318,7 +394,7 @@ def _compile_mesh(alg: str, mesh, size: int, nsegs: int, seg: int,
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    binop = _pl._binop(opname)
+    binop = _binop(opname)
     if native:
         if opname == "MPI_SUM":
             body = lambda x: lax.psum(x, "r")  # noqa: E731
@@ -328,8 +404,7 @@ def _compile_mesh(alg: str, mesh, size: int, nsegs: int, seg: int,
             body = lambda x: lax.pmin(x, "r")  # noqa: E731
     elif alg == "segring":
         # the full reduce-scatter + allgather ring, batched over the
-        # leading nsegs axis — per segment this is exactly the
-        # pipelined tier's segring kernel, fused into one program
+        # leading nsegs axis
         ring = [(j, (j + 1) % size) for j in range(size)]
         m = seg // size
 
@@ -397,7 +472,7 @@ def _build_mesh_plan(comm, alg: str, nsegs: int, seg: int, np_dtype,
         ckey, lambda: _compile_mesh(alg, mesh, size, nsegs, seg,
                                     np_dtype, opname, native, donate))
     return Plan(alg, nsegs, seg, np_dtype,
-                _pl._pad_value(opname, np_dtype),
+                _pad_value(opname, np_dtype),
                 _mesh_meet_fn(mesh, size, jfn), _dev.meet,
                 devs[comm.rank],
                 _ig.spec_static("allreduce", opname,
@@ -439,7 +514,7 @@ def mesh_reduce(module, comm, x, op, alg: str):
     n = int(flat.shape[0])
     np_dtype = np.dtype(flat.dtype)
     nsegs, seg = _plan_segments(
-        comm, n, _pl.segment_elems(comm, np_dtype.itemsize))
+        comm, n, segment_elems(comm, np_dtype.itemsize))
     # donation is only sound when the pack stage owns the padded
     # buffer; exact-fit payloads flow the caller's array straight in.
     # Never while the integrity plane is armed: after a mismatch it
@@ -450,7 +525,7 @@ def mesh_reduce(module, comm, x, op, alg: str):
         comm, pkey,
         lambda: _build_mesh_plan(comm, alg, nsegs, seg, np_dtype,
                                  op.name, donate))
-    _pl.pv_segments.add(nsegs)
+    pv_segments.add(nsegs)
     out = plan.execute(module, comm, flat, n)
     return out if shape is None else out.reshape(shape)
 
@@ -460,10 +535,10 @@ def mesh_reduce(module, comm, x, op, alg: str):
 def _compile_mesh_move(alg: str, mesh, size: int, total: int, root):
     """The ONE jitted program of a large mesh bcast or alltoall: a
     rank's (total,) in P("r").  Named for the algorithm whose schedule
-    it fuses (the profiler's device plane shows jit_ompi_<alg>), as the
-    per-segment kernels of pipeline._build_seg_kernel are.  The payload
-    stays 1-D throughout: a (size, m) view of a tiled 1-D array is a
-    relayout, which compiles to a copy loop over the whole payload.
+    it fuses (the profiler's device plane shows jit_ompi_<alg>, which a
+    trace reduction matches).  The payload stays 1-D throughout: a
+    (size, m) view of a tiled 1-D array is a relayout, which compiles
+    to a copy loop over the whole payload.
     Nothing is donated: the integrity plane re-reads a deposited
     operand after a mismatch."""
     import jax
@@ -544,7 +619,7 @@ def mesh_move(module, comm, x, alg: str, root=None):
     n = int(flat.shape[0])
     np_dtype = np.dtype(flat.dtype)
     nsegs, seg = _plan_segments(
-        comm, n, _pl.segment_elems(comm, np_dtype.itemsize))
+        comm, n, segment_elems(comm, np_dtype.itemsize))
     pkey = ("mesh", alg, nsegs, seg, np_dtype.str, root)
     plan = _resolve(
         comm, pkey,
@@ -573,7 +648,7 @@ def _build_hbm_plan(module, comm, nsegs: int, seg: int, np_dtype,
     fn.traced = functools.partial(_dev._stacked_exec, jbody, out_map, size)
 
     return Plan("hbm", nsegs, seg, np_dtype,
-                _pl._pad_value(opname, np_dtype), fn, _dev.meet,
+                _pad_value(opname, np_dtype), fn, _dev.meet,
                 device_hint,
                 _ig.spec_static("allreduce", opname,
                                 np.empty(0, np_dtype)))
@@ -581,8 +656,7 @@ def _build_hbm_plan(module, comm, nsegs: int, seg: int, np_dtype,
 
 def hbm_reduce(module, comm, x, op):
     """Plan-path intra-chip allreduce: the stacked whole-payload
-    kernel (already one dispatch) now also goes through exactly one
-    rendezvous instead of one per segment."""
+    kernel behind exactly one rendezvous."""
     x = module._deposit(comm, x)
     if getattr(x, "ndim", None) == 1:
         shape, flat = None, x  # no same-shape reshape dispatch
@@ -592,13 +666,13 @@ def hbm_reduce(module, comm, x, op):
     n = int(flat.shape[0])
     np_dtype = np.dtype(flat.dtype)
     nsegs, seg = _plan_segments(
-        comm, n, _pl.segment_elems(comm, np_dtype.itemsize))
+        comm, n, segment_elems(comm, np_dtype.itemsize))
     pkey = ("hbm", nsegs, seg, np_dtype.str, op.name)
     dev = getattr(x, "device", None)
     plan = _resolve(
         comm, pkey,
         lambda: _build_hbm_plan(module, comm, nsegs, seg, np_dtype,
                                 op.name, dev))
-    _pl.pv_segments.add(nsegs)
+    pv_segments.add(nsegs)
     out = plan.execute(module, comm, flat, n)
     return out if shape is None else out.reshape(shape)

@@ -106,7 +106,7 @@ def device_algorithm(comm, kind: str, nbytes: int,
     the reference's comm-bound module selection: None keeps the fused
     single-dispatch path (DESIGN.md §8); "hier" routes to the
     hierarchical tier; "segring"/"segrd"/"segbcast"/"sega2a" route to
-    the segmented pipeline (DESIGN.md §12).
+    a compiled plan of the large-message tier (DESIGN.md §12).
 
     Comm-consistent by construction — thresholds come from knobs and
     the process-wide calibration profile, and nbytes is MPI-matched —

@@ -577,7 +577,7 @@ _COMM_CACHE_KEYS = (
     # epochs
     "_pipeline_pick", "_hier_eligible", "_hier_plan",
     "_cart_device_mesh",
-    # compiled collective plans (DESIGN.md §22): Plan objects hold the
+    # compiled collective plans (DESIGN.md §12): Plan objects hold the
     # old mesh, its sharding and a jitted executable bound to the old
     # device set — stale-mesh executables must never survive an epoch
     "_coll_plans",

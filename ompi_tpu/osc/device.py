@@ -28,7 +28,7 @@ Lowering table (DESIGN.md §19):
                   memcpy of the requested span (kernel mode: masked
                   slice on target row + ppermute target→origin)
     accumulate    whole-mesh bucket kernel with bitcast u8→dtype→u8
-                  and the op mapped through coll/pipeline's jnp binop
+                  and the op mapped through coll/plan's jnp binop
                   table (read-modify-write stays on device)
     get_accumulate / fetch_and_op   accumulate kernel variant that
                   ppermutes the pre-op bytes back to the origin
@@ -142,8 +142,8 @@ def _binop(opname: str):
         return lambda s, w: s
     if opname == "MPI_NO_OP":
         return lambda s, w: w
-    from ompi_tpu.coll.pipeline import _binop as _pipe_binop
-    return _pipe_binop(opname)
+    from ompi_tpu.coll.plan import _binop as _plan_binop
+    return _plan_binop(opname)
 
 
 class _ShardTable:
@@ -404,8 +404,8 @@ class DeviceWindow(Window):
         if v > 0:
             return _pow2floor(max(_ALIGN, v))
         try:
-            from ompi_tpu.coll import pipeline
-            s = pipeline.segment_elems(self.comm, 1)
+            from ompi_tpu.coll import plan
+            s = plan.segment_elems(self.comm, 1)
         except Exception:  # noqa: BLE001 — calibrate profile optional
             s = 1 << 20
         return _pow2floor(max(s, 1 << 16))

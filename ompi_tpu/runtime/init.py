@@ -236,7 +236,7 @@ def mpi_finalize(state: ProcState) -> None:
     # is a standing flag, not a one-shot disarm
     state.progress.suppress_interrupts = True
     state.progress.interrupt = None
-    # flush deferred work (fused device collectives, dispatcher queue)
+    # flush deferred work (fused device collectives)
     # BEFORE the fence: a flush may need one last cross-rank
     # rendezvous, so peers must still be alive and symmetric here
     state.progress.run_finalize_hooks()

@@ -95,7 +95,7 @@ class Progress:
         self._park_set: list = []
         self._park_clear: list = []
         # finalize hooks: subsystems with pending deferred work (fused
-        # device collectives, the device dispatcher queue) flush here.
+        # device collectives) flush here.
         # mpi_finalize runs them BEFORE the finalize fence so a flush
         # that needs a cross-rank rendezvous still has live peers.
         self._finalize_hooks: List[Callable[[], None]] = []

@@ -106,8 +106,7 @@ def run_ranks(n: int, fn: Callable, devices: bool = False,
                 if isinstance(e, _ulfm.RankKilled):
                     # the injected death IS the test scenario: the
                     # rank is gone, survivors mitigate via ULFM.
-                    # Mark the corpse for process-wide accounting
-                    # (coll.device last-rank dispatcher drain) —
+                    # Mark the corpse for process-wide accounting —
                     # whatever raised RankKilled, this incarnation
                     # will never run mpi_finalize
                     try:

@@ -91,7 +91,7 @@ def group_ops(events: List[dict]) -> Dict[tuple, List[dict]]:
     """Correlate whole-op spans across ranks.
 
     Device-tier collectives group on ``(cat, cid, seq)`` — the per-comm
-    device sequence number ticks on every op/segment on every member,
+    device sequence number ticks on every rendezvous on every member,
     sampled out or not, so surviving spans keep aligned keys.  p2p
     spans group on the ob1 match id ``mid`` (identical on sender and
     receiver)."""
@@ -196,8 +196,7 @@ def _fmt_bytes(n: int) -> str:
 
 def _op_alg(op: dict) -> Optional[str]:
     """Algorithm label of a whole-op dispatch span, or None when the
-    span is not an (alg, size) context (segment spans ride inside a
-    pipeline_* span that already carries the algorithm)."""
+    span is not an (alg, size) context."""
     name = op["name"]
     if name == "meet":
         return "fused"
@@ -210,7 +209,7 @@ def _op_alg(op: dict) -> Optional[str]:
 def dispatch_tax(events: List[dict],
                  idx: Dict[int, List[dict]]) -> Dict[str, Dict[str, float]]:
     """Median us per phase per (algorithm, pow2-size) — the measured
-    answer to "where does a segmented op's time actually go"."""
+    answer to "where does a large-message op's time actually go"."""
     acc: Dict[Tuple[str, int], Dict[str, List[float]]] = {}
     for op in _spans(events):
         if op.get("cat") != "coll_dispatch":
@@ -270,6 +269,12 @@ def analyze(dumps: List[dict], offsets_us: List[float],
         ranks = {m["rank"] for m in members}
         if len(ranks) < 2:
             continue
+        if key[0] == "p2p" and len(members) != 2:
+            # one match is one send and one receive; the match id
+            # carries no destination, so a source's streams to several
+            # peers share ids: such a group is not one operation and
+            # its "last starter" gates nothing
+            continue
         multi += 1
         gate, skew = _gate_of(members)
         skews.append(skew)
@@ -280,8 +285,9 @@ def analyze(dumps: List[dict], offsets_us: List[float],
         gating[gkey] = gating.get(gkey, 0) + 1
 
     # coverage over whole-op spans that HAVE a phase-profiled window:
-    # meet/seg_meet (per-op, per-segment) — pipeline_* wraps the same
-    # wall time again and would double the denominator
+    # meet (one per rendezvous) and plan_exec (a planned operation's
+    # pack, meet and unpack) — pipeline_* wraps the same wall time
+    # again and would double the denominator
     op_wall = 0.0
     attributed = 0.0
     ops = 0

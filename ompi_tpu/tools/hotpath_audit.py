@@ -66,15 +66,11 @@ HOT_FUNCTIONS: Dict[str, Tuple[str, ...]] = {
     # per op at the gate, never inside these
     "ompi_tpu/coll/device.py": (
         "_phase_fn",
-        # the publisher's steps of a traced meeting (ISSUE 26): the
-        # spans of a KEPT operation are built in _pub_span, off these
+        # the publisher's steps of a traced meeting (ISSUE 26)
         "_mesh_exec",
         "_stacked_exec",
     ),
-    "ompi_tpu/coll/pipeline.py": (
-        "_pull_segment",
-    ),
-    # the compiled-plan executor (DESIGN.md §22) runs once per
+    # the compiled-plan executor (DESIGN.md §12) runs once per
     # large-message collective in steady state: span shell, the single
     # rendezvous, integer pvar adds.  Packing, key construction and
     # plan/executable resolution live in helpers off this path

@@ -67,11 +67,11 @@ published process-wide as the ``trace_layer_<name>_ns`` pvars and
 rank-thread's tracer): one pvar for each number a metric reads.
 
 On top of the same ring, fixed log2-bucket latency histograms
-(progress tick, collective dispatch, p2p completion, per-segment
-rendezvous) are maintained per rank and exposed as MPI_T pvars —
-``bench.py --trace-overhead`` snapshots them into BENCH_DETAIL.json,
-and ``ompi_tpu/coll/autotune.py`` folds them back into the calibrate
-profile online.  Histograms count KEPT spans only, so histogram
+(progress tick, collective dispatch, p2p completion, planned
+large-message collective) are maintained per rank and exposed as
+MPI_T pvars — ``bench.py --trace-overhead`` snapshots them into
+BENCH_DETAIL.json, and ``ompi_tpu/coll/autotune.py`` folds them back
+into the calibrate profile online.  Histograms count KEPT spans only, so histogram
 totals always equal ring span counts per category.
 
 The collective/nbc hooks here (``coll_begin``/``coll_end``,
@@ -88,7 +88,6 @@ import threading
 import time
 import weakref
 from array import array
-from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 from ompi_tpu import peruse
@@ -158,7 +157,7 @@ BUCKET_BOUNDS_US = tuple(1 << i for i in range(N_BUCKETS - 1))
 HIST_PROGRESS_TICK = 0
 HIST_COLL_DISPATCH = 1
 HIST_P2P_COMPLETE = 2
-HIST_COLL_SEGMENT = 3  # per-segment rendezvous latency (pipeline tier)
+HIST_COLL_SEGMENT = 3  # planned large-message collective latency (plan_exec)
 HIST_SERVE_ATTACH = 4  # DVM session-attach latency (tools/dvm)
 HIST_RDV_WAIT = 5      # rendezvous-wait phase (straggler-skew gauge)
 HIST_NAMES = ("progress_tick", "coll_dispatch", "p2p_complete",
@@ -255,15 +254,14 @@ _OP_CAT_IDS = (CAT_COLL, CAT_COLL_DISPATCH, CAT_COLL_SEGMENT, CAT_PHASE)
 NAME_SEND = intern_name("send", ("cid", "src", "tag", "seq", "bytes"))
 NAME_RECV = intern_name("recv", ("cid", "src", "tag", "seq", "bytes"))
 NAME_NBC = intern_name("nbc", ("cid", "seq"))
-# ``seq`` is the device-tier sequence (one per rendezvous, so segments
-# stay apart); ``op`` is the enclosing operation's collective sequence,
-# the key its coll span and its phase spans carry
+# ``seq`` is the device-tier sequence (one per rendezvous); ``op`` is
+# the enclosing operation's collective sequence, the key its coll span
+# and its phase spans carry
 NAME_MEET = intern_name("meet", ("cid", "seq", "nbytes", "op"))
-NAME_SEG_MEET = intern_name("seg_meet", ("cid", "seq", "nbytes", "op"))
-# one span per compiled-plan collective (DESIGN.md §22): pack, the
+# one span per compiled-plan collective (DESIGN.md §12): pack, the
 # single rendezvous and unpack all inside it.  Categorized under
-# coll_segment so HIST_COLL_SEGMENT keeps a latency pulse when the
-# plan path replaces per-segment meets
+# coll_segment: HIST_COLL_SEGMENT is the large-message tier's latency
+# pulse (coll/autotune folds it)
 NAME_PLAN_EXEC = intern_name("plan_exec", ("cid", "nbytes", "alg$", "op"))
 NAME_FUSED_FLUSH = intern_name("fused_flush", ("cid", "ops", "seq"))
 NAME_FUSED_PACK = intern_name("fused_pack", ("cid", "groups", "slots"))
@@ -395,7 +393,7 @@ class Tracer:
         "_over", "_auto", "_max_period", "_p0", "_plo", "_phi",
         "phase", "sync_offsets_us",
         "_req_tags", "_req_ts", "_req_n",
-        "_lns", "_t_cur", "_cur_k", "_t_ret", "_done",
+        "_lns", "_t_cur", "_cur_k", "_t_ret",
         "__weakref__",
     )
 
@@ -450,10 +448,6 @@ class Tracer:
         self._t_cur = 0
         self._cur_k = L_ENTRY
         self._t_ret = 0
-        # phase spans closed by another thread (device.py's dispatcher
-        # thread, on this rank's behalf) wait here until this rank's
-        # own thread files them: the ring keeps one writer
-        self._done: deque = deque()
         # mpisync offsets measured at finalize (sync_state) ride the
         # dump so traceview/critpath need no hand-plumbed --sync file
         self.sync_offsets_us: Optional[List[float]] = None
@@ -578,7 +572,7 @@ class Tracer:
         sampled-out, a kept one is counted by the end() that follows,
         so kept + sampled_out == seen per category on every rank.  The
         sites that run on every blocking collective (coll_begin,
-        device.meet, meet_begin, the pipeline and plan entries) carry
+        device.meet, the pipeline and plan entries) carry
         the same test inline: the sampled-out steady state is two
         compares, a modulo and a list store, no call, no clock read."""
         if not self._plo <= seq < self._phi:
@@ -707,12 +701,11 @@ class Tracer:
 
     # -- layer accounting (trace_phase_enable) ---------------------------
     # One clock read per boundary.  The cursor belongs to the rank's
-    # own thread; the publisher's steps (another thread when the
-    # dispatcher runs them, on the triggering rank's behalf) bank into
-    # accumulators the cursor never touches.  The rendezvous' own
-    # boundaries (deposit, woken) and the shim's are banked inline
-    # where they are read (device.Rendezvous, coll_begin / coll_end):
-    # they run on every operation of every rank.
+    # own thread; the publisher's steps (the last arriver's, inside
+    # its own serve interval) bank into accumulators the cursor never
+    # touches.  The rendezvous' own boundaries (deposit, woken) and the
+    # shim's are banked inline where they are read (device.Rendezvous,
+    # coll_begin / coll_end): they run on every operation of every rank.
     def lap(self, _pcns=time.perf_counter_ns) -> int:
         """A boundary that starts a named interval (pack, unpack):
         banks the time since the last boundary where it belongs (entry
@@ -735,15 +728,6 @@ class Tracer:
             self._t_cur = now
             self._cur_k = then
         return now
-
-    def file_done(self) -> None:
-        """File the phase spans the dispatcher thread closed on this
-        rank's behalf (start, end, name, cid, seq, nbytes: ph_dispatch
-        and its parts) into the ring, from this rank's own thread."""
-        done = self._done
-        while done:
-            t0, t1, name_id, cid, seq, nb = done.popleft()
-            self.end_at(t0, t1, name_id, CAT_PHASE, cid, seq, nb)
 
     def layer_totals(self) -> Dict[str, int]:
         """{layer: ns} of this tracer, and the rendezvous count (cold)."""
@@ -896,7 +880,6 @@ class Tracer:
         """Events oldest-first, materialized as span dicts (the dump
         schema — id decode and string synthesis happen here, off the
         hot path).  Timestamps become epoch seconds via the anchor."""
-        self.file_done()
         out = []
         for i in self._live_range():
             e = {"name": _names[self._name[i]],
@@ -914,7 +897,6 @@ class Tracer:
         spans, which ARE the compile phase) from the live ring — the
         obs_critpath_phase_us gauge.  Cold path: pvar reads and the
         probe harness only."""
-        self.file_done()
         compile_cid = _cat_ids.get("compile", -1)
         out: Dict[str, int] = {}
         for i in self._live_range():
@@ -930,7 +912,6 @@ class Tracer:
         return out
 
     def span_count(self, cat) -> int:
-        self.file_done()
         cid = _cat_ids.get(cat, -1) if isinstance(cat, str) else cat
         n = 0
         for i in self._live_range():
@@ -1181,8 +1162,8 @@ for _i, _layer in enumerate(LAYERS):
         getter=_layer_sum(_i))
 registry.register_pvar(
     "trace", "layer", "rendezvous_count",
-    help="Rendezvous the layer account saw (one per unsegmented "
-         "operation, one per segment of a pipelined one), summed over "
+    help="Rendezvous the layer account saw (one per blocking device "
+         "collective), summed over "
          "every rank-thread of the process (trace_phase_enable)",
     getter=_layer_sum(L_RENDEZVOUS))
 registry.register_pvar(
@@ -1204,8 +1185,8 @@ registry.register_pvar(
     getter=_tr_hist(HIST_P2P_COMPLETE))
 registry.register_pvar(
     "trace", "", "hist_coll_segment", var_class="size",
-    help="Per-segment rendezvous latency histogram of the pipelined "
-         "large-message tier (log2 us buckets)",
+    help="Latency histogram of the large-message tier's planned "
+         "collectives, plan_exec spans (log2 us buckets)",
     getter=_tr_hist(HIST_COLL_SEGMENT))
 registry.register_pvar(
     "trace", "", "hist_serve_attach", var_class="size",
@@ -1251,10 +1232,9 @@ def coll_begin(comm, name_id: int, _peruse=peruse, _CAT=CAT_COLL,
     sequence number, the same on every member; sampled out with the
     phase profiler off it takes no clock read and makes no call.  With
     the profiler armed this is also the first boundary of the
-    operation's layer account: it closes the open caller interval,
-    opens the entry interval, and files the spans the dispatcher
-    thread closed for this rank since its last operation.  Not a
-    Tracer method: the shim runs on every collective of every rank."""
+    operation's layer account: it closes the open caller interval and
+    opens the entry interval.  Not a Tracer method: the shim runs on
+    every collective of every rank."""
     tr = comm.state.tracer
     if tr is None:
         if _peruse.enabled:
@@ -1272,8 +1252,6 @@ def coll_begin(comm, name_id: int, _peruse=peruse, _CAT=CAT_COLL,
             tr._t_ret = 0
         tr._t_cur = now
         tr._cur_k = L_ENTRY
-        if tr._done:
-            tr.file_done()
         if seq % tr._period[_CAT]:
             tr._skipped[_CAT] += 1
             now = -1

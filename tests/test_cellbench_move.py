@@ -2,22 +2,19 @@
 moving data through the large-message tier, held to the benchmark's
 plain references on the CPU.
 
-* ``bcast_arr`` through the segmented tier equals
+* ``bcast_arr`` through the tier's compiled plans equals
   cellbench/reference_rooted.py bit for bit, for every root and for a
   count that leaves a tail; ``alltoall_arr`` equals
   cellbench/reference.py on every rank; both equal the fused
   single-dispatch path byte for byte;
-* the same through the compiled plans (ISSUE 29, the default): equal to
-  the references and to the per-segment path, every bit pattern
-  delivered as sent, one rendezvous a call, the pipeline's counters at
-  rest, programs named for their algorithms and free of arithmetic;
+* every bit pattern is delivered as sent, a call is one rendezvous,
+  the segment counter stays at rest, and the programs are named for
+  their algorithms and free of arithmetic;
 * the lower-precision control (inputs handed over in bfloat16) and an
   answer that is the rank's own input are NOT correct;
 * the rooted reference against a two-line numpy statement of itself,
   the required-bytes rules of cellbench/bytes_mesh.py at the cells'
   sizes, the reader of ``move_roofline``, the choice of compared ranks;
-* what the program brings for the cells: ``coll_pipeline_inflight`` and
-  the segment kernels' stable program names;
 * BENCHMARK.json is valid with the six cells.
 """
 import functools
@@ -44,25 +41,18 @@ import jax.numpy as jnp  # noqa: E402
 
 # register the knobs before any snapshot of them
 import ompi_tpu.coll.pipeline as pipeline  # noqa: E402
-import ompi_tpu.coll.plan  # noqa: E402,F401
+import ompi_tpu.coll.plan as plan  # noqa: E402
 
 P = 4
 SEED = 3000000019            # the driver's seeds pass 2**31
 SEG_ELEMS = 1024             # coll_seg_size 4096 B of float32
-DEPTH = 2
-# the knobs tests/test_coll_pipeline.py uses: everything from 2 KiB
-# through 4 KiB segments, several segments an operation
-SEGMENTED = {"coll_pipeline_enable": True, "coll_pipeline_min_bytes": 2048,
-             "coll_seg_size": 4 * SEG_ELEMS, "coll_pipeline_depth": DEPTH,
-             "coll_pipeline_rd_max_bytes": 0, "coll_hier_enable": False,
-             "coll_plan_enable": False}
 # the default knobs but for the test-sized crossover and segment: the
 # compiled plans (coll/plan.mesh_move) serve both operations
 PLANNED = {"coll_pipeline_min_bytes": 2048, "coll_seg_size": 4 * SEG_ELEMS}
 FUSED = {"coll_pipeline_enable": False, "coll_hier_enable": False}
-TIERS = {"segmented": SEGMENTED, "planned": PLANNED, "fused": FUSED}
+TIERS = {"planned": PLANNED, "fused": FUSED}
 # elements per rank: (bcast, alltoall); the tail case leaves 3 elements
-# of a bcast segment and 7 columns of an alltoall segment over
+# of a bcast segment and 7 columns of an alltoall's blocks over
 COUNTS = {"whole": (4 * SEG_ELEMS, 4 * SEG_ELEMS),
           "tail": (4 * SEG_ELEMS + 3, P * (SEG_ELEMS + 7))}
 
@@ -78,12 +68,11 @@ def knobs_set(vals):
 def answers(tier: str, count: str, control=None):
     """One world of four rank-threads on four devices: every root's
     bcast and one alltoall of the seed's inputs, as host arrays, and
-    what the pipeline's counters moved by; per rank."""
+    what the tier's counters moved by; per rank."""
     nb, na = COUNTS[count]
 
     def body(comm):
-        before = [v.read() for v in (pipeline.pv_ops, pipeline.pv_segments,
-                                     pipeline.pv_inflight)]
+        before = [v.read() for v in (pipeline.pv_ops, plan.pv_segments)]
         xb = make_input(jax, jnp, comm, SEED, nb, control)
         xa = make_input(jax, jnp, comm, SEED, na, control)
         out = {("bcast", root): np.asarray(comm.bcast_arr(xb, root))
@@ -91,8 +80,7 @@ def answers(tier: str, count: str, control=None):
         out["alltoall"] = np.asarray(comm.alltoall_arr(xa))
         comm.Barrier()
         out["moved"] = [v.read() - b for v, b in zip(
-            (pipeline.pv_ops, pipeline.pv_segments, pipeline.pv_inflight),
-            before)]
+            (pipeline.pv_ops, plan.pv_segments), before)]
         out["provider"] = comm.coll.providers.get("bcast_arr")
         return out
 
@@ -117,55 +105,27 @@ def alltoall_gap(got, rank, n):
 
 @pytest.mark.parametrize("count", ["whole", "tail"])
 @pytest.mark.parametrize("root", range(P))
-def test_planned_bcast_equals_the_reference_and_the_segments(root, count):
+def test_planned_bcast_equals_the_reference_and_the_fused_tier(root, count):
     n = COUNTS[count][0]
-    plan, seg = answers("planned", count), answers("segmented", count)
+    planned, fused = answers("planned", count), answers("fused", count)
     for rank in range(P):
-        got = plan[rank]["bcast", root]
-        assert got.dtype == np.float32 and got.shape == (n,)
-        assert bcast_gap(got, rank, root, n) == 0.0
-        assert got.tobytes() == reference.values(SEED, root, 0, n).tobytes()
-        assert got.tobytes() == seg[rank]["bcast", root].tobytes()
-    ops, segs, inflight = plan[0]["moved"]
-    assert plan[0]["provider"] == "tpu"
-    assert ops == P * (P + 1)              # the tier counted every call
-    assert (segs, inflight) == (0, 0)      # and no segment ran
-
-
-@pytest.mark.parametrize("count", ["whole", "tail"])
-def test_planned_alltoall_equals_the_reference_and_the_segments(count):
-    n = COUNTS[count][1]
-    plan, seg = answers("planned", count), answers("segmented", count)
-    for rank in range(P):
-        got = plan[rank]["alltoall"]
-        assert got.dtype == np.float32 and got.shape == (n,)
-        assert alltoall_gap(got, rank, n) == 0.0
-        assert got.tobytes() == seg[rank]["alltoall"].tobytes()
-
-
-@pytest.mark.parametrize("count", ["whole", "tail"])
-@pytest.mark.parametrize("root", range(P))
-def test_segmented_bcast_equals_the_rooted_reference(root, count):
-    n = COUNTS[count][0]
-    seg, fused = answers("segmented", count), answers("fused", count)
-    for rank in range(P):
-        got = seg[rank]["bcast", root]
+        got = planned[rank]["bcast", root]
         assert got.dtype == np.float32 and got.shape == (n,)
         assert bcast_gap(got, rank, root, n) == 0.0
         assert got.tobytes() == reference.values(SEED, root, 0, n).tobytes()
         assert got.tobytes() == fused[rank]["bcast", root].tobytes()
-    ops, segs, _ = seg[0]["moved"]
-    assert seg[0]["provider"] == "tpu"
-    assert ops >= P * (P + 1) and segs > ops     # the tier engaged
-    assert fused[0]["moved"] == [0, 0, 0]        # and the other did not
+    assert planned[0]["provider"] == "tpu"
+    # the tier counted every call, and a mover adds no segments
+    assert planned[0]["moved"] == [P * (P + 1), 0]
+    assert fused[0]["moved"] == [0, 0]           # the other did not engage
 
 
 @pytest.mark.parametrize("count", ["whole", "tail"])
-def test_segmented_alltoall_equals_the_reference(count):
+def test_planned_alltoall_equals_the_reference_and_the_fused_tier(count):
     n = COUNTS[count][1]
-    seg, fused = answers("segmented", count), answers("fused", count)
+    planned, fused = answers("planned", count), answers("fused", count)
     for rank in range(P):
-        got = seg[rank]["alltoall"]
+        got = planned[rank]["alltoall"]
         assert got.dtype == np.float32 and got.shape == (n,)
         assert alltoall_gap(got, rank, n) == 0.0
         assert got.tobytes() == fused[rank]["alltoall"].tobytes()
@@ -176,7 +136,7 @@ def test_bf16_control_is_not_correct(op):
     """Inputs rounded to bfloat16 before the library sees them: the
     answer is 2**-9 off somewhere, far above the limit of 0."""
     nb, na = COUNTS["tail"]
-    res = answers("segmented", "tail", "bf16")
+    res = answers("planned", "tail", "bf16")
     for rank in range(P):
         if op == "bcast":
             g = min(bcast_gap(res[rank]["bcast", root], rank, root, nb)
@@ -306,10 +266,9 @@ def test_planned_bcast_delivers_every_bit_pattern(root):
 
 @pytest.mark.parametrize("op", ["bcast", "alltoall"])
 def test_a_planned_call_is_one_rendezvous(op):
-    """One call is one meeting on every rank, nothing passes through
-    the segment pipeline, and the second call of a shape is served by
+    """One call is one meeting on every rank, a mover adds nothing to
+    the segment counter, and the second call of a shape is served by
     the plan the first resolved."""
-    from ompi_tpu.coll import plan
     n = COUNTS["tail"][op == "alltoall"]
 
     def body(comm):
@@ -320,7 +279,7 @@ def test_a_planned_call_is_one_rendezvous(op):
 
         def counted():
             return [tr.layer_totals()["rendezvous"]] + [v.read() for v in (
-                pipeline.pv_ops, pipeline.pv_segments, pipeline.pv_inflight,
+                pipeline.pv_ops, plan.pv_segments,
                 plan.pv_builds, plan.pv_hits)]
 
         jax.block_until_ready(call())
@@ -338,13 +297,12 @@ def test_a_planned_call_is_one_rendezvous(op):
     finally:
         knobs_set(saved)
     # the rank's own meetings; the process-wide counters, all four ranks'
-    assert res == [[1, P, 0, 0, 0, P]] * P
+    assert res == [[1, P, 0, 0, P]] * P
 
 
 @pytest.mark.parametrize("alg,root", [("segbcast", 1), ("sega2a", None)])
 def test_planned_programs_are_named_and_move_data_only(alg, root):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
-    from ompi_tpu.coll import plan
     mesh = Mesh(np.array(jax.devices()[:P]), ("r",))
     total = 5 * SEG_ELEMS
     jfn = plan._compile_mesh_move(alg, mesh, P, total, root)
@@ -362,33 +320,6 @@ def test_planned_programs_are_named_and_move_data_only(alg, root):
     assert ("stablehlo.all_gather" if alg == "segbcast"
             else "stablehlo.all_to_all") in text
     assert "all-reduce" not in lowered.compile().as_text()
-
-
-# -- what the program brings for the cells -------------------------------------
-
-def test_inflight_counter_is_bounded_by_the_depth():
-    """coll_pipeline_inflight adds, at every segment begun, the handles
-    outstanding on that rank (the new one included): at least one a
-    segment, at most depth + 1."""
-    _, segs, inflight = answers("segmented", "tail")[0]["moved"]
-    # 4 bcasts of 5 segments and an alltoall of 5, on 4 rank-threads
-    assert segs == P * (P * 5 + 5)
-    assert segs <= inflight <= (DEPTH + 1) * segs
-    assert inflight > segs          # something was outstanding
-
-
-@pytest.mark.parametrize("kind,extra", [
-    ("segbcast", 0), ("sega2a", None), ("segring", "MPI_SUM"),
-    ("segrd", "MPI_SUM")])
-def test_segment_kernels_carry_stable_program_names(kind, extra):
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-    mesh = Mesh(np.array(jax.devices()[:P]), ("r",))
-    jfn = pipeline._build_seg_kernel(kind, mesh, SEG_ELEMS, np.float32,
-                                     extra)
-    x = jax.ShapeDtypeStruct((P * SEG_ELEMS,), jnp.float32,
-                             sharding=NamedSharding(mesh,
-                                                    PartitionSpec("r")))
-    assert f"@jit_ompi_{kind} " in jfn.lower(x).as_text()
 
 
 # -- the manifest ---------------------------------------------------------------

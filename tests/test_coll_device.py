@@ -286,7 +286,7 @@ def _a2a_world(shape, dtype, min_bytes):
     ``shape`` with the large-message tier starting at ``min_bytes``,
     traced so that the layer account counts the rendezvous.  Per rank:
     the bytes, what the result is, and what moved."""
-    from ompi_tpu.coll import pipeline
+    from ompi_tpu.coll import pipeline, plan
     from ompi_tpu.mca.params import registry
     hbm = registry.register_pvar("coll", "hbm", "offloaded_collectives")
 
@@ -297,7 +297,7 @@ def _a2a_world(shape, dtype, min_bytes):
         assert x.nbytes >= 2048
 
         def counters():
-            return (pipeline.pv_ops.read(), pipeline.pv_segments.read(),
+            return (pipeline.pv_ops.read(), plan.pv_segments.read(),
                     hbm.read(), tr.layer_totals()["rendezvous"])
 
         comm.Barrier()

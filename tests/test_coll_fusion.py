@@ -196,32 +196,6 @@ def test_fused_batch_mismatch_is_clear_error():
 
 
 # ---------------------------------------------------------------------------
-# dispatcher drain (satellite): flush at finalize, reject afterwards
-# ---------------------------------------------------------------------------
-
-def test_dispatcher_drains_rejects_and_revives():
-    from ompi_tpu.coll import device as dmod
-
-    saved = _set({"coll_device_dispatcher": True})
-    try:
-        res = run_ranks(2, lambda c: int(np.asarray(
-            c.allreduce_arr(jnp.int32(1), mpi_op.SUM))), devices=True)
-        assert res == [2, 2]
-    finally:
-        _restore(saved)
-    d = dmod._dispatcher_singleton
-    assert d is not None and d.closed  # last finalize drained it
-    with pytest.raises(RuntimeError, match="closed"):
-        d.submit(lambda: None)
-    with pytest.raises(RuntimeError, match="finalize"):
-        dmod._dispatcher()
-    # a fresh world in the same process revives the plane
-    res = run_ranks(2, lambda c: int(np.asarray(
-        c.allreduce_arr(jnp.int32(3), mpi_op.SUM))), devices=True)
-    assert res == [6, 6]
-
-
-# ---------------------------------------------------------------------------
 # measured crossover selection (coll/calibrate)
 # ---------------------------------------------------------------------------
 

@@ -1,4 +1,4 @@
-"""Compiled collective plans (coll/plan, DESIGN.md §22): byte
+"""Compiled collective plans (coll/plan, DESIGN.md §12): byte
 identity against the fused path across algorithms / dtypes / ragged
 tails, exactly ONE rendezvous per op, cache lifetime across ULFM
 epochs and autotone-style purges, and the shared staging utility the
@@ -40,7 +40,7 @@ def _restore(saved):
 # segment pow2 quantization all exercised at test-sized arrays
 PLAN_ON = {"coll_pipeline_enable": True, "coll_pipeline_min_bytes": 2048,
            "coll_seg_size": 4096, "coll_pipeline_rd_max_bytes": 0,
-           "coll_hier_enable": False, "coll_plan_enable": True}
+           "coll_hier_enable": False}
 FUSED = {"coll_pipeline_enable": False, "coll_hier_enable": False}
 
 
@@ -68,6 +68,45 @@ def _reduce_ops(comm):
     return b"".join(out)
 
 
+def _odd_dtype_ops(comm):
+    """Odd dtypes through the identity-padded tail: int8 (sum stays in
+    range), float16, float64, int64 PROD (never native)."""
+    r = comm.rank
+    out = []
+    x8 = _put(comm, (jnp.arange(4097) % 3).astype(jnp.int8)
+              + np.int8(r % 2))
+    out.append(np.asarray(comm.allreduce_arr(x8, mpi_op.SUM)).tobytes())
+    h = _put(comm, ((jnp.arange(2050) % 8) + r).astype(jnp.float16))
+    out.append(np.asarray(comm.allreduce_arr(h, mpi_op.MAX)).tobytes())
+    d = _put(comm, (jnp.arange(1025, dtype=jnp.float64) % 9) + r)
+    out.append(np.asarray(comm.allreduce_arr(d, mpi_op.SUM)).tobytes())
+    i64 = _put(comm, (jnp.arange(1000, dtype=jnp.int64) % 13) * (r + 1))
+    out.append(np.asarray(comm.allreduce_arr(i64, mpi_op.PROD)).tobytes())
+    return b"".join(out)
+
+
+def _mixed_ops(comm):
+    """Every operation the tier plans, over sizes that leave tails:
+    allreduce SUM / MAX / BAND, a bcast from a middle root, an
+    alltoall of odd blocks.  Returns (rank-symmetric bytes, this
+    rank's alltoall rows)."""
+    r = comm.rank
+    P = comm.size
+    out = []
+    base = (jnp.arange(4099, dtype=jnp.float32) % 11).astype(jnp.float32)
+    x = _put(comm, base + r)
+    out.append(np.asarray(comm.allreduce_arr(x, mpi_op.SUM)).tobytes())
+    xi = _put(comm, (jnp.arange(3072, dtype=jnp.int32) % 17) * (r + 1))
+    out.append(np.asarray(comm.allreduce_arr(xi, mpi_op.MAX)).tobytes())
+    xb = _put(comm, jnp.full(2048 + 1, 0xFF ^ (1 << r), jnp.uint32))
+    out.append(np.asarray(comm.allreduce_arr(xb, mpi_op.BAND)).tobytes())
+    b = _put(comm, base * (r + 1))
+    out.append(np.asarray(comm.bcast_arr(b, root=min(2, P - 1)))
+               .tobytes())
+    a = _put(comm, jnp.arange(1031 * P, dtype=jnp.int32) + 100000 * r)
+    return b"".join(out), np.asarray(comm.alltoall_arr(a)).tobytes()
+
+
 def _run_vs_fused(fn, n=4, plan_knobs=None, **kw):
     saved = _set(dict(PLAN_ON, **(plan_knobs or {})))
     try:
@@ -86,15 +125,16 @@ def _run_vs_fused(fn, n=4, plan_knobs=None, **kw):
 # byte identity + the one-rendezvous contract
 # ---------------------------------------------------------------------------
 
-def test_plan_mesh_byte_identical_mixed_dtypes():
+@pytest.mark.parametrize("ops", [_reduce_ops, _odd_dtype_ops])
+def test_plan_mesh_byte_identical_mixed_dtypes(ops):
     """Plan-path mesh allreduce (segring pick): bytes equal to fused
     across dtypes and ragged tails, every rank agreeing, and the plan
     pvars actually moving."""
     def fn(comm):
         from ompi_tpu.coll import plan
         b0, h0 = plan.pv_builds.read(), plan.pv_hits.read()
-        out = _reduce_ops(comm)
-        again = _reduce_ops(comm)  # second pass: every geometry hits
+        out = ops(comm)
+        again = ops(comm)  # second pass: every geometry hits
         comm.Barrier()
         return (out, again,
                 plan.pv_builds.read() - b0, plan.pv_hits.read() - h0)
@@ -107,6 +147,35 @@ def test_plan_mesh_byte_identical_mixed_dtypes():
         assert pb2 == pb                  # deterministic on repeat
         assert dbuilds > 0 and dhits > 0  # plan tier engaged + reused
         assert fh == 0                    # fused run untouched
+
+
+def test_tier_mesh_byte_identical_and_counted():
+    """The fast deterministic 4-rank gate: every operation of the tier
+    returns the same bytes as the fused path, all ranks agree, and the
+    tier's counters say what it did: one ``coll_pipeline_ops`` an
+    operation, and ``coll_pipeline_segments`` advancing by a planned
+    allreduce's segment count (the movers add none)."""
+    from ompi_tpu.coll import pipeline, plan
+
+    def fn(comm):
+        comm.Barrier()   # thread-ranks share the process-wide pvars
+        ops0 = pipeline.pv_ops.read()
+        segs0 = plan.pv_segments.read()
+        comm.Barrier()
+        common, a2a = _mixed_ops(comm)
+        comm.Barrier()
+        return common, a2a, pipeline.pv_ops.read() - ops0, \
+            plan.pv_segments.read() - segs0
+
+    seg, fused = _run_vs_fused(fn, 4, devices=True)
+    assert len({c for c, _, _, _ in seg}) == 1   # ranks byte-agree
+    # 1,024-element segments of 4-byte items: 4,099 floats are 5
+    # segments, 3,072 ints 3, 2,049 words 3; on each of 4 ranks
+    for (sc, sa, dops, dsegs), (fc, fa, fops, fsegs) in zip(seg, fused):
+        assert sc == fc and sa == fa             # tier is invisible
+        assert dops == 5 * 4                     # ...but engaged
+        assert dsegs == (5 + 3 + 3) * 4
+        assert fops == 0 and fsegs == 0          # fused run untouched
 
 
 @pytest.mark.parametrize("nranks", [4, 3])
@@ -157,8 +226,8 @@ def test_plan_segrd_and_hop_explicit_byte_identical():
 
 def test_plan_one_rendezvous_per_op():
     """THE structural claim: on the plan path an N-segment collective
-    is ONE meet — no per-segment seg_meet spans, one plan_exec span
-    per op, and meet-span count == op count."""
+    is ONE meet: one plan_exec span per op, and meet-span count ==
+    op count."""
     def fn(comm):
         ops = 0
         for n in (4096, 4097, 6144):  # multi-segment sizes
@@ -168,35 +237,32 @@ def test_plan_one_rendezvous_per_op():
             ops += 1
         tr = comm.state.tracer
         names = [e["name"] for e in tr.snapshot() if e["ph"] == "X"]
-        return (ops, names.count("meet"), names.count("seg_meet"),
-                names.count("plan_exec"))
+        return ops, names.count("meet"), names.count("plan_exec")
 
     saved = _set(dict(PLAN_ON, trace_enable="1", trace_dump_path=""))
     try:
         res = run_ranks(4, fn, devices=True)
     finally:
         _restore(saved)
-    for ops, meets, seg_meets, plan_execs in res:
+    for ops, meets, plan_execs in res:
         assert meets == ops == plan_execs == 3
-        assert seg_meets == 0
-    # the plan_exec spans land in the coll_segment histogram, so the
-    # autotune fold keeps a per-op latency pulse on the plan path
-    def hist_fn(comm):
-        from ompi_tpu import trace
-        x = _put(comm, (jnp.arange(4099, dtype=jnp.float32) % 11))
-        comm.allreduce_arr(x, mpi_op.SUM)
-        tr = comm.state.tracer
-        return tr.hist_total(trace.HIST_COLL_SEGMENT)
-
-    saved = _set(dict(PLAN_ON, trace_enable="1", trace_dump_path=""))
-    try:
-        res = run_ranks(4, hist_fn, devices=True)
-    finally:
-        _restore(saved)
-    assert all(n >= 1 for n in res)
 
 
-def test_plan_hbm_byte_identical():
+def _hbm_allreduce_and_alltoall(comm):
+    """One ragged allreduce, which the tier plans, and one alltoall,
+    which on one chip is the stacked whole-payload kernel at every
+    size: its bytes must not depend on the tier's knobs."""
+    r = comm.rank
+    x = _put(comm, (jnp.arange(5003, dtype=jnp.float32) % 7) + r)
+    a = _put(comm, jnp.arange(1009 * comm.size, dtype=jnp.int32)
+             + 1000 * r)
+    return (np.asarray(comm.allreduce_arr(x, mpi_op.SUM)).tobytes()
+            + np.asarray(comm.alltoall_arr(a)).tobytes())
+
+
+@pytest.mark.parametrize("ops", [_reduce_ops,
+                                 _hbm_allreduce_and_alltoall])
+def test_plan_hbm_byte_identical(ops):
     """Plan path over the intra-chip (one shared device) module:
     stacked whole-payload kernel, one rendezvous, fused-identical."""
     import jax as _jax
@@ -205,7 +271,7 @@ def test_plan_hbm_byte_identical():
     def fn(comm):
         from ompi_tpu.coll import plan
         b0 = plan.pv_builds.read()
-        out = _reduce_ops(comm)
+        out = ops(comm)
         comm.Barrier()
         return out, plan.pv_builds.read() - b0
 
@@ -228,12 +294,11 @@ def test_plan_hbm_byte_identical():
 # chaos: delay faults and epoch boundaries
 # ---------------------------------------------------------------------------
 
-def test_plan_under_delay_faults():
+@pytest.mark.parametrize("fn", [_reduce_ops, _mixed_ops])
+def test_plan_under_delay_faults(fn):
     """ft_inject 'delay' at the (single) rendezvous: straggler arrival
-    order through the plan path changes nothing."""
-    def fn(comm):
-        return _reduce_ops(comm)
-
+    order through the plan path changes nothing, for the reductions
+    and for the data movers."""
     saved = _set(PLAN_ON)
     try:
         clean = run_ranks(4, fn, devices=True)
@@ -247,14 +312,18 @@ def test_plan_under_delay_faults():
     finally:
         _restore(saved)
     assert clean == chaotic
-    assert len({b for b, *_ in clean}) >= 1
+    if fn is _reduce_ops:   # rank-symmetric results
+        assert len(set(clean)) == 1
 
 
-# the planned operations of the epoch tests: the allreduce plans and
-# the two data movers of coll/plan.mesh_move; 4092 divides by the 4
-# ranks of the old epoch and the 3 of the shrunk one
+# the planned operations of the epoch tests: the allreduce plans (SUM
+# lowers to the native program, PROD to the hop-explicit ring) and the
+# two data movers of coll/plan.mesh_move; 4092 divides by the 4 ranks
+# of the old epoch and the 3 of the shrunk one
 _EPOCH_OPS = {
     "allreduce": (4099, lambda comm, x: comm.allreduce_arr(x, mpi_op.SUM)),
+    "allreduce_ring": (4099,
+                       lambda comm, x: comm.allreduce_arr(x, mpi_op.PROD)),
     "bcast": (4099, lambda comm, x: comm.bcast_arr(x, 1)),
     "alltoall": (4092, lambda comm, x: comm.alltoall_arr(x)),
 }
@@ -288,6 +357,8 @@ def test_plan_across_shrink_epoch(kind):
         time.sleep(0.5)
         new = comm.shrink()
         assert "_coll_plans" not in comm.__dict__  # epoch hygiene
+        assert "_pipeline_pick" not in comm.__dict__
+        assert "_hier_plan" not in comm.__dict__
         stale = [k for k in list(compile_cache._d)
                  if isinstance(k, tuple) and k
                  and isinstance(k[0], str) and k[0].startswith("plan_")

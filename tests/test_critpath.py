@@ -16,20 +16,15 @@ from ompi_tpu.mca.params import registry
 from ompi_tpu.testing import run_ranks
 from ompi_tpu.tools import critpath, traceview
 
-# register the plan knob _PIPE_ON pins off before any registry.set
-import ompi_tpu.coll.plan  # noqa: E402,F401
-
-# segmented-ring pipeline knobs (the test_coll_pipeline PIPE_ON shape):
-# small segments so a 16 KiB allreduce becomes several rendezvous
+# the large-message tier's knobs (the test_coll_pipeline PIPE_ON
+# shape): a 16 KiB allreduce is a planned segring of 5 segments, one
+# rendezvous, with a pack and an unpack around it (4,099 is ragged)
 _PIPE_ON = {
     "coll_pipeline_enable": True,
     "coll_pipeline_min_bytes": 2048,
     "coll_seg_size": 4096,
     "coll_pipeline_rd_max_bytes": 0,
     "coll_hier_enable": False,
-    # critpath attribution is over the PER-SEGMENT rendezvous phase
-    # structure; the compiled-plan tier collapses it to one meet
-    "coll_plan_enable": False,
 }
 
 
@@ -136,7 +131,7 @@ def test_clipped_attribution_never_exceeds_op():
 # -- the acceptance world: injected straggler named as gating ---------------
 
 def _segring_world(tmp_path, victim=None):
-    """One 4-rank segmented-ring world, phase-profiled at full
+    """One 4-rank planned-segring world, phase-profiled at full
     fidelity, dumped to tmp_path; when ``victim`` is set that rank
     straggles 40 ms at every rendezvous deposit (ft_inject)."""
     registry.set("trace_enable", "1")
@@ -175,7 +170,7 @@ def _segring_world(tmp_path, victim=None):
 
 
 def test_phase_coverage_on_clean_segring(tmp_path):
-    """Acceptance: on a clean 4-rank segmented-ring run, >=90% of op
+    """Acceptance: on a clean 4-rank planned-segring run, >=90% of op
     wall time is attributed to named phases, and the dispatch-tax
     table has per-phase medians for the segring tier."""
     dumps, offsets = _segring_world(tmp_path)
@@ -186,7 +181,7 @@ def test_phase_coverage_on_clean_segring(tmp_path):
 
 
 def test_injected_delay_names_gating_rank(tmp_path):
-    """4-rank segmented-ring world with a deterministic ft_inject
+    """4-rank planned-segring world with a deterministic ft_inject
     rendezvous delay on ONE rank: the critical-path analysis must name
     that rank as gating (arrival-gated: 'rendezvous') and stitch flow
     arrows into the Chrome trace."""
@@ -230,6 +225,8 @@ def test_hotpath_audit_declares_phase_helpers():
         "ompi_tpu/coll/device.py"]
     assert "Tracer.end_at2" in hotpath_audit.HOT_FUNCTIONS[
         "ompi_tpu/trace/__init__.py"]
-    assert "_pull_segment" in hotpath_audit.HOT_FUNCTIONS[
-        "ompi_tpu/coll/pipeline.py"]
+    assert "Plan.execute" in hotpath_audit.HOT_FUNCTIONS[
+        "ompi_tpu/coll/plan.py"]
+    # the router holds no hot function of its own any more
+    assert "ompi_tpu/coll/pipeline.py" not in hotpath_audit.HOT_FUNCTIONS
     assert hotpath_audit.audit() == []

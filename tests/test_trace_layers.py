@@ -1,7 +1,7 @@
 """The phase profiler's account of a blocking device collective
 (ISSUE 26 / docs/DESIGN.md §18): sampling of the operation categories
 by sequence number (an operation kept on one member is kept on all,
-with every segment and phase), the exact layer accumulators and their
+with its one meeting and every phase), the exact layer accumulators and their
 closure against the caller's own clock, no execute fence on the
 blocking path, the off-cost guard at the new sites, and the
 process-wide ``trace_layer_*`` pvars."""
@@ -24,11 +24,11 @@ import ompi_tpu.coll.pipeline  # noqa: E402,F401  (registers the knobs)
 import ompi_tpu.coll.plan  # noqa: E402,F401
 from ompi_tpu.coll import device  # noqa: E402
 
-# several 4 KiB segments per op in test-sized arrays, per-segment
-# rendezvous (the compiled-plan tier collapses them to one meet)
+# the large-message tier at test sizes: from 2 KiB up an operation is
+# a compiled plan of 4 KiB segments behind one rendezvous
 PIPE_ON = {"coll_pipeline_enable": True, "coll_pipeline_min_bytes": 2048,
            "coll_seg_size": 4096, "coll_pipeline_rd_max_bytes": 0,
-           "coll_hier_enable": False, "coll_plan_enable": False}
+           "coll_hier_enable": False}
 TRACE_ON = {"trace_enable": True, "trace_phase_enable": True,
             "trace_buffer_events": 65536, "trace_sample_auto": 0}
 OP_CATS = ("coll", "coll_dispatch", "coll_segment", "phase")
@@ -66,7 +66,8 @@ N_FUSED, N_SEG = 24, 6
 @pytest.fixture(scope="module")
 def period4(tmp_path_factory):
     """One 4-rank world at period 4 in every operation category:
-    24 unsegmented and 6 segmented allreduces; every rank's events,
+    24 allreduces under the tier's crossover and 6 planned ones (4,099
+    elements: ragged, so packed and unpacked); every rank's events,
     counters and dump."""
     dumps = tmp_path_factory.mktemp("period4")
 
@@ -128,51 +129,56 @@ def test_kept_operations_are_kept_on_every_member(period4, cat):
     lo, hi = res[0]["seq0"], res[0]["seq1"]
     want = {s for s in range(lo + 1, hi + 1) if s % 4 == 0}
     if cat == "coll_segment":
-        # only the segmented operations have segments: the last N_SEG
+        # only the planned operations have a plan_exec: the last N_SEG
         want = {s for s in want if s > hi - N_SEG}
     assert {seq for _cid, seq in per_rank[0]} == want
     assert res[0]["rates"][cat] == 4
 
 
-def test_kept_operation_keeps_all_segments_and_phases(period4):
-    """A kept segmented operation has every segment's seg_meet and
-    both rendezvous waits of every segment on every rank, and its
-    phases carry the key of its coll span (their parent)."""
+def test_kept_operation_keeps_its_meeting_and_phases(period4):
+    """A kept planned operation has, on every rank, its one plan_exec,
+    its one meet, both rendezvous waits, its pack and its unpack, all
+    carrying the key of its coll span (their parent); the publisher's
+    steps are there once an operation, on the one rank that ran them."""
     res, _ = period4
-    shapes = []
+    hi = res[0]["seq1"]
+    seg_ops = {s for s in range(hi - N_SEG + 1, hi + 1) if s % 4 == 0}
+    assert seg_ops
+    published = {s: [] for s in seg_ops}
     for r in res:
-        hi = r["seq1"]
-        seg_ops = {s for s in range(hi - N_SEG + 1, hi + 1) if s % 4 == 0}
-        assert seg_ops
+        assert r["seq1"] == hi
         colls = {e["args"]["seq"] for e in r["events"]
                  if e["cat"] == "coll" and e["name"] == "allreduce_arr"}
-        shape = {}
         for s in seg_ops:
             assert s in colls       # the parent span is there
-            segs = [e for e in r["events"] if e["name"] == "seg_meet"
-                    and e["args"]["op"] == s]
-            waits = [e for e in r["events"] if e["name"] == "ph_rdv_wait"
-                     and e["args"]["seq"] == s]
-            packs = [e for e in r["events"] if e["name"] == "ph_pack"
-                     and e["args"]["seq"] == s]
-            assert len(segs) >= 2
-            assert len(waits) == 2 * len(segs)
-            assert len(packs) == len(segs) + 1   # + the exhausted probe
-            shape[s] = len(segs)
-        shapes.append(shape)
-    assert all(s == shapes[0] for s in shapes)
+
+            def count(name, key="seq"):
+                return sum(1 for e in r["events"] if e["name"] == name
+                           and e["args"][key] == s)
+
+            assert count("plan_exec", "op") == 1
+            assert count("meet", "op") == 1
+            assert count("ph_rdv_wait") == 2    # slot side, collect side
+            assert count("ph_pack") == 1 and count("ph_unpack") == 1
+            assert count("ph_entry") == 1
+            steps = [count(n) for n in ("ph_dispatch", "ph_assemble",
+                                        "ph_launch", "ph_scatter")]
+            assert steps in ([0] * 4, [1] * 4)
+            published[s].append(steps[0])
+    assert all(sum(v) == 1 for v in published.values()), published
 
 
 def test_sampling_accounts_exactly_per_category(period4):
     """kept + sampled_out == seen per category and rank, with seen
     counted from the operations the test issued: one coll and one
     phase decision per operation, one coll_segment sighting per
-    segment."""
+    planned operation."""
     res, _ = period4
     n_ops = N_FUSED + N_SEG
     for r in res:
         assert r["seq1"] - r["seq0"] == n_ops
         assert r["seen"]["coll"] == n_ops
+        assert r["seen"]["coll_segment"] == N_SEG
         kept_ops = sum(1 for s in range(r["seq0"] + 1, r["seq1"] + 1)
                        if s % 4 == 0)
         assert r["kept"]["coll"] == kept_ops
@@ -196,8 +202,8 @@ def test_sampling_accounts_exactly_per_category(period4):
 
 def test_critpath_correlates_every_kept_operation(period4):
     """The gating table's input at period 4: every kept whole-op span
-    (coll, meet, seg_meet) has a member from every rank, so every
-    kept operation is correlated."""
+    (coll, meet) has a member from every rank, so every kept
+    operation is correlated."""
     _, dumps_dir = period4
     dumps = traceview.load_dumps([str(dumps_dir / "trace-r*.json")])
     assert len(dumps) == 4
@@ -341,56 +347,43 @@ def test_layer_account_closes_over_blocking_allreduces():
     assert all(d["launch"] == 0 for _w, d in res)
 
 
-def test_layer_account_closes_over_pipelined_allreduces():
-    """The segmented path: pack, per-segment rendezvous and unpack
-    sum with the rest to the wall time, and rendezvous per operation
-    is exactly the segment count (the pipeline's own counter)."""
-    from ompi_tpu.coll import pipeline
-
-    def segs():
-        return pipeline.pv_segments.read()
-
-    n0 = segs()
+@pytest.mark.parametrize("where", ["mesh", "one_chip"])
+def test_layer_account_on_the_plan_path(where):
+    """The compiled plans (the benchmark's large allreduce shapes,
+    test-sized), over four devices and on one chip: one rendezvous per
+    operation exactly, closure within 3%.  A plan brings its traced
+    twin, so the one publisher of each rendezvous banks the launch and
+    the split.  The mesh case is ragged (4,099 elements): pack and
+    unpack sum with the rest, the publisher assembles, and
+    coll_pipeline_segments advances by the plan's 5 segments an
+    operation; the one-chip case fits (4,096), packs nothing and
+    assembles nothing (the shards are the kernel's arguments)."""
+    from ompi_tpu.coll import plan
+    mesh = where == "mesh"
+    n_ops, n_elems = (30, 4099) if mesh else (100, 4096)
+    n0 = plan.pv_segments.read()
     res = _closure_world(
-        30, lambda comm: jax.device_put(
-            jnp.arange(4099, dtype=jnp.float32) + comm.rank, comm.device),
-        PIPE_ON, devices=True)
-    nsegs = set()
+        n_ops, lambda comm: jax.device_put(
+            jnp.arange(n_elems, dtype=jnp.float32) + comm.rank,
+            comm.device),
+        PIPE_ON, **({"devices": True} if mesh else {"device_map": _one_dev}))
     for wall, d in res:
         total = sum(d[k] for k in trace.LAYER_CLOSURE)
         assert abs(total - wall) <= 0.03 * wall, (total, wall, d)
-        nseg, rest = divmod(d["rendezvous"], 30)
-        assert nseg >= 2 and rest == 0
-        nsegs.add(nseg)
-        assert d["pack"] > 0 and d["unpack"] > 0
-    assert len(nsegs) == 1
-    # coll_pipeline_segments counts the same segments over all four
-    # rank-threads, the 5 warm-up operations of _closure_world too (a
-    # plain += shared by the threads: allow it a lost update or two)
-    assert abs((segs() - n0) - 4 * 35 * nsegs.pop()) <= 3
-
-
-def test_layer_account_on_one_chip_plan_path():
-    """coll/hbm through the compiled plan (the benchmark's 256 MiB
-    shape, test-sized): one rendezvous per operation, closure within
-    3%; the plan brought its traced twin, so the one publisher of each
-    rendezvous banks the launch and the split, and assembles
-    nothing."""
-    knobs = {"coll_pipeline_enable": True, "coll_pipeline_min_bytes": 2048,
-             "coll_seg_size": 4096, "coll_plan_enable": True}
-    res = _closure_world(
-        100, lambda comm: jax.device_put(
-            jnp.arange(4096, dtype=jnp.float32) + comm.rank, comm.device),
-        knobs, device_map=_one_dev)
-    for wall, d in res:
-        total = sum(d[k] for k in trace.LAYER_CLOSURE)
-        assert abs(total - wall) <= 0.03 * wall, (total, wall, d)
-        assert d["rendezvous"] == 100
-        assert d["assemble"] == 0
+        assert d["rendezvous"] == n_ops
+        assert (d["pack"] > 0 and d["unpack"] > 0) if mesh else (
+            d["pack"] == 0 and d["unpack"] == 0)
     launch = sum(d["launch"] for _w, d in res)
     serve = max(d["rdv_serve"] for _w, d in res)
     assert 0 < launch <= serve
     assert sum(d["scatter"] for _w, d in res) > 0
+    assert (sum(d["assemble"] for _w, d in res) > 0) == mesh
+    # over all four rank-threads, the 5 warm-up operations of
+    # _closure_world too (a plain += shared by the threads: allow it a
+    # lost update or two)
+    nsegs = 5 if mesh else 4
+    assert abs((plan.pv_segments.read() - n0)
+               - 4 * (n_ops + 5) * nsegs) <= 3 * nsegs
 
 
 def test_layer_account_on_one_chip_alltoall_above_threshold():
@@ -398,8 +391,8 @@ def test_layer_account_on_one_chip_alltoall_above_threshold():
     alltoall cell, test-sized): the stacked path at every size, so one
     rendezvous per operation, nothing packed or unpacked, the
     pipeline's counters at rest, and closure within 3%."""
-    from ompi_tpu.coll import pipeline
-    moved0 = (pipeline.pv_ops.read(), pipeline.pv_segments.read())
+    from ompi_tpu.coll import pipeline, plan
+    moved0 = (pipeline.pv_ops.read(), plan.pv_segments.read())
     knobs = {"coll_pipeline_enable": True, "coll_pipeline_min_bytes": 2048,
              "coll_seg_size": 4096}
     res = _closure_world(
@@ -415,7 +408,7 @@ def test_layer_account_on_one_chip_alltoall_above_threshold():
         assert d["assemble"] == 0
     assert 0 < sum(d["launch"] for _w, d in res) <= max(
         d["rdv_serve"] for _w, d in res)
-    assert (pipeline.pv_ops.read(), pipeline.pv_segments.read()) == moved0
+    assert (pipeline.pv_ops.read(), plan.pv_segments.read()) == moved0
 
 
 def test_host_collectives_stay_out_of_the_account():
@@ -457,7 +450,7 @@ def test_rendezvous_publishes_before_the_result_is_ready():
             log.append("asked")
             return False
 
-    def traced(shards, ph, direct):
+    def traced(shards, ph):
         log.append("computed")
         return [Pending(), Pending()]
 
@@ -494,10 +487,10 @@ def test_rendezvous_publishes_before_the_result_is_ready():
 def test_no_fence_reachable_from_the_blocking_path():
     """Structural: neither Rendezvous.begin / finish nor the helpers
     they call on the publisher name block_until_ready, and no thread
-    but the dispatcher exists to wait on a result."""
+    exists to wait on a result."""
     import inspect
     for fn in (device.Rendezvous.begin, device.Rendezvous.finish,
-               device._phase_fn, device._pub_span,
+               device._phase_fn,
                device._mesh_exec, device._stacked_exec):
         src = inspect.getsource(fn)
         assert "block_until_ready" not in src, fn.__name__
@@ -509,11 +502,11 @@ def test_no_fence_reachable_from_the_blocking_path():
 
 def test_new_sites_read_no_clock_when_tracing_is_off(monkeypatch):
     """trace_enable off: the rendezvous, the publisher's steps, the
-    plan resolution and the pipeline's pack and unpack stages take no
+    plan resolution and the plans' pack and unpack stages take no
     timestamp (coll/device's clock explodes; no Tracer exists to read
-    its own).  The pipeline's stages run under the allreduce with the
-    plan off and under the mesh alltoall; the one-device alltoall is
-    the stacked path in every variant."""
+    its own).  The ragged allreduce packs and unpacks on the mesh and
+    on one chip; the mesh alltoall is planned, the one-device alltoall
+    the stacked path."""
     assert not trace.enable_var.value
 
     def boom():
@@ -526,18 +519,16 @@ def test_new_sites_read_no_clock_when_tracing_is_off(monkeypatch):
         x = jax.device_put(jnp.arange(4099, dtype=jnp.float32), comm.device)
         s = jax.device_put(jnp.ones(8, jnp.float32), comm.device)
         a = comm.allreduce_arr(s, mpi_op.SUM)          # fused
-        b = comm.allreduce_arr(x, mpi_op.SUM)          # segmented
+        b = comm.allreduce_arr(x, mpi_op.SUM)          # planned, ragged
         c = comm.alltoall_arr(jax.device_put(
             jnp.arange(4096, dtype=jnp.float32), comm.device))
         comm.Barrier()
         return float(a[0]), float(b[1]), c.shape
 
     for devs in ({"devices": True}, {"device_map": _one_dev}):
-        for plan in (False, True):
-            res = _world(4, fn, dict(PIPE_ON, coll_plan_enable=plan),
-                         **devs)
-            assert {r[0] for r in res} == {4.0}
-            assert {r[1] for r in res} == {4.0}
+        res = _world(4, fn, PIPE_ON, **devs)
+        assert {r[0] for r in res} == {4.0}
+        assert {r[1] for r in res} == {4.0}
     assert not trace.live_tracers() or all(
         tr.rank >= 0 for tr in trace.live_tracers())
 
@@ -570,48 +561,6 @@ def test_layer_pvars_sum_all_rank_threads():
         assert pv["trace_layer_launch_ns"] > 0
         assert pv["trace_layer_rendezvous_count"] == 4 * 12
         assert mine["rendezvous"] == 12   # one thread's is a quarter
-
-
-def test_spans_handed_over_by_other_threads_reach_the_ring_whole():
-    """The dispatcher thread hands spans to the rank's own thread
-    (Tracer._done); the ring keeps one writer.  Stress:
-    more producers than cores' worth of turns, a shortened switch
-    interval, the owner filing while they append; every span arrives
-    once and untorn (its columns belong together)."""
-    import sys
-    tr = trace.Tracer(0, 1 << 15)
-    n_prod, n_each = 6, 1500
-    old = sys.getswitchinterval()
-    sys.setswitchinterval(1e-5)
-    try:
-        def produce(k):
-            for i in range(n_each):
-                v = k * n_each + i
-                tr._done.append((v, v + 7, trace.NAME_PH_LAUNCH, k, v, 3 * v))
-
-        ts = [threading.Thread(target=produce, args=(k,))
-              for k in range(n_prod)]
-        for t in ts:
-            t.start()
-        deadline = time.time() + 60
-        while any(t.is_alive() for t in ts) and time.time() < deadline:
-            tr.file_done()          # the owner files while they append
-            t0 = tr.start()         # ...and records spans of its own
-            tr.end(t0, trace.NAME_MEET, trace.CAT_COLL_DISPATCH, 1, 2, 3)
-        for t in ts:
-            t.join(10)
-            assert not t.is_alive()
-    finally:
-        sys.setswitchinterval(old)
-    ex = [e for e in tr.snapshot() if e["name"] == "ph_launch"]
-    assert len(ex) == n_prod * n_each
-    assert len({e["args"]["seq"] for e in ex}) == n_prod * n_each
-    for e in ex:
-        v = e["args"]["seq"]
-        assert e["args"]["nbytes"] == 3 * v
-        assert e["args"]["cid"] == v // n_each
-        assert round(e["dur"] * 1e9) == 7
-    assert tr.dropped == 0
 
 
 # -- audit wiring -----------------------------------------------------------
