@@ -86,6 +86,14 @@ _rv_timeout_var = registry.register(
     "coll", "device", "rendezvous_timeout", 300.0, float,
     help="Seconds a device-collective rendezvous may stall before "
          "raising (dead/diverged peer diagnosis)")
+_pv_doorbells = registry.register_pvar(
+    "coll", "device", "rdv_doorbells",
+    help="Progress doorbells rung by rendezvous publishers: one for "
+         "each waiter that had left the condvar for its idle selector")
+_pv_parks = registry.register_pvar(
+    "coll", "device", "rdv_parks",
+    help="Waits in which a rendezvous waiter left the condvar (its "
+         "2 ms there ran out) to sweep its progress engine and park")
 _cache_max_var = registry.register(
     "coll", "device", "cache_max", 256, int,
     help="Bound on the compiled-collective LRU cache (distinct "
@@ -341,7 +349,9 @@ class Rendezvous:
         self.results: Dict[int, List[Any]] = {}
         self.errors: Dict[int, BaseException] = {}
         self.readers: Dict[int, int] = {}
-        self._progs: Dict[int, Any] = {}  # rank -> Progress (wake targets)
+        # the Progress engines of waiters that may be parked in their
+        # idle selector, where notify_all does not reach (_wait_for)
+        self._away: set = set()
         # per-generation stamps of the phase profiler (perf_counter_ns;
         # set only by a traced publisher, dropped with the results):
         # when the meeting became full, when its results were published
@@ -355,15 +365,25 @@ class Rendezvous:
         ``coll_device_rendezvous_timeout`` of no progress — a stuck
         peer must become a diagnosable error, not a silent hang.
 
-        A waiter keeps its rank's ``progress`` engine turning while
-        blocked (the opal_progress-in-every-blocking-call discipline,
-        ref: opal/runtime/opal_progress.c:186): passive-target RMA —
-        osc lock grants, fetch_and_op application, the sharedfp file
-        pointer — targets THIS rank while it sits in a collective, and
-        a rank parked on a bare condvar would starve those handlers
-        forever.  Waiters park on the progress idle selector, which
-        both frag arrival (inproc send → wakeup) and rendezvous
-        completion (_wake_peers) ring, so parking costs no latency."""
+        A waiter sits on the condvar first, where the publisher's
+        ``notify_all`` alone wakes it: in the common meeting (all peers
+        arrive within a couple of ms) it runs no progress sweep, which
+        costs 10-50x a condvar wake.  Once a 2 ms wait there has run
+        out it leaves the condvar for good and keeps its rank's
+        ``progress`` engine turning (the opal_progress-in-every-
+        blocking-call discipline, ref: opal/runtime/opal_progress.c:186:
+        passive-target RMA — osc lock grants, fetch_and_op application,
+        the sharedfp file pointer — targets THIS rank while it sits in
+        a collective): sweep outside the lock, then park in the
+        progress idle selector, which frag arrival (inproc send →
+        wakeup) rings.
+
+        ``notify_all`` does not reach the selector, so a waiter that
+        may park there says so in ``_away`` under the lock before it
+        drops it; the publisher rings exactly those, after the lock is
+        released (``begin``).  A wait that timed out re-takes the lock,
+        perhaps behind the publisher, who then saw no flag: the waiter
+        looks at cond() once more before it leaves."""
         import time
 
         poll = _rv_poll_var.value
@@ -385,32 +405,35 @@ class Rendezvous:
                     tick(t0)
             return
         park = min(poll, 0.05)
-        first = True
+        on_cv = True
+        left = 0
         while not cond():
-            if first:
-                # fast path: park straight on the condvar — in the
-                # common meeting (all peers arrive within a couple
-                # ms) the last arriver's notify wakes us with ZERO
-                # progress sweeps.  A sweep costs 10-50x a condvar
-                # wake and used to run once per waiter per op,
-                # dominating the small-collective floor; background
-                # service (passive-target RMA at this rank) keeps
-                # its <=2 ms latency via the timeout below.
-                first = False
-                if self.cv.wait(timeout=0.002):
+            if on_cv:
+                if self.cv.wait(timeout=0.002) or cond():
+                    # notified; or the look-once-more
                     continue
+                on_cv = False
+                left = 1
+            selects = progress.has_idle_fds
+            if selects:
+                self._away.add(progress)
             # progress outside the cv: handlers may send replies
             # (osc acks) and must never run under the meeting lock
             self.cv.release()
             try:
+                if left:
+                    _pv_parks.add(1)
+                    left = 0
                 events = progress.progress()
-                if events == 0 and progress.has_idle_fds:
+                if events == 0 and selects:
                     # park in the idle selector: woken by frag
-                    # arrival AND by rendezvous completion
+                    # arrival AND by the publisher's ring
                     progress.idle_wait(park)
             finally:
                 self.cv.acquire()
-            if events == 0 and not progress.has_idle_fds:
+                if selects:
+                    self._away.discard(progress)
+            if events == 0 and not selects:
                 # no kernel-wakeable fds: park on the condvar (a
                 # GIL-holding spin here is measured strictly worse
                 # on shared cores) with a short timeout so the pml
@@ -428,9 +451,8 @@ class Rendezvous:
         token to collect with ``finish``.  Slots recycle as soon as
         the meeting is full, so a fast rank may deposit for generation
         g+1 while stragglers still read the results of g."""
-        if progress is not None:
-            self._progs[rank] = progress
         ta = td = 0
+        ring = ()
         if ph is not None:
             # layer account (trace.LAYERS; inline: this runs on every
             # operation of every rank): the interval before the
@@ -475,9 +497,15 @@ class Rendezvous:
                     # hand-off, every member's rdv_wake
                     self.t_rel[gen] = _now()
                 self.cv.notify_all()
-                for r, prog in self._progs.items():
-                    if r != rank:
-                        prog.wakeup()
+                if self._away:
+                    ring = list(self._away)
+        if ring:
+            # the doorbell write is a system call that gives the GIL
+            # away: made under the lock it woke waiters only to block
+            # on that lock
+            for prog in ring:
+                prog.wakeup()
+            _pv_doorbells.add(len(ring))
         if td:
             if c:
                 # deposited at td: the wait for the slot, one more
