@@ -160,7 +160,9 @@ def main() -> None:
     nranks = ONE_CHIP_RANKS if dev["count"] == 1 else dev["count"]
     cmd = [sys.executable, "-m", "ompi_tpu.tools.mpirun",
            "-np", str(nranks), "--ranks-per-proc", "all",
-           "--timeout", str(int(opts.timeout))]
+           "--timeout", str(int(opts.timeout)),
+           # config5 is MPI_DOUBLE on the device: a job-level statement
+           "--mca", "mpi_device_x64", "1"]
     if opts.tiny:
         # the same code paths at 1/256 of the bytes: the large-message
         # tier's crossover and segment shrink with the sizes

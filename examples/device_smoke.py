@@ -63,6 +63,7 @@ COUNTERS = {
     "plan_builds": "coll_plan_builds",
     "plan_hits": "coll_plan_hits",
     "fused": "coll_device_fused_collectives",
+    "typed": "coll_typed_device_ops",
 }
 CALLS = 3  # after the compiling one
 
@@ -277,53 +278,37 @@ class Smoke:
 
     def config5(self):
         """BASELINE config 5: Reduce_scatter_block MPI_MAX on
-        MPI_DOUBLE sourced through a derived vector datatype packed on
-        the device.  float64 needs jax's process-wide x64 switch, so
-        rank 0 flips it while every rank waits; if the backend hands
-        back anything but float64 the leg runs float32 and says so."""
+        MPI_DOUBLE through a derived vector datatype.  The datatype is
+        the collective's own argument (the library packs on the device,
+        inside the collective's program) and MPI_DOUBLE on the device is
+        the job's ``--mca mpi_device_x64 1``, which chip_smoke.py's
+        launch line carries.  Where the device's float64 is not IEEE
+        binary64 (a TPU v5e) the doubles travel as the library says
+        they do there, as uint64 bit patterns (runtime/x64)."""
         from ompi_tpu.datatype import engine as dtmod
-        from ompi_tpu.datatype.device import device_pack
+        from ompi_tpu.runtime import x64
 
-        jax, comm, p, oid = self.jax, self.comm, self.size, self.op_id + 1
-        comm.Barrier()
-        if self.rank == 0:
-            jax.config.update("jax_enable_x64", True)
-        comm.Barrier()
-        probe = self.put(np.zeros(2, np.float64)) + 1
-        got64 = self.gather([int(probe.dtype == np.float64)])
-        flag = np.array([int(got64.all()) if self.rank == 0 else 0],
-                        np.int64)
-        comm.Bcast(flag, root=0)
-        dtype = np.float64 if flag[0] else np.float32
-        if not flag[0]:
-            comm.Barrier()
-            if self.rank == 0:
-                jax.config.update("jax_enable_x64", False)
-            comm.Barrier()
-            self.say("config5: float64 unavailable on this backend "
-                     f"(x64 probe returned {probe.dtype}); running the "
-                     "float32 SUBSTITUTE")
-        isz = np.dtype(dtype).itemsize
-        m = self.sizes["config5"] // isz // p
+        comm, p, oid = self.comm, self.size, self.op_id + 1
+        if not self.jax.config.jax_enable_x64:
+            raise SmokeFailure(
+                "config5 needs MPI_DOUBLE on the device: launch with "
+                "--mca mpi_device_x64 1")
+        m = self.sizes["config5"] // 8 // p
         n = m * p
         # n blocks of 1 element, stride 2: the packed stream is the
         # even-indexed elements of a 2n-element buffer
-        vec = dtmod.vector(n, 1, 2, dtmod.from_numpy_dtype(
-            np.dtype(dtype))).commit()
-        pack = jax.jit(lambda a: device_pack(vec, 1, a))
+        vec = dtmod.vector(n, 1, 2, dtmod.DOUBLE).commit()
+        carry = (lambda a: a) if x64.native() else x64.bits
         self.collective(
             "config5_reduce_scatter_block_max_vector",
-            "reduce_scatter_block_arr", n * isz, dtype,
-            lambda r: gen(self.seed, oid, r, 2 * n, dtype),
-            lambda x: comm.reduce_scatter_arr(pack(x), mpi_op.MAX),
-            lambda r: np.max(
-                [gen(self.seed, oid, s, 2 * n, dtype)[::2]
-                 [r * m:(r + 1) * m] for s in range(p)], axis=0))
-        comm.Barrier()
-        if self.rank == 0:
-            jax.config.update("jax_enable_x64", False)
-        comm.Barrier()
-        return np.dtype(dtype).name
+            "reduce_scatter_block_arr", n * 8, np.float64,
+            lambda r: carry(gen(self.seed, oid, r, 2 * n, np.float64)),
+            lambda x: comm.reduce_scatter_arr(x, mpi_op.MAX, vec, 1),
+            lambda r: carry(np.max(
+                [gen(self.seed, oid, s, 2 * n, np.float64)[::2]
+                 [r * m:(r + 1) * m] for s in range(p)], axis=0)),
+            counter="typed")
+        return "float64" if x64.native() else "float64 as uint64 bits"
 
     def allgather(self):
         n, oid = self.sizes["allgather"] // 4, self.op_id + 1

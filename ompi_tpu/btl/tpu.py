@@ -29,6 +29,8 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
+from ompi_tpu.runtime import x64 as _x64
+
 
 class DeviceArrayPayload:
     """Opaque pml payload carrying a device array by reference.
@@ -332,8 +334,7 @@ def _pull_transfer(comm, src: int, hdr: _XferHdr):
         req.wait()
         arr = buf.view(dtype)
         if dev is not None:
-            import jax
-            arr = jax.device_put(arr, dev)
+            arr = _x64.put(arr, dev, "recv_arr")
         parts.append(arr)
     if len(parts) == 1:
         out = parts[0]
@@ -374,8 +375,7 @@ def send_arr(comm, x, dst: int, tag: int = 0) -> None:
     local, pdev = _peer_local_device(comm, dst)
     if local:
         if pdev is not None:
-            import jax
-            x = jax.device_put(x, pdev)
+            x = _x64.put(x, pdev, "send_arr")
         elif isinstance(x, np.ndarray):
             # co-resident by-reference delivery: copy so the user may
             # reuse the send buffer immediately (jax arrays are
@@ -428,10 +428,8 @@ def recv_arr(comm, src: int, tag: int = 0):
             f"{src}); byte messages use Recv")
     arr = payload.arr
     dev = comm.state.device
-    if dev is not None:
-        import jax
-        if getattr(arr, "device", None) != dev:
-            arr = jax.device_put(arr, dev)
+    if dev is not None and getattr(arr, "device", None) != dev:
+        arr = _x64.put(arr, dev, "recv_arr")
     return arr
 
 

@@ -45,8 +45,10 @@ from ompi_tpu.obs import integrity as _ig
 from ompi_tpu.coll.framework import CollComponent, CollModule, coll_framework
 from ompi_tpu.pml.monitoring import count_offload
 from ompi_tpu.coll.tuned import TunedModule
+from ompi_tpu.datatype import device as _dtdev
 from ompi_tpu.mca.params import registry
 from ompi_tpu.op.op import MAX, MIN, PROD, SUM, Op
+from ompi_tpu.runtime import x64 as _x64
 
 # trace ids as module constants: meet() runs once per device
 # collective and must not pay module-attribute lookups for them
@@ -94,6 +96,16 @@ _pv_parks = registry.register_pvar(
     "coll", "device", "rdv_parks",
     help="Waits in which a rendezvous waiter left the condvar (its "
          "2 ms there ran out) to sweep its progress engine and park")
+_pv_typed_dev = registry.register_pvar(
+    "coll", "typed", "device_ops",
+    help="Typed *_arr collectives (a datatype argument) served on the "
+         "device with the pack inside the collective's own program; "
+         "once a rank-call")
+_pv_typed_host = registry.register_pvar(
+    "coll", "typed", "host_packs",
+    help="Typed *_arr collectives whose datatype the host convertor "
+         "packed (not device-packable, or the call was not eligible "
+         "for a device provider); once a rank-call")
 _cache_max_var = registry.register(
     "coll", "device", "cache_max", 256, int,
     help="Bound on the compiled-collective LRU cache (distinct "
@@ -892,18 +904,22 @@ def _charge_hbm(nbytes: int) -> None:
         hook(nbytes)
 
 
-def _mesh_collective(kind: str, mesh, shape, dtype, extra=None) -> Callable:
+def _mesh_collective(kind: str, mesh, shape, dtype, extra=None,
+                     typed=None) -> Callable:
     # keyed by device ids, NOT mesh identity: every rank builds its own
     # (equal) Mesh object, and whichever thread is last-arriver must hit
     # the same compiled executable (a miss costs a full XLA compile)
     dev_key = tuple(d.id for d in mesh.devices.reshape(-1))
     key = (kind, dev_key, tuple(shape), np.dtype(dtype).str, extra)
+    if typed is not None:
+        key += (typed,)
     return compile_cache.get(
-        key, lambda: _build_mesh_collective(kind, mesh, shape, dtype, extra))
+        key, lambda: _build_mesh_collective(kind, mesh, shape, dtype, extra,
+                                            typed))
 
 
 def _build_mesh_collective(kind: str, mesh, shape, dtype,
-                           extra=None) -> Callable:
+                           extra=None, typed=None) -> Callable:
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -969,6 +985,16 @@ def _build_mesh_collective(kind: str, mesh, shape, dtype,
     else:
         raise KeyError(kind)
 
+    if typed is not None:
+        # a typed call: every rank's shard is the buffer its datatype
+        # addresses, packed here, inside the one program
+        untyped = body
+
+        def body(x):
+            return typed.unkey(untyped(typed.pack(x)))
+
+        body.__name__ = body.__qualname__ = "ompi_typed_" + kind
+
     # check_vma off: collective bodies are intentionally rank-divergent
     return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=False))
@@ -991,7 +1017,8 @@ def _assemble(mesh, shards: List, sharding=None):
             placed.append(s)
         else:
             _charge_hbm(int(getattr(s, "nbytes", 0)))
-            placed.append(jax.device_put(s, devs[i]))
+            placed.append(_x64.put(s, devs[i],
+                                   "coll/tpu device collective"))
     n = placed[0].shape[0]
     global_shape = (n * len(placed),) + tuple(placed[0].shape[1:])
     if sharding is None:
@@ -1025,6 +1052,53 @@ def _pipeline():
         from ompi_tpu.coll import pipeline as _p
         _pipeline_mod = _p
     return _pipeline_mod
+
+
+def typed_arr(comm, entry, x, op: Op, datatype, count):
+    """``comm.<op>_arr(x, op, datatype, count)``: resolve the count,
+    refuse a buffer whose 8-byte elements this device would not hold
+    (jax narrows them, or its float64 is not binary64: runtime/x64),
+    hand the shim the datatype for the call's ``coll`` span (its name,
+    the count and the packed bytes) and call the winning provider's
+    entry with the two arguments.  Untyped calls never come here."""
+    if not hasattr(x, "dtype"):
+        x = np.asarray(x)
+    dt = x.dtype
+    if dt.itemsize < 8 and datatype.runs:
+        # a buffer narrower than the datatype's base is what jax made
+        # of a double with x64 off
+        dt = datatype.runs[0].dtype
+    if dt.itemsize >= 8 and comm.state.device is not None:
+        _x64.check(dt, "typed *_arr collective")
+    count = _dtdev.typed_count(datatype, count, x)
+    tr = comm.state.tracer
+    if tr is None:
+        return entry(comm, x, op, datatype, count)
+    tr.coll_args = {"datatype": _dtdev.label(datatype), "count": count,
+                    "packed_bytes": count * datatype.size}
+    try:
+        return entry(comm, x, op, datatype, count)
+    finally:
+        tr.coll_args = None     # a span sampled out took nothing
+
+
+def _typed_on_device(mod, comm, kind: str, x, op: Op, datatype, count):
+    """The ``Typed`` a device provider serves a typed call with, or
+    None when the call goes to the host fallback.  One rule for
+    coll/tpu and coll/hbm: the provider's own eligibility, a reduction
+    with an on-device lowering, datatype/device.typed_operand, for a
+    reduce_scatter a packed stream that splits evenly, and for
+    MPI_DOUBLE carried as bit patterns a reduction that compares."""
+    if not mod._eligible(comm, x) or (
+            op.name not in _XLA_REDUCERS and op.name not in _GATHER_FOLD):
+        return None
+    t = _dtdev.typed_operand(datatype, count, x)
+    if t is None or (kind == "reduce_scatter" and t.elems % comm.size) \
+            or (t.bits and op.name not in _dtdev.BITS_OPS):
+        # MPI_DOUBLE as bit patterns: a reduction that is arithmetic
+        # is the host's, in binary64
+        return None
+    return t
 
 
 def _measured_host_wins(comm, kind: str, nbytes: int) -> bool:
@@ -1094,13 +1168,49 @@ class TpuCollModule(CollModule):
         comm.__dict__["_device_abort_check"] = check
         return check
 
+    @staticmethod
+    def _deposit(comm, x):
+        """Ensure the deposited value lives on the rank's device: a
+        host buffer is moved by its own rank, which is where an 8-byte
+        element that would not arrive whole is refused (runtime/x64)."""
+        if _is_jax_array(x):
+            return x
+        arr = np.asarray(x)
+        _charge_hbm(arr.nbytes)
+        return _x64.put(arr, comm.state.device, "device collective")
+
     def _run(self, comm, value, fn, ck=None):
-        out = meet(comm, value, fn, self._abort_check(comm), ck)
+        out = meet(comm, self._deposit(comm, value), fn,
+                   self._abort_check(comm), ck)
         self.pvar_offload.add(1)
         return out
 
     # -- device-array collectives (the *_arr vtable surface) -------------
-    def allreduce_arr(self, comm, x, op: Op):
+    def _typed(self, comm, kind: str, x, op: Op, datatype, count):
+        """A typed allreduce / reduce_scatter: each rank's shard is
+        the buffer its datatype addresses and the pack is inside the
+        mesh program (_build_mesh_collective), one rendezvous and one
+        program a call.  Never planned: the large-message tier's plans
+        are keyed by a contiguous payload."""
+        t = _typed_on_device(self, comm, kind, x, op, datatype, count)
+        if t is None:
+            return self.fallback.typed(comm, kind, x, op, datatype, count)
+        mesh = comm.mesh()
+        opname = op.name
+
+        def fn(shards):
+            g = _assemble(mesh, shards)
+            jfn = _mesh_collective(kind, mesh, g.shape, g.dtype, opname, t)
+            return _scatter_out(jfn(g), mesh, comm.size)
+
+        ck = _ig.spec_typed(_CK_KINDS[kind], opname, t) if _ig.on else None
+        out = self._run(comm, x.reshape(-1), fn, ck)
+        _pv_typed_dev.add(1)
+        return out
+
+    def allreduce_arr(self, comm, x, op: Op, datatype=None, count=None):
+        if datatype is not None:
+            return self._typed(comm, "allreduce", x, op, datatype, count)
         if not self._eligible(comm, x) or (
                 op.name not in _XLA_REDUCERS
                 and op.name not in _GATHER_FOLD) \
@@ -1125,7 +1235,11 @@ class TpuCollModule(CollModule):
         out = self._run(comm, x, fn, ck)
         return out.reshape(()) if was_scalar else out
 
-    def reduce_scatter_block_arr(self, comm, x, op: Op):
+    def reduce_scatter_block_arr(self, comm, x, op: Op, datatype=None,
+                                 count=None):
+        if datatype is not None:
+            return self._typed(comm, "reduce_scatter", x, op, datatype,
+                               count)
         if not self._eligible(comm, x) or (
                 op.name not in _XLA_REDUCERS
                 and op.name not in _GATHER_FOLD) \
@@ -1268,14 +1382,7 @@ class HbmCollModule(CollModule):
     _abort_check = TpuCollModule._abort_check
     _norm = staticmethod(TpuCollModule._norm)
 
-    def _deposit(self, comm, x):
-        """Ensure the deposited value lives on the shared device."""
-        if _is_jax_array(x):
-            return x
-        import jax
-        arr = np.asarray(x)
-        _charge_hbm(arr.nbytes)
-        return jax.device_put(arr, comm.state.device)
+    _deposit = staticmethod(TpuCollModule._deposit)
 
     def _stacked(self, kind: str, opname: str, nshards: int, shape, dtype,
                  extra=None) -> Callable:
@@ -1286,10 +1393,13 @@ class HbmCollModule(CollModule):
         key = ("hbm", kind, opname, nshards, tuple(shape),
                np.dtype(dtype).str, extra)
         return compile_cache.get(
-            key, lambda: self._build_stacked(kind, opname))
+            key, lambda: self._build_stacked(kind, opname, extra))
 
     @staticmethod
-    def _build_stacked(kind: str, opname: str) -> Callable:
+    def _build_stacked(kind: str, opname: str, typed=None) -> Callable:
+        """``typed`` (datatype/device.Typed, the ``extra`` of a typed
+        call) packs each rank's deposit inside the kernel, in front of
+        the same arithmetic."""
         import jax
         import jax.numpy as jnp
 
@@ -1347,6 +1457,15 @@ class HbmCollModule(CollModule):
         else:
             raise KeyError(kind)
 
+        if typed is not None:
+            untyped = body
+
+            def body(*s):
+                return typed.unkey(untyped(*[typed.pack(a) for a in s]))
+
+            # a stable program name for the device trace
+            body.__name__ = body.__qualname__ = "ompi_typed_" + kind
+
         return (jax.jit(body), out)
 
     def _run(self, comm, kind, opname, x, extra=None):
@@ -1372,16 +1491,35 @@ class HbmCollModule(CollModule):
             # the plan: the untraced body above is what runs otherwise
             fn.traced = functools.partial(_stacked_exec, jbody, out, size)
             plans[pkey] = fn
-        ck = _ig.spec(_CK_KINDS.get(kind, kind), opname, x) \
-            if _ig.on else None
+        ck = None
+        if _ig.on:
+            # a typed call's deposit is not the operand the kernel
+            # reduces: its spec digests the packed stream (extra.operand)
+            ck = _ig.spec(_CK_KINDS.get(kind, kind), opname, x) \
+                if extra is None else _ig.spec_typed(
+                    _CK_KINDS.get(kind, kind), opname, extra)
         return self._meet(comm, x, fn, ck)
+
+    def _typed(self, comm, kind: str, x, op: Op, datatype, count):
+        """A typed allreduce / reduce_scatter: the stacked kernel with
+        the pack inside it (``extra`` carries the Typed into the plan
+        key, the cache key and the builder), one rendezvous and one
+        program a call."""
+        t = _typed_on_device(self, comm, kind, x, op, datatype, count)
+        if t is None:
+            return self.fallback.typed(comm, kind, x, op, datatype, count)
+        out = self._run(comm, kind, op.name, x.reshape(-1), t)
+        _pv_typed_dev.add(1)
+        return out
 
     def _meet(self, comm, x, fn, ck=None):
         out = meet(comm, x, fn, self._abort_check(comm), ck)
         self.pvar_offload.add(1)
         return out
 
-    def allreduce_arr(self, comm, x, op: Op):
+    def allreduce_arr(self, comm, x, op: Op, datatype=None, count=None):
+        if datatype is not None:
+            return self._typed(comm, "allreduce", x, op, datatype, count)
         if not self._eligible(comm, x) or (
                 op.name not in _XLA_REDUCERS and op.name not in _GATHER_FOLD):
             return self.fallback.allreduce_arr(comm, x, op)
@@ -1394,7 +1532,11 @@ class HbmCollModule(CollModule):
         out = self._run(comm, "allreduce", op.name, x)
         return out.reshape(()) if was_scalar else out
 
-    def reduce_scatter_block_arr(self, comm, x, op: Op):
+    def reduce_scatter_block_arr(self, comm, x, op: Op, datatype=None,
+                                 count=None):
+        if datatype is not None:
+            return self._typed(comm, "reduce_scatter", x, op, datatype,
+                               count)
         # every stacked-foldable op, not just SUM: BASELINE config 5
         # is MPI_MAX — a SUM-only guard silently host-staged it at
         # ~100 ms/op through the d2h fallback (r5 finding)
@@ -1486,20 +1628,59 @@ class HostArrModule(CollModule):
         return np.asarray(x)
 
     def _back(self, comm, arr: np.ndarray):
+        """The result's way back to the rank's device; an 8-byte
+        element that would not arrive whole raises (runtime/x64)."""
         dev = comm.state.device
         if dev is not None:
-            import jax
-            return jax.device_put(arr, dev)
+            return _x64.put(arr, dev, "host-staged *_arr collective")
         return arr
 
     def _dtype_of(self, arr):
         return self._dt.from_numpy_dtype(arr.dtype)
 
-    def allreduce_arr(self, comm, x, op: Op):
+    def typed(self, comm, kind: str, x, op: Op, datatype, count):
+        """A typed allreduce / reduce_scatter no device provider
+        serves: the host convertor packs ``count`` elements of
+        ``datatype`` out of the staged buffer, and the untyped
+        host-staged call reduces the packed stream in the type's one
+        primitive element type."""
+        from ompi_tpu import errhandler as _eh
+        from ompi_tpu.datatype import convertor
+        runs = datatype.runs_for_count(count)
+        if not runs or any(r.dtype != runs[0].dtype for r in runs):
+            raise _eh.MPIException(
+                _eh.ERR_TYPE, f"a typed {kind}_arr reduces one element "
+                f"type; {_dtdev.label(datatype)} mixes them "
+                "(MPI_ERR_TYPE)")
+        carrier = _dtype_of(x)
+        packed = np.frombuffer(
+            convertor.pack(datatype, count, np.ascontiguousarray(x)),
+            dtype=runs[0].dtype)
+        _pv_typed_host.add(1)
+        # MPI_DOUBLE that came as uint64 bit patterns is reduced here in
+        # binary64 and goes back as it came
+        return self._back(
+            comm, self._reduced(comm, kind, packed, op).view(carrier))
+
+    def _reduced(self, comm, kind: str, x, op: Op) -> np.ndarray:
+        """The host-staged allreduce / reduce_scatter of a flat stream,
+        still on the host."""
         a = self._np(x).reshape(-1)
-        r = np.empty_like(a)
-        self.p2p.allreduce(comm, a, r, a.size, self._dtype_of(a), op)
-        return self._back(comm, r.reshape(_shape_of(x)))
+        if kind == "allreduce":
+            r = np.empty_like(a)
+            self.p2p.allreduce(comm, a, r, a.size, self._dtype_of(a), op)
+        else:
+            n = a.size // comm.size
+            r = np.empty(n, dtype=a.dtype)
+            self.p2p.reduce_scatter_block(comm, a, r, n, self._dtype_of(a),
+                                          op)
+        return r
+
+    def allreduce_arr(self, comm, x, op: Op, datatype=None, count=None):
+        if datatype is not None:
+            return self.typed(comm, "allreduce", x, op, datatype, count)
+        return self._back(comm, self._reduced(
+            comm, "allreduce", x, op).reshape(_shape_of(x)))
 
     def bcast_arr(self, comm, x, root: int):
         a = self._np(x).reshape(-1).copy()
@@ -1532,13 +1713,15 @@ class HostArrModule(CollModule):
                           self._dtype_of(a))
         return self._back(comm, r.reshape(shp))
 
-    def reduce_scatter_block_arr(self, comm, x, op: Op):
+    def reduce_scatter_block_arr(self, comm, x, op: Op, datatype=None,
+                                 count=None):
+        if datatype is not None:
+            return self.typed(comm, "reduce_scatter", x, op, datatype,
+                              count)
         shp = _shape_of(x)
-        a = self._np(x).reshape(-1)
-        n = a.size // comm.size
-        r = np.empty(n, dtype=a.dtype)
-        self.p2p.reduce_scatter_block(comm, a, r, n, self._dtype_of(a), op)
-        out_shape = (shp[0] // comm.size,) + tuple(shp[1:]) if shp else (n,)
+        r = self._reduced(comm, "reduce_scatter", x, op)
+        out_shape = (shp[0] // comm.size,) + tuple(shp[1:]) if shp \
+            else r.shape
         return self._back(comm, r.reshape(out_shape))
 
     def ppermute_arr(self, comm, x, perm):

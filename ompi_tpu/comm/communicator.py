@@ -873,8 +873,19 @@ class Communicator:
     # new arrays (jax arrays are immutable); lowered to XLA collectives
     # on the comm's mesh when eligible, host-staged otherwise.
 
-    def allreduce_arr(self, x, op):
-        return self.coll.allreduce_arr(self, x, op)
+    # With ``datatype`` (committed; MPI's own argument of the
+    # collective) ``x`` is the flat element-typed buffer the datatype
+    # addresses, the operation is applied to the packed stream of
+    # ``count`` elements of the type (None: as many as the buffer
+    # holds) and the result is contiguous in the base type.  The
+    # provider packs inside its own program (datatype/device.py).
+
+    def allreduce_arr(self, x, op, datatype=None, count=None):
+        if datatype is None:
+            return self.coll.allreduce_arr(self, x, op)
+        from ompi_tpu.coll.device import typed_arr
+        return typed_arr(self, self.coll.allreduce_arr, x, op, datatype,
+                         count)
 
     def bcast_arr(self, x, root: int = 0):
         return self.coll.bcast_arr(self, x, root)
@@ -888,8 +899,12 @@ class Communicator:
     def alltoall_arr(self, x):
         return self.coll.alltoall_arr(self, x)
 
-    def reduce_scatter_arr(self, x, op):
-        return self.coll.reduce_scatter_block_arr(self, x, op)
+    def reduce_scatter_arr(self, x, op, datatype=None, count=None):
+        if datatype is None:
+            return self.coll.reduce_scatter_block_arr(self, x, op)
+        from ompi_tpu.coll.device import typed_arr
+        return typed_arr(self, self.coll.reduce_scatter_block_arr, x, op,
+                         datatype, count)
 
     def ppermute_arr(self, x, perm):
         """perm: [(src_rank, dst_rank), ...] — mesh-neighbor shift."""

@@ -15,30 +15,137 @@ buffer, with displacements/strides that are whole elements —
 exactly the shapes MPI vector/indexed/subarray types of one base
 type produce.  Mixed-type structs fall back to the host convertor
 (they would need byte-level gathers that defeat XLA vectorization).
+
+One exception to "same dtype as the buffer": MPI_DOUBLE may travel
+as its bit pattern, a uint64 buffer (runtime/x64.py: a TPU v5e holds
+no binary64, it does hold 64 bits).  MAX and MIN of such a buffer are
+integer compares on an order key, exact for every binary64 value.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import numpy as np
 
 from .engine import Datatype
 
-_idx_cache: dict = {}
-_dtype_cache: dict = {}
+_BITS = np.dtype(np.uint64)
+_SIGN = 1 << 63
+#: the reductions a bit-pattern carrier serves on the device
+BITS_OPS = frozenset(("MPI_MAX", "MPI_MIN"))
 
 
-def element_indices(datatype: Datatype, count: int) -> Optional[np.ndarray]:
-    """Element indices (into a flat element-typed buffer view) whose
-    gather equals the datatype's packed stream for ``count`` elements,
-    or None when the datatype is not device-packable.  Cached per
-    (datatype id, count) — index construction is host-side and O(n),
-    the device gather is the per-call cost."""
-    key = (datatype.id, count)
-    hit = _idx_cache.get(key)
-    if hit is not None:
-        return hit
+def label(datatype: Datatype) -> str:
+    """What a message or a span calls the datatype: its name, else its
+    combiner (VECTOR, INDEXED, ...)."""
+    return datatype.name or str(datatype.envelope[0])
+
+
+def order_key(b):
+    """binary64 bit patterns (uint64, traced) -> uint64 keys whose
+    unsigned order is IEEE's total order of the doubles: a negative
+    value has all its bits turned, another its sign bit set.  -0 sorts
+    below +0 and a NaN beyond the infinity of its sign."""
+    import jax.numpy as jnp
+    u = jnp.uint64
+    return b ^ ((u(0) - (b >> u(63))) | u(_SIGN))
+
+
+def from_order_key(k):
+    """The inverse of ``order_key``."""
+    import jax.numpy as jnp
+    u = jnp.uint64
+    return k ^ (((k >> u(63)) - u(1)) | u(_SIGN))
+
+
+class Typed:
+    """A committed datatype and a count, resolved once: the index
+    vector whose gather is the packed stream (``idx``), the one
+    primitive type of its runs (``dtype``), and what the device
+    providers of a typed ``*_arr`` collective need of them: ``pack``
+    (the gather, inside the provider's jit), ``sig`` (what keys their
+    programs: base type, carrier and a digest of ``idx``, so equal
+    layouts share one executable whatever datatype object described
+    them).  ``bits``: the buffer holds MPI_DOUBLE as uint64 bit
+    patterns; ``pack`` then hands the reduction order keys and
+    ``unkey`` turns its results back."""
+
+    __slots__ = ("datatype", "count", "dtype", "idx", "elems", "span",
+                 "bits", "sig", "_twin")
+
+    def __init__(self, datatype: Datatype, count: int, dtype: np.dtype,
+                 idx: np.ndarray) -> None:
+        import hashlib
+
+        self.datatype = datatype
+        self.count = count
+        self.dtype = dtype
+        self.idx = idx
+        self.elems = int(idx.size)          # packed stream, in elements
+        self.span = int(idx.max()) + 1 if idx.size else 0
+        self.bits = False
+        self.sig = ("typed", dtype.str, self.elems,
+                    hashlib.blake2b(np.ascontiguousarray(idx).tobytes(),
+                                    digest_size=16).hexdigest())
+        self._twin = None
+
+    # a provider's cache key holds the Typed itself: equal layouts are
+    # one key
+    def __hash__(self) -> int:
+        return hash(self.sig)
+
+    def __eq__(self, other) -> bool:
+        return isinstance(other, Typed) and self.sig == other.sig
+
+    def carried(self, dtype) -> Optional["Typed"]:
+        """The Typed for a buffer of ``dtype``: this one when it is the
+        base type, its bit-pattern twin for a uint64 buffer under
+        MPI_DOUBLE, None for any other (the host convertor's)."""
+        dtype = np.dtype(dtype)
+        if dtype == self.dtype:
+            return self
+        if dtype != _BITS or self.dtype != np.float64:
+            return None
+        if self._twin is None:
+            t = Typed.__new__(Typed)
+            for k in ("datatype", "count", "dtype", "idx", "elems", "span"):
+                setattr(t, k, getattr(self, k))
+            t.bits, t.sig, t._twin = True, self.sig + ("bits",), self
+            self._twin = t
+        return self._twin
+
+    def pack(self, arr):
+        import jax.numpy as jnp
+        out = jnp.take(arr.reshape(-1), jnp.asarray(self.idx), axis=0)
+        return order_key(out) if self.bits else out
+
+    def unkey(self, out):
+        """What the reduction of packed streams returned (an array or
+        a tuple of them), as the caller gets it."""
+        if not self.bits:
+            return out
+        if isinstance(out, tuple):
+            return tuple(from_order_key(o) for o in out)
+        return from_order_key(out)
+
+    # the host's view, for the integrity plane: the operand a deposit
+    # stands for and the values an output holds, in the base type
+    def operand(self, deposit) -> np.ndarray:
+        return np.asarray(deposit).reshape(-1)[self.idx].view(self.dtype)
+
+    def answer(self, out) -> np.ndarray:
+        return np.asarray(out).view(self.dtype)
+
+
+@functools.lru_cache(maxsize=64)
+def resolve(datatype: Datatype, count: int) -> Optional[Typed]:
+    """The ``Typed`` of ``count`` elements of ``datatype``, or None
+    when the datatype is not device-packable.  The one cache: index
+    construction is host-side and O(n) (16 MiB of indices for the 2 Mi
+    elements of a large vector, hence the bound), the device gather is
+    the per-call cost."""
     runs = datatype.runs_for_count(count)
     if not runs:
         return None
@@ -59,28 +166,31 @@ def element_indices(datatype: Datatype, count: int) -> Optional[np.ndarray]:
     idx = np.concatenate(chunks) if len(chunks) > 1 else chunks[0]
     if (idx < 0).any():
         return None  # negative displacement: host convertor owns it
-    _idx_cache[key] = idx
-    _dtype_cache[key] = runs[0].dtype
-    return idx
+    return Typed(datatype, count, runs[0].dtype, idx)
+
+
+def element_indices(datatype: Datatype, count: int) -> Optional[np.ndarray]:
+    """Element indices (into a flat element-typed buffer view) whose
+    gather equals the datatype's packed stream for ``count`` elements,
+    or None when the datatype is not device-packable."""
+    t = resolve(datatype, count)
+    return None if t is None else t.idx
 
 
 def device_pack(datatype: Datatype, count: int, arr):
     """Pack a device-resident array through the datatype: one XLA
     gather (jittable; fuses into downstream collectives).  ``arr`` is
     the flat element-typed buffer the datatype addresses."""
-    import jax.numpy as jnp
-
-    idx = element_indices(datatype, count)
-    if idx is None:
+    t = resolve(datatype, count)
+    if t is None:
         raise ValueError(
             f"datatype {datatype.name or datatype.id} is not "
             f"device-packable (mixed types or sub-element layout)")
-    base = _dtype_cache[(datatype.id, count)]
-    if base != np.dtype(arr.dtype):
+    if t.dtype != np.dtype(arr.dtype):
         raise ValueError(
             f"buffer dtype {arr.dtype} does not match datatype base "
-            f"{base}")
-    return jnp.take(arr.reshape(-1), jnp.asarray(idx), axis=0)
+            f"{t.dtype}")
+    return t.pack(arr)
 
 
 def device_unpack(datatype: Datatype, count: int, packed, out):
@@ -96,7 +206,47 @@ def device_unpack(datatype: Datatype, count: int, packed, out):
 
 
 def is_device_packable(datatype: Datatype, count: int) -> bool:
-    return element_indices(datatype, count) is not None
+    return resolve(datatype, count) is not None
+
+
+# ---------------------------------------------------------------------------
+# typed device collectives: comm.<op>_arr(x, op, datatype, count)
+# ---------------------------------------------------------------------------
+
+def typed_count(datatype: Datatype, count: Optional[int], x) -> int:
+    """``count`` of a typed call; None means as many elements of the
+    type as the buffer holds."""
+    if count is not None:
+        return int(count)
+    nbytes = int(x.size) * np.dtype(x.dtype).itemsize
+    return max(1, nbytes // max(1, datatype.extent))
+
+
+def typed_operand(datatype: Datatype, count: int, x) -> Optional[Typed]:
+    """The one eligibility rule of every provider: a ``Typed`` when the
+    packed stream of ``count`` elements of ``datatype`` can be gathered
+    from ``x`` on the device (committed, device-packable, base type
+    equal to the buffer's, or MPI_DOUBLE carried as uint64 bit
+    patterns), else None (the host convertor serves it).  Depends only
+    on the datatype, the count and the buffer's dtype and size, which
+    MPI requires to match across ranks, so every member reaches the
+    same verdict."""
+    if not datatype.committed:
+        from ompi_tpu import errhandler as _eh
+        raise _eh.MPIException(
+            _eh.ERR_TYPE, f"datatype {label(datatype)} is not "
+            "committed (MPI_ERR_TYPE)")
+    t = resolve(datatype, count)
+    if t is not None:
+        t = t.carried(x.dtype)
+    if t is None:
+        return None
+    size = int(x.size)
+    if size < t.span:
+        raise IndexError(
+            f"buffer of {size} elements is shorter than the {t.span} "
+            f"that {count} x {label(datatype)} address")
+    return t
 
 
 # ---------------------------------------------------------------------------

@@ -176,7 +176,7 @@ def spec(kind: str, opname: str, x: Any, root: int = 0):
     on rank-local state, so every rank derives the same spec and the
     comm-consistency invariant holds.
 
-    Spec tuple: ``(kind, foldcode, itemsize[, root])``.
+    Spec tuple: ``(kind, foldcode, itemsize[, root[, typed]])``.
     """
     if not on:
         return None
@@ -214,6 +214,18 @@ def spec_static(kind: str, opname: str, x: Any, root: int = 0):
     if kind == "bcast":
         return (kind, base, dt.itemsize, int(root))
     return None
+
+
+def spec_typed(kind: str, opname: str, typed):
+    """spec() for a typed collective (a datatype argument): what a
+    rank deposits is the buffer its datatype addresses, not the
+    operand, so the spec carries the call's ``datatype/device.Typed``
+    as a fifth element.  Digests then read the packed stream
+    (``typed.operand``) and the outputs in the base type
+    (``typed.answer``: MPI_DOUBLE may travel as uint64 bit patterns,
+    whose integer extremum is not the doubles')."""
+    ck = spec(kind, opname, np.empty(0, typed.dtype))
+    return None if ck is None else ck[:3] + (0, typed)
 
 
 # -- digests (the per-operand checksums) -------------------------------------
@@ -342,6 +354,8 @@ def _digest_for(ck, value):
         for ent in ck[1]:
             out.append(digest(arrays[ent[2]], ent[1]))
         return tuple(out)
+    if len(ck) > 4:
+        value = ck[4].operand(value)
     return digest(value, ck[1])
 
 
@@ -407,6 +421,8 @@ def _verify(ck, shards, outs) -> bool:
         return True
     code, isz = ck[1], ck[2]
     claims = [s.d for s in shards]
+    if len(ck) > 4:
+        outs = [ck[4].answer(o) for o in outs]
     if kind == "allreduce":
         outd = digest(outs[0], code)
         return _eq(code, _fold_claims(code, claims), outd, isz, _rel_tol)

@@ -55,8 +55,10 @@ host-side read-modify-write of the target's write-through mirror
 under the window's table lock — one lock, every op serialized, so
 atomicity holds across mixed dtypes and paths.  In kernel mode the
 wire dtypes jax can bitcast run the jitted bucket kernels and the
-rest (int64/float64/complex/bool/pair — x64 is off) take the same
-host fallback.  put/get are byte-level and never care.
+rest (int64/float64/complex/bool/pair) take the same host fallback,
+with ``mpi_device_x64`` on as with it off: the bucket kernels are
+built for the 32-bit wire dtypes only.  put/get are byte-level (the
+window is uint8 on the device), so a window never narrows a double.
 """
 
 from __future__ import annotations
@@ -108,8 +110,8 @@ _ALIGN = 16
 #: dominates and one shape serves every tiny op
 _BUCKET_MIN = 256
 
-#: dtypes whose accumulate/CAS kernels run on device (32-bit jax
-#: world: 8-byte and complex dtypes take the host fallback)
+#: dtypes whose accumulate/CAS kernels run on device (8-byte and
+#: complex dtypes take the host fallback, whatever mpi_device_x64 says)
 _JIT_ACC_DTYPES = frozenset(
     np.dtype(t).str for t in (np.uint8, np.int8, np.int16, np.uint16,
                               np.int32, np.uint32, np.float32))

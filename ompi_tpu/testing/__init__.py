@@ -63,7 +63,8 @@ def run_ranks(n: int, fn: Callable, devices: bool = False,
     if devices or device_map is not None:
         import jax
 
-        from ompi_tpu.runtime import jaxcache
+        from ompi_tpu.runtime import jaxcache, x64
+        x64.apply()
         jaxcache.enable()
         devs = jax.devices()
     respawn_cv = threading.Condition()

@@ -3379,7 +3379,8 @@ def serve(opts) -> int:
     if opts.devices != "none":
         import jax
 
-        from ompi_tpu.runtime import jaxcache
+        from ompi_tpu.runtime import jaxcache, x64
+        x64.apply()
         jaxcache.enable()
         devices = jax.devices()  # PJRT bring-up happens HERE, once
     server = DVMServer(opts.np, devices=devices,

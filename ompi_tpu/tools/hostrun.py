@@ -48,8 +48,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     if os.environ.get("TPUMPI_DEVICES", "auto") != "none":
         import jax
 
-        from ompi_tpu.runtime import jaxcache
+        from ompi_tpu.runtime import jaxcache, x64
 
+        x64.apply()
         jaxcache.enable()
         devices = jax.devices()
 

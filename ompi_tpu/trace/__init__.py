@@ -393,7 +393,7 @@ class Tracer:
         "_over", "_auto", "_max_period", "_p0", "_plo", "_phi",
         "phase", "sync_offsets_us",
         "_req_tags", "_req_ts", "_req_n",
-        "_lns", "_t_cur", "_cur_k", "_t_ret",
+        "_lns", "_t_cur", "_cur_k", "_t_ret", "coll_args",
         "__weakref__",
     )
 
@@ -416,6 +416,9 @@ class Tracer:
         self._cat = array("i", [0]) * cap
         self._ph = array("b", [0]) * cap
         self._argobj: List[Any] = [None] * cap
+        #: further args for the ``coll`` span of the collective this
+        #: rank is in (a typed call's datatype); coll_end stores them
+        self.coll_args: Optional[dict] = None
         self._nrec = 0          # events stored in the ring (kept)
         ncat = len(_cats)
         self._period = [1] * ncat    # current 1-in-N period per cat
@@ -750,6 +753,13 @@ class Tracer:
         self._argobj[i] = argobj
         self.cursor = cur + 1
         self._nrec += 1
+
+    def take_coll_args(self) -> None:
+        """Put ``coll_args`` on the ``coll`` span coll_end has just
+        stored (the last one).  Cold: typed collectives only."""
+        i = (self.cursor - 1) % self.capacity
+        self._argobj[i] = dict(self._decode_args(i), **self.coll_args)
+        self.coll_args = None
 
     def end_slow(self, t0: int, name: str, cat: str, **args) -> float:
         """String-keyed compat span close for COLD call sites (daemon
@@ -1306,6 +1316,8 @@ def coll_end(comm, name_id: int, token, _pcns=time.perf_counter_ns) -> None:
                 tr.end_at(c, now, NAME_PH_EXIT, CAT_PHASE, comm.cid, seq)
         elif token > 0:
             tr.end_at(token, now, name_id, CAT_COLL, comm.cid, seq)
+        if tr.coll_args is not None and token > 0:
+            tr.take_coll_args()
     if fire:
         _coll_end_slow(comm, name_id, seq)
 

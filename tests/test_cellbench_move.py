@@ -327,11 +327,18 @@ def test_planned_programs_are_named_and_move_data_only(alg, root):
 def test_manifest_is_valid_with_six_cells():
     assert validate.check(REPO) == []
     man = manifest.manifest(REPO)
-    assert [w["name"] for w in man["workloads"]][-2:] == [
-        "bcast-64MiB.tpu4", "alltoall-4MiB.tpu4"]
-    assert len(man["workloads"]) == 6 and len(man["configs"]) == 3
-    assert sum(w["chips"] == 4 for w in man["workloads"]) == 3
+    # what the name means, not a count that the next cell breaks: the
+    # two cells of PR 28 are defined, in their order, on their
+    # configuration, and at most half the cells ask for four chips
+    names = [w["name"] for w in man["workloads"]]
+    assert len(names) >= 6 and len(man["configs"]) >= 3
+    assert names.index("alltoall-4MiB.tpu4") \
+        == names.index("bcast-64MiB.tpu4") + 1 == 5
+    four = sum(w["chips"] == 4 for w in man["workloads"])
+    assert 3 <= four <= max(1, len(names) // 2)
     for name in ("bcast-64MiB.tpu4", "alltoall-4MiB.tpu4"):
+        entry = man["workloads"][names.index(name)]
+        assert (entry["config"], entry["chips"]) == ("osu-tpu4-move", 4)
         spec = manifest.cell(name, REPO)
         assert [m["name"] for m in spec["end_to_end"]] == ["setup_s",
                                                            "iter_us"]

@@ -223,9 +223,10 @@ def test_arr_host_fallback():
 
 
 def test_tpu_numpy_input_falls_back():
-    """numpy buffers through the _arr surface still work."""
+    """numpy buffers through the _arr surface still work (float32:
+    a float64 one is refused while mpi_device_x64 is off, below)."""
     def fn(comm):
-        x = np.full(4, comm.rank + 1.0)
+        x = np.full(4, comm.rank + 1.0, dtype=np.float32)
         r = comm.allreduce_arr(x, mpi_op.SUM)
         return float(np.asarray(r)[0])
 
@@ -516,3 +517,85 @@ def test_compile_cache_fusion_signature_keys():
     b1 = compile_cache.builds
     assert all(run_ranks(2, fn, devices=True))
     assert compile_cache.builds == b1  # warm replay: all cache hits
+
+
+# ---------------------------------------------------------------------------
+# mpi_device_x64 off (the default): an 8-byte element is refused, never
+# narrowed.  The tests with it ON live in tests/test_cellbench_typed.py.
+# ---------------------------------------------------------------------------
+
+_ON_ONE_DEVICE = {"hbm": (4, lambda r: jax.devices()[0]), "tpu": (4, None)}
+
+
+@pytest.mark.parametrize("layout", ["hbm", "tpu"])
+@pytest.mark.parametrize("call", ["allreduce", "reduce_scatter", "bcast",
+                                  "typed_host_buffer", "typed_f32_buffer"])
+def test_x64_off_refuses_an_8_byte_buffer(layout, call):
+    from ompi_tpu import errhandler
+    from ompi_tpu.datatype import engine as dt
+
+    assert not jax.config.jax_enable_x64
+    vec = dt.vector(8, 1, 2, dt.DOUBLE).commit()
+
+    def fn(comm):
+        assert comm.coll.providers["allreduce_arr"] == layout
+        host = np.arange(16, dtype=np.float64) + comm.rank
+        with pytest.raises(errhandler.MPIException) as e:
+            if call == "allreduce":
+                comm.allreduce_arr(host, mpi_op.SUM)
+            elif call == "reduce_scatter":
+                comm.reduce_scatter_arr(host, mpi_op.MAX)
+            elif call == "bcast":
+                comm.bcast_arr(host, root=0)
+            elif call == "typed_host_buffer":
+                comm.reduce_scatter_arr(host, mpi_op.MAX, vec, 1)
+            else:
+                # what jax.device_put made of the doubles: float32
+                comm.reduce_scatter_arr(_put(comm, host), mpi_op.MAX,
+                                        vec, 1)
+        assert "mpi_device_x64" in str(e.value)
+        return e.value.code
+
+    n, device_map = _ON_ONE_DEVICE[layout]
+    res = run_ranks(n, fn, devices=True, device_map=device_map)
+    assert set(res) == {errhandler.ERR_TYPE}
+
+
+def test_x64_off_host_staged_fallback_refuses_too():
+    """A call no device module takes (a pair dtype is one; here a
+    1-rank comm) lands in coll/arr_host, whose way back to the device
+    would narrow."""
+    from ompi_tpu import errhandler
+
+    def fn(comm):
+        self_comm = comm.Split(comm.rank, 0)
+        with pytest.raises(errhandler.MPIException) as e:
+            self_comm.allreduce_arr(np.ones(4, np.float64), mpi_op.SUM)
+        # 8 bytes that are not narrowed pass: complex64
+        z = self_comm.allreduce_arr(np.ones(4, np.complex64), mpi_op.SUM)
+        return e.value.code, str(z.dtype)
+
+    res = run_ranks(2, fn, devices=True)
+    assert res == [(errhandler.ERR_TYPE, "complex64")] * 2
+
+
+def test_untyped_calls_keep_their_float32_path():
+    """The two new arguments default to None: an untyped call resolves
+    the same plan key as before (extra None) and moves no typed
+    counter."""
+    from ompi_tpu.mca.params import registry
+
+    def fn(comm):
+        pv = {p.full_name: p for p in registry.all_pvars()}
+        t0 = pv["coll_typed_device_ops"].read()
+        x = _put(comm, jnp.arange(16, dtype=jnp.float32))
+        comm.reduce_scatter_arr(x, mpi_op.MAX)
+        comm.allreduce_arr(x, mpi_op.SUM)
+        keys = list(comm.__dict__["_hbm_plans"])
+        comm.Barrier()
+        return [k[-1] for k in keys], pv["coll_typed_device_ops"].read() - t0
+
+    res = run_ranks(4, fn, devices=True,
+                    device_map=lambda r: jax.devices()[0])
+    assert all(extras == [None, None] and typed == 0
+               for extras, typed in res)

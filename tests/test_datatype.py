@@ -376,3 +376,37 @@ def test_device_pack_indexed_and_contiguous():
     host = np.frombuffer(cv.pack(idxed, 1, buf), dtype=np.int32)
     dev = np.asarray(device_pack(idxed, 1, jnp.asarray(buf)))
     assert np.array_equal(host, dev)
+
+
+def test_typed_operand_is_the_one_eligibility_rule():
+    """datatype/device.typed_operand: committed, device-packable, base
+    type equal to the buffer's, buffer long enough; equal layouts are
+    one key whatever datatype object described them."""
+    from ompi_tpu import errhandler
+    from ompi_tpu.datatype import engine as dt
+    from ompi_tpu.datatype.device import label, typed_count, typed_operand
+
+    buf = np.zeros(16, np.float32)
+    vec = dt.vector(4, 2, 4, dt.FLOAT).commit()
+    t = typed_operand(vec, 1, buf)
+    assert (t.elems, t.span, label(vec)) == (8, 14, "VECTOR")
+    assert label(dt.FLOAT) == "MPI_FLOAT"
+    assert t.dtype == np.float32
+    # the same layout through another constructor: the same key
+    same = dt.indexed([2] * 4, [0, 4, 8, 12], dt.FLOAT).commit()
+    assert typed_operand(same, 1, buf) == t
+    assert hash(typed_operand(same, 1, buf)) == hash(t)
+    assert typed_operand(dt.vector(4, 2, 3, dt.FLOAT).commit(), 1, buf) != t
+    # another base type, a mixed struct: the host convertor's
+    assert typed_operand(vec, 1, np.zeros(16, np.int32)) is None
+    st = dt.struct([1, 1], [0, 8], [dt.INT, dt.DOUBLE]).commit()
+    assert typed_operand(st, 1, buf) is None
+    with pytest.raises(IndexError):
+        typed_operand(vec, 1, np.zeros(13, np.float32))
+    with pytest.raises(errhandler.MPIException) as e:
+        typed_operand(dt.vector(4, 2, 4, dt.FLOAT), 1, buf)
+    assert e.value.code == errhandler.ERR_TYPE
+    # count None: as many elements of the type as the buffer holds
+    assert typed_count(vec, None, buf) == 1
+    assert typed_count(dt.FLOAT, None, buf) == 16
+    assert typed_count(vec, 3, buf) == 3

@@ -174,3 +174,26 @@ def test_chunked_header_checkpoint_roundtrip():
         return True
 
     assert all(run_ranks(2, fn))
+
+
+def test_x64_off_send_arr_refuses_an_8_byte_host_buffer():
+    """jax.device_put would hand the receiver float32: with
+    mpi_device_x64 off (the default) the send raises MPI_ERR_TYPE
+    naming the parameter, and a float32 buffer goes as before."""
+    import jax
+    from ompi_tpu import errhandler
+
+    assert not jax.config.jax_enable_x64
+
+    def fn(comm):
+        nxt, prv = (comm.rank + 1) % comm.size, (comm.rank - 1) % comm.size
+        with pytest.raises(errhandler.MPIException) as e:
+            comm.send_arr(np.ones(8, np.float64), nxt, tag=9)
+        assert "mpi_device_x64" in str(e.value)
+        got = comm.sendrecv_arr(np.full(8, comm.rank, np.float32), nxt,
+                                prv, tag=10)
+        return e.value.code, str(got.dtype), float(got[0])
+
+    res = run_ranks(2, fn, devices=True)
+    assert res == [(errhandler.ERR_TYPE, "float32", 1.0),
+                   (errhandler.ERR_TYPE, "float32", 0.0)]

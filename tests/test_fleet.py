@@ -457,7 +457,7 @@ def test_hbm_quota_degrades_then_rejects_without_poisoning_pool(
             "from ompi_tpu.op import op as mpi_op\n"
             "comm = ompi_tpu.init()\n"
             "for i in range(8):\n"
-            "    x = np.full(4096, float(comm.rank + i), np.float64)\n"
+            "    x = np.full(8192, float(comm.rank + i), np.float32)\n"
             "    comm.allreduce_arr(x, mpi_op.SUM)\n"
             "ompi_tpu.finalize()\n")
     cb = DvmClient(uri)

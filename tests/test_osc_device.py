@@ -420,3 +420,38 @@ def test_counter_byte_identity_across_shrink():
     assert got[0] is None
     for i in range(1, 4):
         assert got[i] == ref[i - 1], i
+
+
+def test_device_window_never_narrows_a_double():
+    """With mpi_device_x64 off (the default) a device window still
+    moves and accumulates every bit of a float64: the window is bytes
+    (uint8) on the device, so nothing here can be narrowed to float32,
+    and nothing has to be refused."""
+    import jax
+
+    assert not jax.config.jax_enable_x64
+    # needs every one of the 53 significand bits
+    vals = np.array([1.0 + 2.0 ** -52, -(2.0 ** 0.5), 3.0e-300, 7.0e300])
+
+    def fn(comm):
+        rank, size = comm.rank, comm.size
+        win = osc.allocate(comm, 4 * 8, disp_unit=8, name="f64-bits")
+        kind = type(win).__name__
+        win.fence()
+        win.put(vals * (rank + 1), (rank + 1) % size)
+        win.fence()
+        got = np.empty(4, np.float64)
+        win.get(got, (rank + 1) % size)
+        win.fence()
+        win.accumulate(np.full(4, 2.0 ** -60), rank, op=mpi_op.MAX)
+        win.fence()
+        mem = np.asarray(win.memory).view(np.float64).copy()
+        win.free()
+        return kind, got.tobytes(), mem.tobytes()
+
+    res = run_ranks(2, fn, devices=True)
+    for rank, (kind, got, mem) in enumerate(res):
+        assert kind == "DeviceWindow"
+        assert got == (vals * (rank + 1)).tobytes()
+        want = np.maximum(vals * ((rank - 1) % 2 + 1), 2.0 ** -60)
+        assert mem == want.tobytes()

@@ -233,11 +233,11 @@ def test_shrink_device_allreduce_byte_identical():
             ulfm.kill_now(comm.state)
         time.sleep(0.3)
         new = comm.shrink()
-        x = np.arange(8.0) * (new.rank + 1)
+        x = np.arange(8, dtype=np.float32) * (new.rank + 1)
         return np.asarray(new.allreduce_arr(x, mpi_op.SUM)).tobytes()
 
     def fresh_bytes(comm):
-        x = np.arange(8.0) * (comm.rank + 1)
+        x = np.arange(8, dtype=np.float32) * (comm.rank + 1)
         return np.asarray(comm.allreduce_arr(x, mpi_op.SUM)).tobytes()
 
     got = run_ranks(4, survivor_bytes, devices=True,
@@ -253,7 +253,7 @@ def test_shrink_invalidates_compiled_cache():
     from ompi_tpu.coll import device
 
     def fn(comm):
-        x = np.arange(8.0)
+        x = np.arange(8, dtype=np.float32)
         comm.allreduce_arr(x, mpi_op.SUM)  # compile on the 4-mesh
         mesh = comm.__dict__.get("_mesh")
         dev_key = (tuple(d.id for d in mesh.devices.reshape(-1))
@@ -301,7 +301,7 @@ def test_chaos_demo_threadworld():
             if comm.rank == 0 and step == 5:
                 ulfm.kill_now(comm.state)  # dies mid-loop
             try:
-                x = np.arange(8.0) * (work.rank + 1)
+                x = np.arange(8, dtype=np.float32) * (work.rank + 1)
                 out = np.asarray(work.allreduce_arr(x, mpi_op.SUM))
                 step += 1
                 time.sleep(0.02)
@@ -311,7 +311,7 @@ def test_chaos_demo_threadworld():
         return (work.size, out.tobytes())
 
     def fresh(comm):
-        x = np.arange(8.0) * (comm.rank + 1)
+        x = np.arange(8, dtype=np.float32) * (comm.rank + 1)
         return np.asarray(comm.allreduce_arr(x, mpi_op.SUM)).tobytes()
 
     got = run_ranks(4, chaos, devices=True, allow_failures=True,
