@@ -521,8 +521,8 @@ def run_device_sweep(nranks: int, max_ar: int, max_bcast: int,
             # BASELINE config 5 as specified: MPI_MAX on MPI_DOUBLE
             # sourced through a derived VECTOR datatype, with the
             # datatype pack running ON DEVICE (datatype/device.py: the
-            # run descriptors become one XLA gather fused into the
-            # collective).  float64 needs jax x64; when the backend
+            # run descriptors become static slices, here a strided
+            # read, fused into the collective).  float64 needs jax x64; when the backend
             # cannot compile f64 (some TPU generations) the sweep
             # falls back to float32 and RECORDS the substitution
             # instead of silently benching a different config.
@@ -567,10 +567,10 @@ def run_device_sweep(nranks: int, max_ar: int, max_bcast: int,
                               axis=1).reshape(-1), comm.device)
                 packed_fn = jax.jit(
                     lambda a: device_pack(vec, 1, a))
-                packed_fn(raw)  # warm the gather
+                packed_fn(raw)  # warm the pack
 
                 # chain: re-interleave the (n/P)-element result back
-                # into the strided raw layout — the device_pack gather
+                # into the strided raw layout — the device_pack slice
                 # stays INSIDE the timed loop (it is part of config 5)
                 # and every iteration's raw input depends on the
                 # previous collective's output

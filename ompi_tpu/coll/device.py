@@ -101,6 +101,12 @@ _pv_typed_dev = registry.register_pvar(
     help="Typed *_arr collectives (a datatype argument) served on the "
          "device with the pack inside the collective's own program; "
          "once a rank-call")
+_pv_typed_sliced = registry.register_pvar(
+    "coll", "typed", "sliced_packs",
+    help="Typed *_arr collectives served on the device whose datatype "
+         "packs as static slices of the buffer, no gather "
+         "(datatype/device.Typed.sliced); once a rank-call, so "
+         "coll_typed_device_ops less this is the calls still gathering")
 _pv_typed_host = registry.register_pvar(
     "coll", "typed", "host_packs",
     help="Typed *_arr collectives whose datatype the host convertor "
@@ -1082,6 +1088,13 @@ def typed_arr(comm, entry, x, op: Op, datatype, count):
         tr.coll_args = None     # a span sampled out took nothing
 
 
+def _count_typed(t) -> None:
+    """One typed rank-call served on the device with ``t``."""
+    _pv_typed_dev.add(1)
+    if t.sliced:
+        _pv_typed_sliced.add(1)
+
+
 def _typed_on_device(mod, comm, kind: str, x, op: Op, datatype, count):
     """The ``Typed`` a device provider serves a typed call with, or
     None when the call goes to the host fallback.  One rule for
@@ -1205,7 +1218,7 @@ class TpuCollModule(CollModule):
 
         ck = _ig.spec_typed(_CK_KINDS[kind], opname, t) if _ig.on else None
         out = self._run(comm, x.reshape(-1), fn, ck)
-        _pv_typed_dev.add(1)
+        _count_typed(t)
         return out
 
     def allreduce_arr(self, comm, x, op: Op, datatype=None, count=None):
@@ -1509,7 +1522,7 @@ class HbmCollModule(CollModule):
         if t is None:
             return self.fallback.typed(comm, kind, x, op, datatype, count)
         out = self._run(comm, kind, op.name, x.reshape(-1), t)
-        _pv_typed_dev.add(1)
+        _count_typed(t)
         return out
 
     def _meet(self, comm, x, fn, ck=None):

@@ -11,7 +11,8 @@ is the external32 mode).
 Because committed datatypes are flat run vectors (see engine.py), the
 "stack" collapses to (run index, block index, byte-within-block), and
 whole-run copies vectorize through numpy strided views — the same
-descriptor program the device path turns into one XLA gather.
+descriptor program the device path turns into static slices, or one
+XLA gather where the layout is irregular.
 """
 
 from __future__ import annotations

@@ -1,14 +1,30 @@
-"""On-device datatype packing: the descriptor program as ONE XLA
-gather.
+"""On-device datatype packing: the descriptor program as static
+slices of the buffer, or as ONE XLA gather where the layout has no
+such form.
 
 The north-star item SURVEY §2.9.1 calls "datatype packing done
 on-device": a committed datatype's run descriptors (engine.py) are
-compiled once into an element-index vector, and packing a
-device-resident buffer becomes ``buf[idx]`` — a single XLA gather the
-compiler fuses into the collective that consumes it (reference
-counterpart: the convertor pack loop feeding coll buffers,
-opal/datatype/opal_convertor.h:131-137, which walks descriptors
-element-wise on the host CPU).  Unpack is the mirrored scatter.
+merged once into the layout they describe, a few regular runs
+``(base, nblocks, blocklen, stride)`` in elements, and packing a
+device-resident buffer lowers by that layout inside the collective
+that consumes it (reference counterpart: the convertor pack loop
+feeding coll buffers, opal/datatype/opal_convertor.h:131-137, which
+walks descriptors element-wise on the host CPU):
+
+* a contiguous run is a static slice, the array itself when that is
+  all of it;
+* equal blocks a stride apart are the leading columns of a (blocks,
+  stride) view, with the last block apart: the buffer may end at the
+  datatype's span, not at a whole stride.  Where the stride is a few
+  elements (one component of interleaved fields) the view is of rows
+  of 128 strides, transposed, so that the stride lies along rows;
+* anything else (many runs, an ``indexed`` with irregular
+  displacements) is ``buf[idx]``, a per-element gather: 7 to 25 ns an
+  element on a v5e (PERF.md section 5), where a slice runs near the
+  memory's speed.
+
+No index vector exists for a sliced layout until the host's view asks
+for one (``Typed.idx``).  Unpack is the mirrored scatter.
 
 Eligibility: every run must use the same primitive dtype as the
 buffer, with displacements/strides that are whole elements —
@@ -60,35 +76,125 @@ def from_order_key(k):
     return k ^ (((k >> u(63)) - u(1)) | u(_SIGN))
 
 
+def _merge(runs):
+    """Runs ``(base, nblocks, blocklen, stride)`` in elements, in
+    packed order, merged into the fewest regular runs a left-to-right
+    pass finds: adjacent blocks join, equal blocks equally far apart
+    fold into one strided run.  However a datatype was built (a
+    ``vector``, an ``hvector`` in whole elements, a ``subarray`` row
+    block, an ``indexed`` with even displacements, ``count`` of a type
+    whose extent continues its stride), one regular layout comes out
+    as the same single run; a lone block has ``stride == blocklen``."""
+    out = []
+    for base, n, l, s in runs:
+        if n == 1 or s == l:            # one block, or blocks that touch
+            n, l = 1, n * l
+            s = l
+        if out:
+            b0, n0, l0, s0 = out[-1]
+            if n0 == 1 and n == 1 and base == b0 + l0:
+                out[-1] = (b0, 1, l0 + l, l0 + l)
+                continue
+            step = base - b0 if n0 == 1 else s0
+            if l == l0 and step > l and base == b0 + n0 * step \
+                    and (n == 1 or s == step):
+                out[-1] = (b0, n0 + n, l, step)
+                continue
+        out.append((base, n, l, s))
+    return tuple(out)
+
+
+#: a vector register's lanes.  A TPU lays a 2-D view out in tiles of
+#: 128 lanes, so a (blocks, stride) view of a stride below that is
+#: padded to 128 a row (stride 5: 25 times the bytes, and minutes of
+#: compile; measured, PERF.md section 5).
+_LANES = 128
+#: the most runs packed as a concatenation of slices
+_MAX_SLICED_RUNS = 4
+
+
+def _rows(flat, base, n, l, s):
+    """``n`` blocks as the leading ``l`` columns of a (blocks, stride)
+    view, with the last block apart where the buffer ends at the
+    datatype's span and not at a whole stride.  For rows at least
+    ``_LANES`` long, or at most ``_LANES`` of them."""
+    from jax import lax
+    last = base + (n - 1) * s
+    if flat.shape[0] >= last + s:
+        return lax.slice(flat, (base,), (last + s,)) \
+            .reshape(n, s)[:, :l].reshape(-1)
+    tail = lax.slice(flat, (last,), (last + l,))
+    if n == 1:
+        return tail
+    head = lax.slice(flat, (base,), (last,)).reshape(n - 1, s)[:, :l]
+    return lax.concatenate([head.reshape(-1), tail], 0)
+
+
+def _slice_run(flat, base, n, l, s):
+    """One regular run of ``flat`` (1-D, traced) with static bounds."""
+    from jax import lax
+    if n == 1:
+        if base == 0 and l == flat.shape[0]:
+            return flat
+        return lax.slice(flat, (base,), (base + l,))
+    # rows of _LANES strides each that the run holds whole
+    w = _LANES * s
+    rows = ((n - 1) * s + l) // w
+    if s >= _LANES or not rows:
+        return _rows(flat, base, n, l, s)
+    # many blocks a few elements apart: those rows transposed, so that
+    # the stride lies along rows, where a slice is address arithmetic
+    # and no view has a minor dimension of a few elements; the blocks
+    # past the last whole row are a row view
+    head = lax.slice(flat, (base,), (base + rows * w,)).reshape(rows, w).T
+    head = head.reshape(_LANES, s, rows)[:, :l, :].reshape(_LANES * l, rows)
+    head = head.T.reshape(-1)
+    done = rows * _LANES
+    if done == n:
+        return head
+    return lax.concatenate(
+        [head, _rows(flat, base + rows * w, n - done, l, s)], 0)
+
+
 class Typed:
-    """A committed datatype and a count, resolved once: the index
-    vector whose gather is the packed stream (``idx``), the one
+    """A committed datatype and a count, resolved once: the regular
+    runs its packed stream is made of (``layout``: ``(base, nblocks,
+    blocklen, stride)`` in elements, in packed order), the one
     primitive type of its runs (``dtype``), and what the device
     providers of a typed ``*_arr`` collective need of them: ``pack``
-    (the gather, inside the provider's jit), ``sig`` (what keys their
-    programs: base type, carrier and a digest of ``idx``, so equal
-    layouts share one executable whatever datatype object described
-    them).  ``bits``: the buffer holds MPI_DOUBLE as uint64 bit
-    patterns; ``pack`` then hands the reduction order keys and
-    ``unkey`` turns its results back."""
+    (inside the provider's jit; its ``stream`` is static slices where
+    ``sliced``, else the gather of ``idx``), ``sig`` (what keys their
+    programs: base type, carrier and the layout itself, or a digest of
+    ``idx`` where the layout is gathered, so equal layouts share one
+    executable whatever datatype object described them).  ``bits``:
+    the buffer holds MPI_DOUBLE as uint64 bit patterns; ``pack`` then
+    hands the reduction order keys and ``unkey`` turns its results
+    back."""
 
-    __slots__ = ("datatype", "count", "dtype", "idx", "elems", "span",
-                 "bits", "sig", "_twin")
+    __slots__ = ("datatype", "count", "dtype", "layout", "sliced", "elems",
+                 "span", "bits", "sig", "_idx", "_twin")
 
     def __init__(self, datatype: Datatype, count: int, dtype: np.dtype,
-                 idx: np.ndarray) -> None:
+                 layout: tuple) -> None:
         import hashlib
 
         self.datatype = datatype
         self.count = count
         self.dtype = dtype
-        self.idx = idx
-        self.elems = int(idx.size)          # packed stream, in elements
-        self.span = int(idx.max()) + 1 if idx.size else 0
+        self.layout = layout
+        #: a few runs, each one block or equal blocks a constant
+        #: positive gap apart: read with slices, no index vector
+        self.sliced = len(layout) <= _MAX_SLICED_RUNS \
+            and all(n == 1 or l < s for _, n, l, s in layout)
+        # packed stream, in elements; the buffer it is read from
+        self.elems = sum(n * l for _, n, l, _ in layout)
+        self.span = max(b + (n - 1) * max(s, 0) + l for b, n, l, s in layout)
         self.bits = False
-        self.sig = ("typed", dtype.str, self.elems,
-                    hashlib.blake2b(np.ascontiguousarray(idx).tobytes(),
-                                    digest_size=16).hexdigest())
+        self._idx = None
+        self.sig = ("typed", dtype.str, self.elems) + (
+            layout if self.sliced else
+            (hashlib.blake2b(np.ascontiguousarray(self.idx).tobytes(),
+                             digest_size=16).hexdigest(),))
         self._twin = None
 
     # a provider's cache key holds the Typed itself: equal layouts are
@@ -98,6 +204,20 @@ class Typed:
 
     def __eq__(self, other) -> bool:
         return isinstance(other, Typed) and self.sig == other.sig
+
+    @property
+    def idx(self) -> np.ndarray:
+        """The element-index vector whose gather is the packed stream:
+        what a gathered layout packs with, and the host's view of any
+        (``operand``, ``element_indices``, ``device_unpack``).  Built
+        when first asked for: O(n) on the host, 8 bytes an element."""
+        if self._idx is None:
+            chunks = [(b + s * np.arange(n, dtype=np.int64)[:, None]
+                       + np.arange(l, dtype=np.int64)[None, :]).reshape(-1)
+                      for b, n, l, s in self.layout]
+            self._idx = np.concatenate(chunks) if len(chunks) > 1 \
+                else chunks[0]
+        return self._idx
 
     def carried(self, dtype) -> Optional["Typed"]:
         """The Typed for a buffer of ``dtype``: this one when it is the
@@ -110,15 +230,24 @@ class Typed:
             return None
         if self._twin is None:
             t = Typed.__new__(Typed)
-            for k in ("datatype", "count", "dtype", "idx", "elems", "span"):
+            for k in ("datatype", "count", "dtype", "layout", "sliced",
+                      "elems", "span", "_idx"):
                 setattr(t, k, getattr(self, k))
             t.bits, t.sig, t._twin = True, self.sig + ("bits",), self
             self._twin = t
         return self._twin
 
-    def pack(self, arr):
+    def stream(self, arr):
+        """The packed stream of ``arr``, as the buffer holds it."""
         import jax.numpy as jnp
-        out = jnp.take(arr.reshape(-1), jnp.asarray(self.idx), axis=0)
+        flat = arr.reshape(-1)
+        if not self.sliced:
+            return jnp.take(flat, jnp.asarray(self.idx), axis=0)
+        parts = [_slice_run(flat, *run) for run in self.layout]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+    def pack(self, arr):
+        out = self.stream(arr)
         return order_key(out) if self.bits else out
 
     def unkey(self, out):
@@ -142,31 +271,28 @@ class Typed:
 @functools.lru_cache(maxsize=64)
 def resolve(datatype: Datatype, count: int) -> Optional[Typed]:
     """The ``Typed`` of ``count`` elements of ``datatype``, or None
-    when the datatype is not device-packable.  The one cache: index
-    construction is host-side and O(n) (16 MiB of indices for the 2 Mi
-    elements of a large vector, hence the bound), the device gather is
-    the per-call cost."""
+    when the datatype is not device-packable.  The one cache: merging
+    the run descriptors is host-side and O(runs); a gathered layout's
+    index vector is O(n) besides (8 bytes an element, hence the
+    bound), a sliced layout has none until the host's view asks."""
     runs = datatype.runs_for_count(count)
     if not runs:
         return None
     item = runs[0].dtype.itemsize
-    chunks = []
+    elems = []
     for r in runs:
         if r.dtype != runs[0].dtype:
             return None  # mixed primitive types: host convertor
         if r.disp % item or r.stride % item:
             return None  # sub-element displacement: host convertor
-        base = r.disp // item
-        stride = r.stride // item
-        # (nblocks, count) element grid -> flat packed order
-        grid = (base
-                + stride * np.arange(r.nblocks, dtype=np.int64)[:, None]
-                + np.arange(r.count, dtype=np.int64)[None, :])
-        chunks.append(grid.reshape(-1))
-    idx = np.concatenate(chunks) if len(chunks) > 1 else chunks[0]
-    if (idx < 0).any():
-        return None  # negative displacement: host convertor owns it
-    return Typed(datatype, count, runs[0].dtype, idx)
+        base, stride = r.disp // item, r.stride // item
+        if min(base, base + (r.nblocks - 1) * stride) < 0:
+            return None  # negative displacement: host convertor owns it
+        if r.nblocks and r.count:
+            elems.append((base, r.nblocks, r.count, stride))
+    if not elems:
+        return None
+    return Typed(datatype, count, runs[0].dtype, _merge(elems))
 
 
 def element_indices(datatype: Datatype, count: int) -> Optional[np.ndarray]:
@@ -178,19 +304,21 @@ def element_indices(datatype: Datatype, count: int) -> Optional[np.ndarray]:
 
 
 def device_pack(datatype: Datatype, count: int, arr):
-    """Pack a device-resident array through the datatype: one XLA
-    gather (jittable; fuses into downstream collectives).  ``arr`` is
-    the flat element-typed buffer the datatype addresses."""
+    """Pack a device-resident array through the datatype: static
+    slices of a regular layout, else one XLA gather (jittable; fuses
+    into downstream collectives).  ``arr`` is the flat element-typed
+    buffer the datatype addresses, or MPI_DOUBLE's uint64 carrier."""
     t = resolve(datatype, count)
     if t is None:
         raise ValueError(
             f"datatype {datatype.name or datatype.id} is not "
             f"device-packable (mixed types or sub-element layout)")
-    if t.dtype != np.dtype(arr.dtype):
+    c = t.carried(arr.dtype)
+    if c is None:
         raise ValueError(
             f"buffer dtype {arr.dtype} does not match datatype base "
             f"{t.dtype}")
-    return t.pack(arr)
+    return c.stream(arr)
 
 
 def device_unpack(datatype: Datatype, count: int, packed, out):
@@ -224,8 +352,8 @@ def typed_count(datatype: Datatype, count: Optional[int], x) -> int:
 
 def typed_operand(datatype: Datatype, count: int, x) -> Optional[Typed]:
     """The one eligibility rule of every provider: a ``Typed`` when the
-    packed stream of ``count`` elements of ``datatype`` can be gathered
-    from ``x`` on the device (committed, device-packable, base type
+    packed stream of ``count`` elements of ``datatype`` can be read out
+    of ``x`` on the device (committed, device-packable, base type
     equal to the buffer's, or MPI_DOUBLE carried as uint64 bit
     patterns), else None (the host convertor serves it).  Depends only
     on the datatype, the count and the buffer's dtype and size, which

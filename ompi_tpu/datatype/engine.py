@@ -14,8 +14,9 @@ vector of **runs**:
 Regular nesting (contiguous-of-vector etc.) is collapsed at build time
 (the analog of opal_datatype_optimize.c), so the host pack path is a
 handful of vectorized numpy strided copies, and the device pack path
-is a single gather with precomputed indices — both TPU/XLA-friendly
-shapes of the same descriptor program.
+is static slices of a regular layout, or a single gather with
+precomputed indices for any other (datatype/device.py) — both
+TPU/XLA-friendly shapes of the same descriptor program.
 """
 
 from __future__ import annotations

@@ -10,8 +10,9 @@ reference on the CPU.
   uint64 bit-pattern carrier a device without binary64 takes (there a
   SUM is the host's); for indexed, contiguous and tail-block types
   they equal the host convertor's pack followed by the untyped call;
-* a typed call is one rendezvous and one program with the gather
-  inside it; the two counters move as PERF.md section 3 says; a
+* a typed call is one rendezvous and one program with the pack
+  inside it (static slices of a regular layout, a gather of any
+  other); the two counters move as PERF.md section 3 says; a
   datatype the device cannot pack is served by the host fallback and
   counted;
 * answers computed through float32, the odd-indexed elements, a rank's
@@ -241,10 +242,110 @@ def test_the_pack_is_inside_the_named_program():
     x = jnp.zeros(reference_typed.span_elems(VECTOR), jnp.float64)
     low = jbody.lower(*[x] * 8)
     assert "ompi_typed_reduce_scatter" in low.as_text()
-    assert "gather" in low.compile().as_text()
+    # the cell's layout is one regular run: a strided slice, no gather
+    # and no index vector, in the program or on the host
+    assert typed.sliced and typed.layout == ((0, VECTOR["count"], 1, 2),)
+    assert "gather" not in low.as_text()
+    assert "gather" not in low.compile().as_text()
+    assert typed._idx is None
     # equal layouts described twice are one program
     again = coll_device._dtdev.typed_operand(vec_type(VECTOR), 1, x)
     assert again == typed and hash(again) == hash(typed)
+    # a layout with no such form keeps the gather, inside the same program
+    irregular = coll_device._dtdev.typed_operand(IRREGULAR(), 1, x)
+    jbody, _ = coll_device.HbmCollModule._build_stacked(
+        "reduce_scatter", "MPI_MAX", irregular)
+    low = jbody.lower(*[x] * 8)
+    assert not irregular.sliced
+    assert "ompi_typed_reduce_scatter" in low.as_text()
+    assert "gather" in low.compile().as_text()
+
+
+def IRREGULAR(base=dtmod.DOUBLE):
+    """8 blocks of unequal lengths at uneven displacements, 32 elements:
+    more runs than a concatenation of slices takes."""
+    return dtmod.indexed([3, 1, 4, 8, 2, 5, 6, 3],
+                         [40, 2, 9, 20, 60, 70, 80, 90], base).commit()
+
+
+def same_elements_as(vector, base=dtmod.DOUBLE):
+    """The vector's elements, described by three other datatypes."""
+    n, stride = vector["count"], vector["stride"]
+    assert vector["blocklength"] == 1
+    return {
+        "vector": vec_type(vector, base),
+        "hvector": dtmod.hvector(n, 1, stride * base.size, base).commit(),
+        # column 0 of an (n, stride) array
+        "subarray": dtmod.subarray([n, stride], [n, 1], [0, 0],
+                                   dtmod.ORDER_C, base).commit(),
+        "indexed": dtmod.indexed([1] * n, range(0, n * stride, stride),
+                                 base).commit(),
+    }
+
+
+@pytest.mark.parametrize("layout", ["hbm", "tpu"])
+def test_the_sliced_counter_moves_once_a_rank_call(layout):
+    """coll_typed_sliced_packs: one a rank-call on both providers'
+    typed paths when the call's layout packs as slices (the cell's
+    vector), none for an irregular indexed, which still counts as a
+    typed device operation."""
+    names = ("coll_typed_device_ops", "coll_typed_sliced_packs",
+             "coll_typed_host_packs")
+
+    def fn(comm):
+        x = buffer_of(comm, VECTOR)
+        moved = []
+        for dt in (vec_type(VECTOR), IRREGULAR()):
+            comm.Barrier()
+            before = [pvar(n) for n in names]
+            comm.Barrier()
+            jax.block_until_ready(
+                comm.reduce_scatter_arr(x, mpi_op.MAX, dt, 1))
+            jax.block_until_ready(comm.allreduce_arr(x, mpi_op.MIN, dt, 1))
+            comm.Barrier()
+            moved.append([pvar(n) - b for n, b in zip(names, before)])
+        return moved
+
+    n, res = ranks(layout, fn)
+    for sliced, gathered in res:
+        assert sliced == [2 * n, 2 * n, 0]
+        assert gathered == [2 * n, 0, 0]
+
+
+@pytest.mark.parametrize("layout", ["hbm", "tpu"])
+def test_equal_layouts_share_one_program_and_different_ones_do_not(layout):
+    """What keys a typed program is the layout, not the datatype
+    object: a vector, an hvector, a subarray column and an indexed that
+    address the same elements compile once; another regular layout of
+    as many elements compiles its own."""
+    other = {"count": VECTOR["count"], "blocklength": 1, "stride": 3}
+    assert other["stride"] != VECTOR["stride"]
+
+    def fn(comm):
+        # long enough for either layout
+        x = buffer_of(comm, other)
+        cache = coll_device.compile_cache
+        answers, builds = {}, {}
+        for name, dt in list(same_elements_as(VECTOR).items()) + [
+                ("other", vec_type(other))]:
+            comm.Barrier()
+            before = cache.builds
+            comm.Barrier()
+            answers[name] = np.asarray(
+                comm.reduce_scatter_arr(x, mpi_op.MAX, dt, 1))
+            comm.Barrier()
+            builds[name] = cache.builds - before
+        return answers, builds
+
+    _, res = ranks(layout, fn)
+    for answers, builds in res:
+        # rank-threads that miss together each build (CompiledLRU runs
+        # builders outside its lock), so a new layout is one or more
+        assert builds.pop("vector") >= 1 and builds.pop("other") >= 1
+        assert builds == {"hvector": 0, "subarray": 0, "indexed": 0}
+        for name in ("hvector", "subarray", "indexed"):
+            assert answers[name].tobytes() == answers["vector"].tobytes()
+        assert answers["other"].tobytes() != answers["vector"].tobytes()
 
 
 def test_a_datatype_the_device_cannot_pack_is_served_by_the_host():

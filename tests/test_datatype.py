@@ -378,6 +378,137 @@ def test_device_pack_indexed_and_contiguous():
     assert np.array_equal(host, dev)
 
 
+def _pack_cases(base):
+    """name -> (datatype built on ``base``, count, elements the buffer
+    holds beyond the span, how it must lower)."""
+    item = base.size
+    vec = dt.vector(4, 1, 2, base)
+    return {
+        # one component of interleaved pairs; the buffer ends at the
+        # span, one element short of a whole stride (odd length)
+        "vector_1_2_odd_buffer": (dt.vector(9, 1, 2, base), 1, 0, "slice"),
+        "vector_1_2_long_buffer": (dt.vector(9, 1, 2, base), 1, 5,
+                                   "slice"),
+        # the same elements from a first displacement of 3
+        "indexed_1_2_from_3": (dt.indexed(
+            [1] * 9, [3 + 2 * i for i in range(9)], base), 1, 0, "slice"),
+        "vector_3_5_ends_at_span": (dt.vector(6, 3, 5, base), 1, 0,
+                                    "slice"),
+        "indexed_3_5_from_4": (dt.indexed(
+            [3] * 6, [4 + 5 * i for i in range(6)], base), 1, 0,
+            "slice"),
+        # enough blocks a few elements apart for whole rows of 128
+        # strides (the transposed view) and some blocks past them
+        "vector_1_2_of_300": (dt.vector(300, 1, 2, base), 1, 0, "slice"),
+        "vector_1_2_of_256_whole_rows": (dt.vector(256, 1, 2, base), 1,
+                                         1, "slice"),
+        "vector_3_5_of_300": (dt.vector(300, 3, 5, base), 1, 0, "slice"),
+        "indexed_3_5_of_300_from_4": (dt.indexed(
+            [3] * 300, [4 + 5 * i for i in range(300)], base), 1, 2,
+            "slice"),
+        # row blocks of rows at least 128 elements long
+        "vector_100_130_ends_at_span": (dt.vector(5, 100, 130, base), 1,
+                                        0, "slice"),
+        "vector_100_130_whole_rows": (dt.vector(5, 100, 130, base), 1,
+                                      30, "slice"),
+        "hvector_100_130_from_7": (dt.hindexed(
+            [100] * 5, [(7 + 130 * i) * item for i in range(5)], base),
+            1, 0, "slice"),
+        "subarray_rows_150_of_200": (dt.subarray(
+            [6, 200], [3, 150], [1, 20], dt.ORDER_C, base), 1, 0,
+            "slice"),
+        "subarray_rows_4_of_8": (dt.subarray(
+            [6, 8], [3, 4], [1, 2], dt.ORDER_C, base), 1, 0, "slice"),
+        "subarray_columns_2_of_3": (dt.subarray(
+            [400, 3], [300, 2], [50, 1], dt.ORDER_C, base), 1, 0,
+            "slice"),
+        "contiguous": (dt.contiguous(24, base), 1, 0, "identity"),
+        "contiguous_in_a_longer_buffer": (dt.contiguous(24, base), 1, 9,
+                                          "slice"),
+        "primitive_count_n": (base, 24, 0, "identity"),
+        # count=3 of a vector resized so its extent continues the
+        # stride: one run; as MPI_Type_vector leaves it (the extent
+        # ends with the last block): three runs, three slices
+        "count3_extent_continues_stride": (
+            dt.resized(vec, 0, 8 * item), 3, 0, "slice"),
+        "count3_extent_does_not": (vec, 3, 0, "slice"),
+        "count3_resized_apart": (dt.resized(vec, 0, 10 * item), 3, 0,
+                                 "slice"),
+        "indexed_irregular": (dt.indexed(
+            [3, 1, 4, 8, 2, 1, 5], [40, 2, 9, 20, 60, 70, 80], base), 1,
+            0, "gather"),
+    }
+
+
+_PACK_CASES = sorted(_pack_cases(dt.DOUBLE))
+_CARRIERS = {"float32": (dt.FLOAT, np.float32),
+             "float64": (dt.DOUBLE, np.float64),
+             "bits": (dt.DOUBLE, np.uint64)}
+
+
+@pytest.fixture
+def x64():
+    """binary64 and uint64 arrays in this process, for one test."""
+    import jax
+    jax.config.update("jax_enable_x64", True)
+    yield
+    jax.config.update("jax_enable_x64", False)
+
+
+def _pack_case(case, carrier):
+    base, dtype = _CARRIERS[carrier]
+    datatype, count, beyond, lowering = _pack_cases(base)[case]
+    if datatype is not base:
+        datatype.commit()
+    return datatype, count, beyond, lowering, np.dtype(dtype)
+
+
+@pytest.mark.parametrize("carrier", sorted(_CARRIERS))
+@pytest.mark.parametrize("case", _PACK_CASES)
+def test_device_pack_equals_the_host_convertor(case, carrier, x64):
+    """Whatever the lowering, the packed stream is the host
+    convertor's, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    from ompi_tpu.datatype import convertor as cv
+    from ompi_tpu.datatype.device import device_pack, resolve
+
+    datatype, count, beyond, _, dtype = _pack_case(case, carrier)
+    n = resolve(datatype, count).span + beyond
+    rng = np.random.default_rng(n)
+    buf = rng.integers(0, 2 ** 63, n, dtype=np.uint64) if dtype.kind == "u" \
+        else rng.standard_normal(n).astype(dtype)
+    # the convertor packs MPI_DOUBLE's bytes, whatever integer the
+    # device held them in
+    host = cv.pack(datatype, count,
+                   buf.view(np.float64 if dtype.kind == "u" else dtype))
+    dev = jax.jit(lambda a: device_pack(datatype, count, a))(
+        jnp.asarray(buf))
+    assert dev.dtype == dtype
+    assert np.asarray(dev).tobytes() == bytes(host)
+
+
+@pytest.mark.parametrize("carrier", sorted(_CARRIERS))
+@pytest.mark.parametrize("case", _PACK_CASES)
+def test_a_regular_layout_lowers_without_a_gather(case, carrier, x64):
+    """The program text of the pack: static slices for a regular run
+    (no gather, no index constant), the array itself for an identity,
+    one gather for what has no such form."""
+    import jax
+    from ompi_tpu.datatype.device import resolve, typed_operand
+
+    datatype, count, beyond, lowering, dtype = _pack_case(case, carrier)
+    x = jax.ShapeDtypeStruct((resolve(datatype, count).span + beyond,), dtype)
+    t = typed_operand(datatype, count, x)
+    assert t.sliced == (lowering != "gather")
+    text = jax.jit(t.stream).lower(x).as_text()
+    assert ("gather" in text) == (lowering == "gather")
+    if lowering != "gather":
+        assert "constant" not in text and t._idx is None   # no index list
+    if lowering == "identity":
+        assert "slice" not in text
+
+
 def test_typed_operand_is_the_one_eligibility_rule():
     """datatype/device.typed_operand: committed, device-packable, base
     type equal to the buffer's, buffer long enough; equal layouts are
