@@ -64,6 +64,9 @@ COUNTERS = {
     "plan_hits": "coll_plan_hits",
     "fused": "coll_device_fused_collectives",
     "typed": "coll_typed_device_ops",
+    "d2d": "btl_tpu_d2d_sends",
+    "staged": "btl_tpu_staged_sends",
+    "moved": "btl_tpu_recv_moves",
 }
 CALLS = 3  # after the compiling one
 
@@ -339,30 +342,46 @@ class Smoke:
         dst, src = (self.rank + 1) % p, (self.rank - 1) % p
         x = self.put(gen(self.seed, oid, self.rank, n, np.float32))
         comm.Barrier()
+        before = counters()
+        comm.Barrier()   # nobody counts before everybody has read
         times = []
         for _ in range(1 + CALLS):
             t0 = time.perf_counter()
             comm.send_arr(x, dst, tag=7)
             out = jax.block_until_ready(comm.recv_arr(src, tag=7))
             times.append(time.perf_counter() - t0)
+        comm.Barrier()
+        after = counters()
         ok = int(np.array_equal(
             np.asarray(out), gen(self.seed, oid, src, n, np.float32)))
         facts = self.gather([ok, int(self.on_my_device(out)),
                              int(statistics.median(times[1:]) * 1e6)])
         if self.rank != 0:
             return
+        # the provider is what the library's own counters say served
+        # the calls: every send placed on the peer's device, none
+        # through host memory, none placed again on arrival
+        delta = {k: after[k] - before[k] for k in ("d2d", "staged", "moved")}
+        placed = delta == {"d2d": p * (1 + CALLS), "staged": 0, "moved": 0}
+        provider = "btl/tpu" if placed else "btl/tpu-not-placed"
         rec = {"op": "send_arr_recv_arr_ring", "bytes": n * 4,
-               "dtype": "float32", "provider": "btl/tpu",
+               "dtype": "float32", "provider": provider,
                "smoke_timing_s": round(facts[:, 2].max() / 1e6, 6)}
         self.ops.append(rec)
         self.say(f"op={rec['op']} bytes={n * 4} dtype=float32 "
-                 f"provider=btl/tpu "
+                 f"provider={provider} "
+                 f"p2p_counters={json.dumps(delta, separators=(',', ':'))} "
                  f"smoke_timing_s={rec['smoke_timing_s']} "
                  f"(host clock, slowest rank, median of {CALLS})")
         if not facts[:, :2].all():
             raise SmokeFailure(
                 "send_arr/recv_arr ring: [matches numpy, on my device] "
                 f"per rank = {facts[:, :2].tolist()}")
+        if not placed:
+            raise SmokeFailure(
+                f"send_arr/recv_arr ring: {p * (1 + CALLS)} sends, and "
+                f"btl/tpu's counters moved by {delta}: not every one was "
+                "placed on the peer's device, or one went through the host")
 
     def win_epoch(self):
         """One Win fence epoch with put, get and accumulate through

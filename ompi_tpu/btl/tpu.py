@@ -19,6 +19,22 @@ diverge on because the receiver accepts the same wrapper either way.
 API (on Communicator): ``send_arr`` / ``recv_arr`` /
 ``sendrecv_arr``.  Ordering and matching are the pml's (same
 (cid, src, tag) discipline as byte messages).
+
+The path accounts for itself.  Plain counters, always on, say which
+way served a message: ``btl_tpu_d2d_sends`` / ``_d2d_bytes`` (placed
+on the peer's own device), ``btl_tpu_byref_sends`` (a co-resident peer
+that owns no device), ``btl_tpu_staged_sends`` / ``_staged_bytes``
+(every byte that went through host memory: the pickle of a
+cross-process send, and each chunk of a chunked pull) and
+``btl_tpu_recv_moves`` (a ``recv_arr`` that had to place the payload
+because it did not arrive on the rank's own device).  "Without host
+bounce" is then a statement a job can hold the library to: the staged
+counters and ``btl_tpu_recv_moves`` stay where they were.  With
+tracing on every call records a ``p2p`` span named for its way
+(``send_arr_d2d``, ``recv_arr_inplace``, ...) under the byte
+messages' match id, and with ``trace_phase_enable`` banks its time in
+the layer accumulators ``p2p_send`` / ``p2p_match`` / ``p2p_deliver``
+(ompi_tpu/trace).
 """
 
 from __future__ import annotations
@@ -29,7 +45,44 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
+from ompi_tpu import trace as _trace
+from ompi_tpu.mca.params import registry as _mca
 from ompi_tpu.runtime import x64 as _x64
+
+_pv_d2d_sends = _mca.register_pvar(
+    "btl", "tpu", "d2d_sends",
+    help="send_arr calls whose payload was placed on the co-resident "
+         "peer's own device (jax.device_put at send time) and handed "
+         "over by reference")
+_pv_d2d_bytes = _mca.register_pvar(
+    "btl", "tpu", "d2d_bytes",
+    help="Bytes of the payloads counted in btl_tpu_d2d_sends")
+_pv_byref_sends = _mca.register_pvar(
+    "btl", "tpu", "byref_sends",
+    help="send_arr calls to a co-resident peer that owns no device: "
+         "the array was handed over by reference where it was")
+_pv_staged_sends = _mca.register_pvar(
+    "btl", "tpu", "staged_sends",
+    help="send_arr calls whose payload went through host memory: "
+         "pickled to numpy across a process boundary, or parked for "
+         "the chunked pull (above btl_tpu_chunk_bytes)")
+_pv_staged_bytes = _mca.register_pvar(
+    "btl", "tpu", "staged_bytes",
+    help="Bytes of device-array messages that went through host "
+         "memory on the sender: each pickled payload and each chunk "
+         "the pull protocol staged")
+_pv_recv_moves = _mca.register_pvar(
+    "btl", "tpu", "recv_moves",
+    help="recv_arr calls that had to place the payload on the rank's "
+         "device because it arrived elsewhere (another chip, or host "
+         "memory after a staged send)")
+
+_CAT_P2P = _trace.CAT_P2P
+_L_SEND, _L_MATCH, _L_DELIVER = (
+    _trace.L_P2P_SEND, _trace.L_P2P_MATCH, _trace.L_P2P_DELIVER)
+# which way served a call IS its span's name
+_D2D, _BYREF, _STAGED, _CHUNKED = _trace.NAMES_SEND_ARR
+_INPLACE, _MOVED, _PULLED = _trace.NAMES_RECV_ARR
 
 
 class DeviceArrayPayload:
@@ -54,7 +107,10 @@ class DeviceArrayPayload:
         return int(nbytes)
 
     def __getstate__(self):
-        return {"np": np.asarray(self.arr)}
+        host = np.asarray(self.arr)
+        _pv_staged_sends.add(1)
+        _pv_staged_bytes.add(host.nbytes)
+        return {"np": host}
 
     def __setstate__(self, st) -> None:
         self.arr = st["np"]
@@ -69,8 +125,6 @@ class DeviceArrayPayload:
 # time, wired as an ordinary byte message, and h2d-placed on arrival.
 # Peak host memory on both sides is a few chunks, not the array.
 # ---------------------------------------------------------------------------
-
-from ompi_tpu.mca.params import registry as _mca
 
 _chunk_var = _mca.register(
     "btl", "tpu", "chunk_bytes", 4 * 1024 * 1024, int,
@@ -272,6 +326,7 @@ class TpuRndvEngine:
                 piece = np.ascontiguousarray(
                     np.asarray(flat[i * per:(i + 1) * per]))
                 nb = piece.nbytes
+                _pv_staged_bytes.add(nb)
                 self.staged_bytes += nb
                 self.max_staged_bytes = max(self.max_staged_bytes,
                                             self.staged_bytes)
@@ -372,17 +427,45 @@ def send_arr(comm, x, dst: int, tag: int = 0) -> None:
     from ompi_tpu.pml.request import PROC_NULL
     if dst == PROC_NULL:
         return
+    tr = comm.state.tracer
+    t0 = 0
+    if tr is not None:
+        if tr.phase:
+            tr.p2p_enter(_L_SEND)
+        t0 = tr.start_sampled(_CAT_P2P)
+    try:
+        way, nbytes, seq = _send(comm, x, dst, tag)
+        if t0:
+            tr.end(t0, way, _CAT_P2P, comm.cid, comm.rank, tag, seq,
+                   nbytes)
+    finally:
+        # a send that raises still closes its interval: the time up to
+        # the next boundary is the caller's
+        if tr is not None and tr.phase:
+            tr.p2p_return()
+
+
+def _send(comm, x, dst: int, tag: int) -> Tuple[int, int, int]:
+    """send_arr's work; (the way that served it, as its span's name,
+    the payload's bytes, the envelope's sequence number)."""
+    pml = comm.state.pml
     local, pdev = _peer_local_device(comm, dst)
     if local:
         if pdev is not None:
             x = _x64.put(x, pdev, "send_arr")
-        elif isinstance(x, np.ndarray):
+            nbytes = int(x.nbytes)
+            _pv_d2d_sends.add(1)
+            _pv_d2d_bytes.add(nbytes)
+            return _D2D, nbytes, pml.isend_obj(
+                DeviceArrayPayload(x), dst, tag, comm)
+        if isinstance(x, np.ndarray):
             # co-resident by-reference delivery: copy so the user may
             # reuse the send buffer immediately (jax arrays are
             # immutable and need no copy)
             x = x.copy()
-        comm.state.pml.isend_obj(DeviceArrayPayload(x), dst, tag, comm)
-        return
+        _pv_byref_sends.add(1)
+        payload = DeviceArrayPayload(x)
+        return _BYREF, len(payload), pml.isend_obj(payload, dst, tag, comm)
     if not hasattr(x, "nbytes") or not hasattr(x, "reshape"):
         x = np.asarray(x)  # lists/tuples: one materialization
     nbytes = int(x.nbytes)
@@ -404,11 +487,13 @@ def send_arr(comm, x, dst: int, tag: int = 0) -> None:
         xid = eng.begin_send(flat)
         hdr = _XferHdr(xid, tuple(np.shape(x)), str(dt), nbytes,
                        _chunk_var.value)
-        comm.state.pml.isend_obj(hdr, dst, tag, comm)
-        return
+        _pv_staged_sends.add(1)   # its bytes count as the chunks stage
+        return _CHUNKED, nbytes, pml.isend_obj(hdr, dst, tag, comm)
     if isinstance(x, np.ndarray):
         x = x.copy()
-    comm.state.pml.isend_obj(DeviceArrayPayload(x), dst, tag, comm)
+    # the pickle on the way out counts it (DeviceArrayPayload)
+    return _STAGED, nbytes, pml.isend_obj(
+        DeviceArrayPayload(x), dst, tag, comm)
 
 
 def recv_arr(comm, src: int, tag: int = 0):
@@ -418,18 +503,37 @@ def recv_arr(comm, src: int, tag: int = 0):
     from ompi_tpu.pml.request import PROC_NULL
     if src == PROC_NULL:
         return None
-    msg = comm.state.pml.recv_obj(src, tag, comm)
-    payload = msg.payload
-    if isinstance(payload, _XferHdr):
-        return _pull_transfer(comm, msg.src, payload)
-    if not isinstance(payload, DeviceArrayPayload):
-        raise TypeError(
-            f"recv_arr matched a non-device message (tag {tag} from "
-            f"{src}); byte messages use Recv")
-    arr = payload.arr
-    dev = comm.state.device
-    if dev is not None and getattr(arr, "device", None) != dev:
-        arr = _x64.put(arr, dev, "recv_arr")
+    tr = comm.state.tracer
+    t0 = 0
+    if tr is not None:
+        if tr.phase:
+            tr.p2p_enter(_L_MATCH)
+        t0 = tr.start_sampled(_CAT_P2P)
+    try:
+        msg = comm.state.pml.recv_obj(src, tag, comm)
+        if tr is not None and tr.phase:
+            tr.lap_to(_L_MATCH, _L_DELIVER)
+        payload = msg.payload
+        if isinstance(payload, _XferHdr):
+            arr, way = _pull_transfer(comm, msg.src, payload), _PULLED
+        elif not isinstance(payload, DeviceArrayPayload):
+            raise TypeError(
+                f"recv_arr matched a non-device message (tag {tag} from "
+                f"{src}); byte messages use Recv")
+        else:
+            arr, way = payload.arr, _INPLACE
+            dev = comm.state.device
+            if dev is not None and getattr(arr, "device", None) != dev:
+                arr, way = _x64.put(arr, dev, "recv_arr"), _MOVED
+                _pv_recv_moves.add(1)
+        if t0:
+            tr.end(t0, way, _CAT_P2P, comm.cid, msg.src, msg.tag,
+                   msg.seq, msg.total)
+    finally:
+        # a receive that raises (an ULFM error, an abort, a byte
+        # message under the tag) still closes its interval
+        if tr is not None and tr.phase:
+            tr.p2p_return()
     return arr
 
 

@@ -272,17 +272,20 @@ class PmlOb1:
                           offset).wait()
 
     # -- opaque-object channel (device payloads; btl/tpu shim) ----------
-    def isend_obj(self, obj, dst, tag, comm) -> None:
+    def isend_obj(self, obj, dst, tag, comm) -> Optional[int]:
         """Eager send of an opaque payload object: same envelope and
         sequencing as byte messages, but a DISTINCT kind (MATCH_OBJ)
         so object messages can never bind a posted byte receive (and
         byte probes never steal them).  The object rides by reference
-        through inproc and host-stages (pickle) across processes."""
+        through inproc and host-stages (pickle) across processes.
+        Returns the envelope's sequence number (the match id's last
+        part, for the sender's span)."""
         if dst == PROC_NULL:
-            return
+            return None
         gdst, ep, seq = self._envelope(dst, tag, comm)
         ep.send((MATCH_OBJ, comm.cid, comm.rank, tag, seq,
                  self.state.rank, obj))
+        return seq
 
     def poll_obj_any(self, tag):
         """Non-blocking: pop one buffered object message with ``tag``

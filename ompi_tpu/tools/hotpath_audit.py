@@ -55,6 +55,9 @@ HOT_FUNCTIONS: Dict[str, Tuple[str, ...]] = {
         "Tracer.end_at2",
         "Tracer.lap",
         "Tracer.lap_to",
+        # a device-array message's entry and return (ISSUE 34)
+        "Tracer.p2p_enter",
+        "Tracer.p2p_return",
     ),
     "ompi_tpu/pml/ob1.py": (
         "PmlOb1._trace_p2p_end",

@@ -61,7 +61,12 @@ accumulators (``LAYERS``, one ``array('q')`` of nanosecond totals per
 tracer) bank the interval between consecutive boundaries of the
 operation's host path (shim entry, deposit, meeting full, results
 published, woken, shim return, next shim entry), so a rank's
-accumulators sum to its wall time by construction.  They are
+accumulators sum to its wall time by construction.  A device-array
+message (btl/tpu ``send_arr`` / ``recv_arr``) walks the same cursor:
+entry of ``send_arr`` to its return is ``p2p_send``, entry of
+``recv_arr`` to the matched envelope ``p2p_match``, the match to the
+return ``p2p_deliver``, and ``caller`` closes at a message's entry and
+opens at its return as it does around a collective.  They are
 published process-wide as the ``trace_layer_<name>_ns`` pvars and
 ``trace_layer_rendezvous_count`` (summed over every live
 rank-thread's tracer): one pvar for each number a metric reads.
@@ -253,6 +258,17 @@ _OP_CAT_IDS = (CAT_COLL, CAT_COLL_DISPATCH, CAT_COLL_SEGMENT, CAT_PHASE)
 
 NAME_SEND = intern_name("send", ("cid", "src", "tag", "seq", "bytes"))
 NAME_RECV = intern_name("recv", ("cid", "src", "tag", "seq", "bytes"))
+# a device-array message (btl/tpu): the byte messages' schema, so the
+# match id stitches sender to receiver; the name says which way served
+# it.  send_arr: placed on the peer's own device, handed over by
+# reference (the peer owns none), pickled through the host, parked for
+# the chunked pull (every chunk through the host).  recv_arr: arrived
+# where it is wanted, had to be placed again, pulled in chunks.
+_P2P_ARGS = ("cid", "src", "tag", "seq", "bytes")
+NAMES_SEND_ARR = tuple(intern_name("send_arr_" + w, _P2P_ARGS)
+                       for w in ("d2d", "byref", "staged", "chunked"))
+NAMES_RECV_ARR = tuple(intern_name("recv_arr_" + w, _P2P_ARGS)
+                       for w in ("inplace", "moved", "chunked"))
 NAME_NBC = intern_name("nbc", ("cid", "seq"))
 # ``seq`` is the device-tier sequence (one per rendezvous); ``op`` is
 # the enclosing operation's collective sequence, the key its coll span
@@ -309,11 +325,13 @@ PHASE_LABELS = {
 # -- layer accumulators (trace_phase_enable) --------------------------------
 # Exact nanosecond totals of the intervals between the boundaries of a
 # blocking device collective's host path, banked on EVERY operation of
-# every rank (never sampled).  The first nine (LAYER_CLOSURE) partition
-# a rank's wall time: a per-tracer cursor moves from boundary to
-# boundary and each boundary banks the time since the last one, so
-# nothing is counted twice and what no boundary covers shows as the
-# difference to the caller's own clock.  assemble / launch / scatter
+# every rank (never sampled).  Those before assemble (LAYER_CLOSURE)
+# partition a rank's wall time: the collectives' nine and the three of
+# a device-array message (btl/tpu: p2p_send, p2p_match, p2p_deliver).
+# A per-tracer cursor moves from boundary to boundary and each
+# boundary banks the time since the last one, so nothing is counted
+# twice and what no boundary covers shows as the difference to the
+# caller's own clock.  assemble / launch / scatter
 # are the publisher's work INSIDE rdv_serve (banked on the triggering
 # rank's tracer) and never enter the sum.  One more slot of the same
 # array counts rendezvous (L_RENDEZVOUS).  Each has a per-layer metric
@@ -321,9 +339,11 @@ PHASE_LABELS = {
 # kept.
 LAYERS = ("entry", "rdv_slot", "rdv_skew", "rdv_serve", "rdv_wake",
           "exit", "caller", "pack", "unpack",
+          "p2p_send", "p2p_match", "p2p_deliver",
           "assemble", "launch", "scatter")
 (L_ENTRY, L_RDV_SLOT, L_RDV_SKEW, L_RDV_SERVE, L_RDV_WAKE, L_EXIT,
- L_CALLER, L_PACK, L_UNPACK, L_ASSEMBLE, L_LAUNCH, L_SCATTER,
+ L_CALLER, L_PACK, L_UNPACK, L_P2P_SEND, L_P2P_MATCH, L_P2P_DELIVER,
+ L_ASSEMBLE, L_LAUNCH, L_SCATTER,
  L_RENDEZVOUS) = range(len(LAYERS) + 1)
 #: the accumulators whose sum is a rank's whole time
 LAYER_CLOSURE = LAYERS[:L_ASSEMBLE]
@@ -731,6 +751,28 @@ class Tracer:
             self._t_cur = now
             self._cur_k = then
         return now
+
+    def p2p_enter(self, which: int, _pcns=time.perf_counter_ns) -> None:
+        """Entry of a device-array message call (btl/tpu ``send_arr``,
+        ``recv_arr``): closes the open caller interval, as a
+        collective's shim entry does, and opens ``which``."""
+        now = _pcns()
+        r = self._t_ret
+        if r:
+            self._lns[L_CALLER] += now - r
+            self._t_ret = 0
+        self._t_cur = now
+        self._cur_k = which
+
+    def p2p_return(self, _pcns=time.perf_counter_ns) -> None:
+        """Return of a device-array message call: banks the open
+        interval and opens the caller's."""
+        now = _pcns()
+        c = self._t_cur
+        if c:
+            self._lns[self._cur_k] += now - c
+            self._t_cur = 0
+        self._t_ret = now
 
     def layer_totals(self) -> Dict[str, int]:
         """{layer: ns} of this tracer, and the rendezvous count (cold)."""
@@ -1166,9 +1208,9 @@ for _i, _layer in enumerate(LAYERS):
     registry.register_pvar(
         "trace", "layer", f"{_layer}_ns",
         help=f"Nanoseconds banked in the '{_layer}' interval of "
-             "blocking device collectives, summed over every "
-             "rank-thread of the process (trace_phase_enable; "
-             "exact, every operation)",
+             "blocking device collectives and device-array messages, "
+             "summed over every rank-thread of the process "
+             "(trace_phase_enable; exact, every operation)",
         getter=_layer_sum(_i))
 registry.register_pvar(
     "trace", "layer", "rendezvous_count",

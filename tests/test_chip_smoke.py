@@ -77,6 +77,11 @@ def test_dev_mode_every_operation_served_by_a_device_module(devices,
                for _, c in ops["allreduce_sum"])
     assert ops["iallreduce_sum_x4_fused"][0][1]["fused"] > 0
     assert ops["send_arr_recv_arr_ring"][0][0] == "btl/tpu"
+    # ... as btl/tpu's own counters say: every send of every rank placed
+    # on the peer's device, none through the host, none placed again
+    ring = next(ln for ln in lines if "op=send_arr_recv_arr_ring" in ln)
+    p2p = json.loads(ring.split("p2p_counters=")[1].split()[0])
+    assert p2p == {"d2d": 4 * (devices or 8), "staged": 0, "moved": 0}
     assert ops["win_fence_put_get_accumulate"][0][0] == "DeviceWindow"
     assert "block_until_ready_waits:" in p.stdout
     assert "native_loaded=True" in p.stdout
