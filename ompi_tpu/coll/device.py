@@ -47,7 +47,7 @@ from ompi_tpu.pml.monitoring import count_offload
 from ompi_tpu.coll.tuned import TunedModule
 from ompi_tpu.datatype import device as _dtdev
 from ompi_tpu.mca.params import registry
-from ompi_tpu.op.op import MAX, MIN, PROD, SUM, Op
+from ompi_tpu.op.op import MAX, MIN, PREDEFINED, PROD, SUM, Op, jax_binary
 from ompi_tpu.runtime import x64 as _x64
 
 # trace ids as module constants: meet() runs once per device
@@ -107,6 +107,14 @@ _pv_typed_sliced = registry.register_pvar(
          "packs as static slices of the buffer, no gather "
          "(datatype/device.Typed.sliced); once a rank-call, so "
          "coll_typed_device_ops less this is the calls still gathering")
+_pv_typed_folded = registry.register_pvar(
+    "coll", "typed", "folded_first",
+    help="Typed *_arr reductions served on the device that folded the "
+         "ranks' whole buffers and packed the one result, not each "
+         "rank's buffer (datatype/device.Typed.folds_first: a fold that "
+         "rounds nowhere, a layout that skips little, coll/hbm); once a "
+         "rank-call, so coll_typed_device_ops less this is the calls "
+         "still packing once a rank")
 _pv_typed_host = registry.register_pvar(
     "coll", "typed", "host_packs",
     help="Typed *_arr collectives whose datatype the host convertor "
@@ -1088,11 +1096,14 @@ def typed_arr(comm, entry, x, op: Op, datatype, count):
         tr.coll_args = None     # a span sampled out took nothing
 
 
-def _count_typed(t) -> None:
-    """One typed rank-call served on the device with ``t``."""
+def _count_typed(t, folded: bool = False) -> None:
+    """One typed rank-call served on the device with ``t``; ``folded``:
+    by a program that folds first and packs once."""
     _pv_typed_dev.add(1)
     if t.sliced:
         _pv_typed_sliced.add(1)
+    if folded:
+        _pv_typed_folded.add(1)
 
 
 def _typed_on_device(mod, comm, kind: str, x, op: Op, datatype, count):
@@ -1411,8 +1422,10 @@ class HbmCollModule(CollModule):
     @staticmethod
     def _build_stacked(kind: str, opname: str, typed=None) -> Callable:
         """``typed`` (datatype/device.Typed, the ``extra`` of a typed
-        call) packs each rank's deposit inside the kernel, in front of
-        the same arithmetic."""
+        call) packs inside the kernel: each rank's deposit in front of
+        the same arithmetic, or, where the fold rounds nowhere and the
+        datatype skips little (``Typed.folds_first``), the one fold of
+        the whole deposits."""
         import jax
         import jax.numpy as jnp
 
@@ -1471,10 +1484,31 @@ class HbmCollModule(CollModule):
             raise KeyError(kind)
 
         if typed is not None:
-            untyped = body
+            if typed.folds_first(opname):
+                # pack(fold) and fold(pack) select the same elements of
+                # an elementwise fold: P - 1 packs fewer.  The fold is a
+                # chain over the ranks in rank order, one pass and no
+                # stacked copy of P whole buffers
+                pair = jax_binary(PREDEFINED[opname])
+                span = typed.span
 
-            def body(*s):
-                return typed.unkey(untyped(*[typed.pack(a) for a in s]))
+                def body(*s):
+                    # a deposit may be longer than the span, and each
+                    # its own length
+                    keys = [typed.keyed(a if a.shape[0] == span
+                                        else jax.lax.slice(a, (0,), (span,)))
+                            for a in s]
+                    r = typed.stream(functools.reduce(pair, keys))
+                    if kind == "reduce_scatter":
+                        m = r.shape[0] // len(s)
+                        r = tuple(jax.lax.slice(r, (i * m,), ((i + 1) * m,))
+                                  for i in range(len(s)))
+                    return typed.unkey(r)
+            else:
+                untyped = body
+
+                def body(*s):
+                    return typed.unkey(untyped(*[typed.pack(a) for a in s]))
 
             # a stable program name for the device trace
             body.__name__ = body.__qualname__ = "ompi_typed_" + kind
@@ -1522,7 +1556,7 @@ class HbmCollModule(CollModule):
         if t is None:
             return self.fallback.typed(comm, kind, x, op, datatype, count)
         out = self._run(comm, kind, op.name, x.reshape(-1), t)
-        _count_typed(t)
+        _count_typed(t, t.folds_first(op.name))
         return out
 
     def _meet(self, comm, x, fn, ck=None):

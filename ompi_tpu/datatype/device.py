@@ -51,6 +51,15 @@ _BITS = np.dtype(np.uint64)
 _SIGN = 1 << 63
 #: the reductions a bit-pattern carrier serves on the device
 BITS_OPS = frozenset(("MPI_MAX", "MPI_MIN"))
+#: the reductions that round in a float type: the bits of such a fold
+#: depend on how it is associated, so it is never taken in another shape
+_ROUNDING_OPS = frozenset(("MPI_SUM", "MPI_PROD"))
+#: R: the most a datatype may address, in packed streams, and still
+#: have its P buffers folded whole before ONE pack (``Typed.
+#: folds_first``).  Folding first reads ``span / elems`` times the bytes
+#: to save P - 1 packs; set from the chip (PERF.md section 5 has a
+#: reading on each side)
+FOLD_FIRST_SPAN = 8
 
 
 def label(datatype: Datatype) -> str:
@@ -246,9 +255,29 @@ class Typed:
         parts = [_slice_run(flat, *run) for run in self.layout]
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
+    def keyed(self, arr):
+        """What the reduction compares: order keys of a bit-pattern
+        carrier, any other buffer as it is."""
+        return order_key(arr) if self.bits else arr
+
     def pack(self, arr):
-        out = self.stream(arr)
-        return order_key(out) if self.bits else out
+        return self.keyed(self.stream(arr))
+
+    def folds_first(self, opname: str) -> bool:
+        """Whether a stacked reduction of P buffers folds them whole,
+        gaps included, and packs the one result, instead of packing P
+        times and folding the streams.  A pack selects elements and
+        these folds are elementwise, so the answers are the same values
+        wherever the fold does not round: MAX, MIN, the bitwise and
+        logical operations on any type, SUM and PROD on integers (a
+        float SUM keeps the association it has).  Not for one block,
+        whose pack is a plain slice and costs nothing; not where the
+        datatype skips so much that folding the gaps costs more than
+        the packs saved (``FOLD_FIRST_SPAN``).  Read from the call
+        alone, so every rank reaches the same verdict."""
+        return (opname not in _ROUNDING_OPS or self.dtype.kind in "iub") \
+            and (len(self.layout) > 1 or self.layout[0][1] > 1) \
+            and self.span <= FOLD_FIRST_SPAN * self.elems
 
     def unkey(self, out):
         """What the reduction of packed streams returned (an array or

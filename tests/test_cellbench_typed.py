@@ -211,6 +211,7 @@ def test_a_typed_call_is_one_rendezvous_and_one_program():
         comm.Barrier()
         builds, gen = coll_device.compile_cache.builds, rv.gen
         before = {n: pvar(n) for n in ("coll_typed_device_ops",
+                                       "coll_typed_folded_first",
                                        "coll_typed_host_packs",
                                        "coll_arr_host_staged_collectives")}
         comm.Barrier()
@@ -226,7 +227,10 @@ def test_a_typed_call_is_one_rendezvous_and_one_program():
     assert len(launches) == calls            # one publisher a call
     for builds, meetings, moved in res:
         assert builds == 0 and meetings == calls
+        # a MAX through the cell's vector folds first and packs once
+        # (ISSUE 36): still one meeting and one program
         assert moved == {"coll_typed_device_ops": calls * n,
+                         "coll_typed_folded_first": calls * n,
                          "coll_typed_host_packs": 0,
                          "coll_arr_host_staged_collectives": 0}
 
@@ -798,13 +802,46 @@ def test_typed_roofline_reader():
                                said.append) is None
 
 
+@pytest.mark.parametrize("name,pvar_name", [
+    ("typed_folded_per_iter", "coll_typed_folded_first"),
+    ("typed_sliced_per_iter", "coll_typed_sliced_packs")])
+def test_typed_counter_metrics_read_through_pvar_sum(name, pvar_name):
+    """A data-only metric: the counter's delta over the window per
+    rank-iteration; 1.0 where it moved once a rank-call, nothing (never
+    0) on a program that has no such variable (the parent's)."""
+    from cellbench.readers import pvar_sum
+    spec = manifest.metric_spec(name)
+    assert spec["reader"] == "pvar_sum" and spec["pvars"] == [pvar_name]
+    assert spec["per"] == "rank_iteration" and spec["moves"] == "iter_us"
+    assert spec["workloads"] == [CELL]
+    entry = next(m for m in manifest.manifest(REPO)["per_layer"]
+                 if m["name"] == name)
+    for k in ("unit", "better", "source", "layer", "moves", "workloads"):
+        assert spec[k] == entry[k], k
+    assert name in {m["name"] for m in manifest.cell(CELL, REPO)["per_layer"]}
+    facts = {"iters": 499, "ranks": 8,
+             "pvars_before": {pvar_name: 24, "coll_typed_device_ops": 24},
+             "pvars_after": {pvar_name: 24 + 499 * 8,
+                             "coll_typed_device_ops": 24 + 499 * 8}}
+    said = []
+    assert pvar_sum.read(spec, facts, said.append) == 1.0
+    assert pvar_name in said[0]
+    older = {k: {"coll_typed_device_ops": v["coll_typed_device_ops"]}
+             for k, v in facts.items() if k.startswith("pvars_")}
+    assert pvar_sum.read(spec, {**facts, **older}, said.append) is None
+    # a library that has the variable and served pack-first reads 0.0
+    still = {**facts, "pvars_after": {**facts["pvars_after"],
+                                      pvar_name: 24}}
+    assert pvar_sum.read(spec, still, said.append) == 0.0
+
+
 def test_the_cell_in_the_manifest():
     spec = manifest.cell(CELL, REPO)
     assert [m["name"] for m in spec["end_to_end"]] == [
         "setup_s", "iter_us", "iter_p95_us"]
     due = {m["name"] for m in spec["per_layer"]}
-    assert {"typed_roofline", "typed_ops_per_iter", "kernel_us",
-            "rdv_per_iter", "pack_unpack_per_iter_us",
+    assert {"typed_roofline", "typed_ops_per_iter", "typed_folded_per_iter",
+            "kernel_us", "rdv_per_iter", "pack_unpack_per_iter_us",
             "assemble_scatter_us", "device_idle_pct"} <= due
     assert not due & {"collective_roofline", "move_roofline",
                       "segments_per_iter", "inflight_segments",
