@@ -304,6 +304,18 @@ def _allreduce_sum(comm, x):
 
 
 def _closure_world(n_ops, make_x, knobs, call=_allreduce_sum, **kw):
+    """Per rank (wall ns of the loop, the layer accumulators' deltas
+    over it).
+
+    ``wall`` is stamped at the closing Barrier's ENTRY, where the
+    account ends: a host collective closes the open caller interval at
+    its entry, banks nothing else and opens none.  Stamped after the
+    Barrier (as it was until ISSUE 37) the closure came out short by
+    the barrier itself, a constant 6.4 to 7.9 ms whatever the loop
+    took on the four-device plan path (63.0 of 69.5 ms, 64.4 of 72.3,
+    83.4 of 90.3, 70.1 of 77.3): the Barrier read 7.30 and 7.44 ms on
+    two ranks and 0.047 ms on the last to arrive.  The account was
+    right; the test measured a barrier the account leaves out."""
     def fn(comm):
         tr = comm.state.tracer
         x = make_x(comm)
@@ -314,8 +326,8 @@ def _closure_world(n_ops, make_x, knobs, call=_allreduce_sum, **kw):
         t0 = time.perf_counter_ns()
         for _ in range(n_ops):
             jax.block_until_ready(call(comm, x))
-        comm.Barrier()    # its entry closes the last caller interval
         wall = time.perf_counter_ns() - t0
+        comm.Barrier()    # its entry closes the last caller interval
         after = tr.layer_totals()
         return wall, {k: after[k] - before[k] for k in after}
 
