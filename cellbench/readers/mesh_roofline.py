@@ -1,14 +1,11 @@
-"""Reader: a data-movement collective's share of its roofline across
-chips.  The least time a chip could take for the operation's REQUIRED
-bytes (cellbench/bytes_mesh.py, by operation, ranks and size, whatever
-implements it) against cellbench/peaks.json, over the device time per
-iteration of the programs the cell's ``kernel_events`` name, on the
-fullest device (cellbench/tracered.py's ``kernel_s_per_iter``).
-
-Never clipped: a share over 100% is a wrong count.  Where the trace
-shows no device plane, or no program matched (a program that does not
-name its exchange programs so), the reader returns nothing; it never
-returns 0.
+"""NOT a reader of any metric since PR 38: ``move_roofline`` is
+``collective_roofline`` now (readers/profiler_trace.py over the one
+table, cellbench/bytes.py).  The file stays only because
+``tests/test_cellbench_move.py`` imports it and a ``benchmark`` PR may
+edit no file outside ``cellbench/``; the first PR that may edit that
+test deletes it with ``cellbench/bytes_mesh.py`` (PERF.md section 7).
+The same share, the same way: never clipped, nothing where no program
+matched, never 0.
 """
 from cellbench import bytes_mesh
 

@@ -1,15 +1,11 @@
-"""Reader: a typed reduction's share of its roofline on one chip.  The
-least time the chip could take for the operation's REQUIRED bytes
-(cellbench/bytes_typed.py: by operation, ranks and packed size,
-whatever implements it) against cellbench/peaks.json, over the device
-time per iteration of the programs the cell's ``kernel_events`` name
-(cellbench/tracered.py's ``kernel_s_per_iter``: the typed program, pack
-included, since the pack is inside it).
-
-Never clipped: a share over 100% is a wrong count.  Where the trace
-shows no device plane, or no program matched (a library that has no
-typed program, or names it otherwise), the reader returns nothing; it
-never returns 0.
+"""NOT a reader of any metric since PR 38: ``typed_roofline`` is
+``collective_roofline`` now (readers/profiler_trace.py over the one
+table, cellbench/bytes.py).  The file stays only because
+``tests/test_cellbench_typed.py`` imports it and a ``benchmark`` PR may
+edit no file outside ``cellbench/``; the first PR that may edit that
+test deletes it with ``cellbench/bytes_typed.py`` (PERF.md section 7).
+The same share, the same way: never clipped, nothing where no program
+matched, never 0.
 """
 from cellbench import bytes_typed
 

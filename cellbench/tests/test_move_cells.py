@@ -2,7 +2,7 @@
 ``python -m pytest cellbench/tests``).
 
 * ``--allow-cpu --tiny`` runs each new cell end to end, timed and
-  traced, on four virtual devices through the segmented tier, labels
+  traced, on four virtual devices through the large-message tier, labels
   every line and puts no number under a device metric's name;
 * the lower-precision control comes out NOT correct in both;
 * ``blocking_rooted``, driven with the timed path broken underneath,
@@ -69,15 +69,14 @@ def test_dev_mode_runs_a_new_cell_and_labels_it(cell, trace):
         assert set(got) == {"dev_setup_s", "dev_iter_us"}
         return
     # no device plane on the CPU: the device metrics are left out,
-    # never reported as 0; the program's counters are all there
-    assert not {"dev_kernel_us", "dev_move_roofline",
+    # never reported as 0; the program's counters are all there.  One
+    # rendezvous and one whole-payload program a call since PR 29, and
+    # nothing packs on the host (a whole number of segments)
+    assert not {"dev_kernel_us", "dev_collective_roofline",
                 "dev_device_idle_pct"} & set(got)
-    segments = 64 if cell.startswith("bcast") else 16
-    assert got["dev_segments_per_iter"]["value"] == segments
-    assert got["dev_rdv_per_iter"]["value"] == segments
-    assert 1.0 <= got["dev_inflight_segments"]["value"] <= 3.0
-    assert got["dev_pack_unpack_per_iter_us"]["value"] > 0
-    assert got["dev_pack_unpack_us"]["value"] > 0
+    assert got["dev_rdv_per_iter"]["value"] == 1
+    assert got["dev_pack_unpack_per_iter_us"]["value"] == 0
+    assert got["dev_serve_us"]["value"] > got["dev_launch_us"]["value"] > 0
     assert abs(got["dev_unaccounted_us"]["value"]) \
         < 0.03 * got["dev_traced_iter_us"]["value"]
 
