@@ -2,41 +2,53 @@
 program and ONE rendezvous per large-message collective.
 
 Every operation that ``pipeline.maybe_device_coll`` routes to the
-large-message tier runs here.  For each (alg, mesh, segment geometry,
-dtype, op) the plan compiler builds ONE jitted program covering the
-whole payload — the full reduce-scatter + allgather ring (segring) or
-the recursive-doubling exchange (segrd) as a single shard_map with
-buffer donation, the stacked one-chip kernel (hbm), or a data mover
-(segbcast / sega2a) — and binds it into a ``Plan`` holding the
-prebuilt sharding, the meet-fn closure and the pad identity.
-Executing a plan is pure data motion:
+large-message tier runs here.  For each (alg, mesh, length, dtype, op)
+the plan compiler builds ONE jitted program covering the whole
+payload — the full reduce-scatter + allgather ring (segring) or the
+recursive-doubling exchange (segrd) as a single shard_map, the
+stacked one-chip kernel (hbm), or a data mover (segbcast / sega2a) —
+and binds it into a ``Plan`` holding the prebuilt sharding and the
+meet-fn closure.  Executing a plan is ONE ``device.meet`` (the ULFM
+abort check rides the meet, so fault handling sits at the plan
+boundary) plus pvar/trace accounting: no pack, no unpack, no dispatch
+beside the one program.
 
-    pack (identity-pad to the plan's fixed shape, zero-copy staging
-    bypass where the runtime aliases aligned host buffers)
-      -> ONE ``device.meet`` (the ULFM abort check rides the meet, so
-         fault handling sits at the plan boundary)
-      -> unpack (trim) + pvar/trace accounting.
+**A plan runs at the payload's own length.**  None of these programs
+needs a segment geometry: the one-chip kernel, the native
+psum / pmax / pmin and recursive doubling are elementwise over the
+whole vector, an alltoall's blocks are equal by MPI's definition, and
+the stripe schedules (the hop-explicit ring, segbcast) take one
+segment of ``n`` with stripes of ``n // size``.  So the plan key and
+the compile key carry the length, as ``HbmCollModule._run``'s key
+carries the shape of everything under the tier's crossover, and a new
+length compiles a new program (0.6 to 1.1 s on a v5e, PERF.md): the
+per-comm plan LRU (``coll_plan_cache_max``) and the process-wide
+compile cache (``coll_device_cache_max``) bound what is HELD, nothing
+bounds the keys a sweep of sizes can ask for.  Jobs whose sizes come
+from a model repeat a handful.  ``coll_pipeline_segments`` still
+advances by the segment count of every planned allreduce
+(``_plan_segments``), a count and no longer a shape.
 
-**Segment-size discipline**: a payload is padded (op identity
-elements; sliced off at unpack) to a whole number of fixed per-host
-segments (``segment_elems``: ``coll_seg_size``, or the calibrated
-size), so the compiled programs are keyed by segment COUNT, never by
-message size, and a sweep of message sizes cannot blow the bounded
-cache.  Sub-segment payloads quantize the plan shape to the next pow2
-(multiple of comm size).  ``coll_pipeline_segments`` advances by the
-segment count of every planned allreduce.
+**What is left of pad and trim**: a stripe schedule whose payload
+does not divide by the comm size (segring with
+``coll_plan_native_reduce`` 0 or a non-native op, segbcast) is padded
+to the next multiple of ``comm.size`` with the op's identity by ONE
+jitted program (``ompi_plan_pad``) and trimmed by one
+(``ompi_plan_trim``), around the plan's execute.  ``coll_plan_padded``
+counts those calls; the layer account books them as pack / unpack.
+The same code runs on every backend.
 
 Keying and lifetime:
 
 * jitted executables live in the process-wide ``device.compile_cache``
-  under ``("plan_<alg>", dev_key, geometry, dtype, op, donate)`` —
+  under ``("plan_<alg>", dev_key, (total,), dtype, op, donate)`` —
   dev_key is a top-level element, so ``drop_mesh`` on device loss and
   shrink epochs evicts exactly the stale-mesh programs.
 * resolved ``Plan`` objects live per comm in ``comm._coll_plans``
   (bounded LRU, ``coll_plan_cache_max``), purged by ULFM's
   ``_COMM_CACHE_KEYS`` at shrink/respawn epochs and by
   ``SELECTION_CACHE_KEYS`` when an autotune fold moves the calibrated
-  segment size out from under the plan geometry.
+  segment size (a plan holds its segment count).
 
 Reduce lowering: with ``coll_plan_native_reduce`` (default), plans
 for SUM/MAX/MIN lower to the runtime's native cross-replica reduction
@@ -44,13 +56,13 @@ for SUM/MAX/MIN lower to the runtime's native cross-replica reduction
 path's bcast-as-masked-psum — because a compiler-scheduled fused
 reduction beats a hop-explicit schedule wherever the runtime provides
 one.  Other ops, and all ops with the knob off, keep the faithful
-batched schedule, which real multi-slice topologies may prefer:
+schedule, which real multi-slice topologies may prefer:
 
-* **segring** — chunked ``ppermute`` ring allreduce: P-1
-  reduce-scatter steps (each rank accumulates one stripe per hop) then
-  P-1 allgather steps.  Per-chunk accumulation is a rank-ordered left
-  fold computed by exactly ONE rank and circulated verbatim, so every
-  rank's output is byte identical by construction.
+* **segring** — ``ppermute`` ring allreduce: P-1 reduce-scatter steps
+  (each rank accumulates one stripe per hop) then P-1 allgather
+  steps.  Per-stripe accumulation is a rank-ordered left fold computed
+  by exactly ONE rank and circulated verbatim, so every rank's output
+  is byte identical by construction.
 * **segrd** — recursive doubling (power-of-two comms): log2(P)
   exchange rounds; both operand orders are computed and selected by
   rank parity (the MPICH operand-order discipline), so all ranks
@@ -71,6 +83,7 @@ from collections import OrderedDict
 import functools
 import time
 from typing import Any, Callable, Optional
+import warnings
 
 import numpy as np
 
@@ -79,7 +92,12 @@ from ompi_tpu import trace as _trace
 from ompi_tpu.coll import device as _dev
 from ompi_tpu.obs import integrity as _ig
 from ompi_tpu.mca.params import registry
-from ompi_tpu.runtime import staging as _staging
+
+# a padded plan donates the buffer its pad program made; donation is
+# a no-op on the CPU backend, and the warning would fire once a
+# compiled program in every tier-1 run
+warnings.filterwarnings(
+    "ignore", message="Some donated buffers were not usable")
 
 _CAT_SEG = _trace.CAT_COLL_SEGMENT
 _CAT_PHASE = _trace.CAT_PHASE
@@ -93,11 +111,12 @@ _L_UNPACK = _trace.L_UNPACK
 
 _seg_size_var = registry.register(
     "coll", "seg", "size", 1 << 20, int,
-    help="Segment size (bytes) for the segmented/pipelined large-"
-         "message device algorithms (ref: "
-         "coll_tuned_decision_fixed.c:72).  Rounded up so ring "
-         "stripes stay equal; coll_tuned_use_measured_rules replaces "
-         "this with the calibrated per-host segment size")
+    help="Segment size (bytes) of the large-message device tier (ref: "
+         "coll_tuned_decision_fixed.c:72): what coll_pipeline_segments "
+         "counts a planned allreduce in, and osc/device's default "
+         "segment.  No program has its shape: a plan runs at the "
+         "payload's own length.  coll_tuned_use_measured_rules "
+         "replaces this with the calibrated per-host segment size")
 
 _cache_max_var = registry.register(
     "coll", "plan", "cache_max", 32, int,
@@ -109,8 +128,8 @@ _native_var = registry.register(
     "coll", "plan", "native_reduce", True, bool,
     help="Lower plan reduce phases for SUM/MAX/MIN to the runtime's "
          "native cross-replica reduction (psum/pmax/pmin); 0 keeps "
-         "the hop-explicit batched ring / recursive-doubling "
-         "schedule for every op")
+         "the hop-explicit ring / recursive-doubling schedule for "
+         "every op")
 
 pv_segments = registry.register_pvar(
     "coll", "pipeline", "segments",
@@ -126,7 +145,13 @@ pv_hits = _obs.scoped_pvar(
 pv_exec_us = _obs.scoped_pvar(
     "coll", "plan", "exec_us",
     help="cumulative wall microseconds inside plan execution "
-         "(pack + rendezvous + unpack)")
+         "(the rendezvous and the one program)")
+pv_padded = registry.register_pvar(
+    "coll", "plan", "padded",
+    help="Planned collectives that padded and trimmed their payload: "
+         "a stripe schedule (hop-explicit segring, segbcast) over a "
+         "length that does not divide by the comm size.  Every other "
+         "plan runs at the payload's own length")
 
 #: ops with a native cross-replica lowering in the runtime
 _NATIVE_OPS = frozenset(("MPI_SUM", "MPI_MAX", "MPI_MIN"))
@@ -165,8 +190,8 @@ def _binop(opname: str) -> Callable:
 
 
 def _pad_value(opname: Optional[str], dtype) -> Any:
-    """Identity element of the op — a ragged payload is padded with it
-    so every size hits a compiled shape keyed by segment count and the
+    """Identity element of the op: what ``ompi_plan_pad`` appends to a
+    payload that a stripe schedule cannot split evenly, so that the
     padding cannot perturb real elements."""
     dt = np.dtype(dtype)
     if opname in ("MPI_MAX",):
@@ -179,13 +204,13 @@ def _pad_value(opname: Optional[str], dtype) -> Any:
         return dt.type(1)
     if opname == "MPI_BAND":
         return dt.type(~dt.type(0)) if dt.kind in "iu" else dt.type(1)
-    # SUM, OR/XOR families, and data-movement kinds (bcast/alltoall)
+    # SUM, OR/XOR families, and data-movement kinds (bcast)
     return dt.type(0)
 
 
 def segment_elems(comm, itemsize: int) -> int:
     """Per-host segment size in elements, rounded UP to a multiple of
-    the comm size so ring stripes and alltoall blocks stay equal."""
+    the comm size."""
     from ompi_tpu.coll import calibrate
     seg_bytes = calibrate.segment_bytes(comm.size, _seg_size_var.value)
     elems = max(comm.size, seg_bytes // max(1, itemsize))
@@ -193,55 +218,52 @@ def segment_elems(comm, itemsize: int) -> int:
     return elems + (comm.size - rem) if rem else elems
 
 
-def _plan_segments(comm, n: int, seg: int):
-    """(nsegs, seg_elems) for an n-element payload.  Payloads below
-    one calibrated segment quantize to the next pow2 (rounded to a
-    comm-size multiple) so a 64 KiB message is not identity-padded to
-    a 1 MiB program; at or above, the calibrated segment is the unit.
-    Either way the key set stays log-bounded in payload size."""
-    size = comm.size
-    if n < seg:
-        s = 1
-        while s < n:
-            s <<= 1
-        rem = s % size
-        if rem:
-            s += size - rem
-        return 1, min(s, seg)
-    return -(-n // seg), seg
+def _plan_segments(n: int, seg: int) -> int:
+    """How many ``seg``-element segments an n-element payload covers:
+    what ``coll_pipeline_segments`` advances by for a planned
+    allreduce.  A count only: no program has a segment's shape (a plan
+    runs at the payload's own length), so this bounds no key set."""
+    return max(1, -(-n // seg))
+
+
+def _stripe_total(n: int, size: int) -> int:
+    """The length a stripe schedule runs at: ``n`` where it divides by
+    the comm size, else the next multiple (padded and trimmed)."""
+    return -(-n // size) * size
 
 
 class Plan:
     """One resolved collective plan: the prebound meet-fn (prebuilt
-    sharding + jitted whole-schedule program + scatter), the pad
-    identity, this rank's deposit device and the interned ids the
-    executor stamps into spans.  Everything per-op-variable is an
+    sharding + jitted whole-schedule program + scatter) and the
+    interned ids the executor stamps into spans.  The program runs at
+    the payload's own length, but for a stripe schedule over a length
+    that does not divide by the comm size, whose plan also holds the
+    two jitted programs that pad to the next multiple and trim back
+    (``pad`` / ``trim``, else None).  Everything per-op-variable is an
     ``execute`` argument; everything else was decided at build."""
 
-    __slots__ = ("alg", "alg_id", "nsegs", "seg", "total", "itemsize",
-                 "np_dtype", "pad_val", "fn", "meet", "device", "ck")
+    __slots__ = ("alg", "alg_id", "nsegs", "itemsize", "fn", "meet",
+                 "ck", "pad", "trim")
 
-    def __init__(self, alg: str, nsegs: int, seg: int, np_dtype,
-                 pad_val, fn, meet, device, ck=None) -> None:
+    def __init__(self, alg: str, nsegs: int, np_dtype, fn, meet,
+                 ck=None, pad=None, trim=None) -> None:
         self.alg = alg
         self.alg_id = _ALG_ID[alg]
         self.nsegs = nsegs
-        self.seg = seg
-        self.total = nsegs * seg
         self.itemsize = np_dtype.itemsize
-        self.np_dtype = np_dtype
-        self.pad_val = pad_val
         self.fn = fn
         self.meet = meet
-        self.device = device
         # integrity spec, built unconditionally (plans outlive
         # arm/disarm); execute() re-gates on the live arm flag
         self.ck = ck
+        self.pad = pad
+        self.trim = trim
 
     def execute(self, module, comm, flat, n: int):
-        """The whole steady-state op.  Hot (once per large-message
-        collective): audited by hotpath_audit — pack/unpack and all
-        key/closure work live off this path."""
+        """The whole steady-state op: ``flat`` is what the program
+        takes, ``n`` the payload's elements (the span's bytes).  Hot
+        (once per large-message collective): audited by hotpath_audit
+        — all key/closure work lives off this path."""
         tr = comm.state.tracer
         t0 = 0
         if tr is not None:
@@ -255,13 +277,8 @@ class Plan:
             else:
                 t0 = tr.start()
         ns0 = time.perf_counter_ns()
-        value = flat
-        if n != self.total:
-            value = _pack(comm, flat, n, self)
-        out = self.meet(comm, value, self.fn, module._abort_check(comm),
+        out = self.meet(comm, flat, self.fn, module._abort_check(comm),
                         self.ck if _ig.on else None)
-        if n != self.total:
-            out = _unpack(comm, out, n, self)
         pv_exec_us.add((time.perf_counter_ns() - ns0) // 1000,
                        _obs.current_band())
         if t0:
@@ -271,30 +288,57 @@ class Plan:
         return out
 
 
-def _pack(comm, flat, n: int, plan: Plan):
-    """Identity-pad ``flat`` (n,) to the plan's fixed (total,) shape.
-    On a zero-copy runtime this is ONE memcpy into a fresh aligned
-    host buffer that device_put then aliases — no device program, and
-    fresh per op because the padded array may still back an unforced
-    program when the next op starts (unlike osc's lock-serialized
-    mirror reuse).  Copying runtimes compose on device."""
+def _compile_pad_trim(n: int, total: int, np_dtype, opname):
+    """The two programs of a padded plan, each ONE jitted function
+    with a name the device trace shows (``jit_ompi_plan_pad``,
+    ``jit_ompi_plan_trim``; never a lambda: the benchmark's hbm
+    allreduce cells count every ``jit__lambda`` as the kernel)."""
+    import jax
+    from jax import lax
+
+    pad_val = _pad_value(opname, np_dtype)
+
+    def ompi_plan_pad(x):
+        return lax.pad(x, pad_val, ((0, total - n, 0),))
+
+    def ompi_plan_trim(out):
+        return lax.slice(out, (0,), (n,))
+
+    return jax.jit(ompi_plan_pad), jax.jit(ompi_plan_trim)
+
+
+def _pad_trim(n: int, total: int, np_dtype, opname):
+    """(pad, trim) for a plan over ``total`` elements serving payloads
+    of ``n``: (None, None) at the payload's own length."""
+    if total == n:
+        return None, None
+    return _dev.compile_cache.get(
+        ("plan_pad_trim", n, total, np_dtype.str, opname),
+        lambda: _compile_pad_trim(n, total, np_dtype, opname))
+
+
+def _execute(plan: Plan, module, comm, flat, n: int):
+    """``plan.execute``, and around it, for the payloads that still
+    pad, one jitted pad of the rank's own deposit before the
+    rendezvous and one jitted trim of its own result after.  Booked as
+    pack / unpack where the phase profiler is armed."""
+    if plan.pad is None:
+        return plan.execute(module, comm, flat, n)
+    pv_padded.add(1)
     tr = comm.state.tracer
-    t0 = tr.lap() if tr is not None and tr.phase else 0
-    if _staging.runtime_zero_copy():
-        import jax
-        buf = _staging.aligned_empty(plan.total * plan.itemsize)
-        view = buf.view(plan.np_dtype)
-        np.copyto(view[:n], np.asarray(flat))
-        view[n:] = plan.pad_val
-        value = jax.device_put(view, plan.device)
-    else:
-        import jax.numpy as jnp
-        value = jnp.concatenate(
-            [jnp.asarray(flat),
-             jnp.full((plan.total - n,), plan.pad_val, plan.np_dtype)])
+    if tr is not None and not tr.phase:
+        tr = None
+    nbytes = n * plan.itemsize
+    t0 = tr.lap() if tr is not None else 0
+    value = plan.pad(module._deposit(comm, flat))
     if t0:
-        _pack_end(tr, comm, t0, n * plan.itemsize)
-    return value
+        _pack_end(tr, comm, t0, nbytes)
+    out = plan.execute(module, comm, value, n)
+    t0 = tr.lap() if tr is not None else 0
+    out = plan.trim(out)
+    if t0:
+        _unpack_end(tr, comm, t0, nbytes)
+    return out
 
 
 def _pack_end(tr, comm, t0: int, nbytes: int) -> None:
@@ -316,43 +360,6 @@ def _unpack_end(tr, comm, t0: int, nbytes: int) -> None:
     if tr.kept(_CAT_PHASE, seq):
         tr.end_at(t0, t1, _NAME_PH_UNPACK, _CAT_PHASE, comm.cid, seq,
                   nbytes)
-
-
-def _unpack(comm, out, n: int, plan: Plan):
-    tr = comm.state.tracer
-    t0 = tr.lap() if tr is not None and tr.phase else 0
-    res = out[:n]
-    if t0:
-        _unpack_end(tr, comm, t0, n * plan.itemsize)
-    return res
-
-
-def _pack_rows(comm, flat, n: int, plan: Plan):
-    """``_pack`` for an alltoall: ``flat`` is comm.size destination
-    blocks of n // size elements, and each block is zero-padded at its
-    own end to the plan's total // size (a pad at the vector's end
-    would shift every block but the first)."""
-    tr = comm.state.tracer
-    t0 = tr.lap() if tr is not None and tr.phase else 0
-    import jax.numpy as jnp
-    size = comm.size
-    rows = jnp.asarray(flat).reshape(size, n // size)
-    value = jnp.pad(
-        rows, ((0, 0), (0, (plan.total - n) // size))).reshape(-1)
-    if t0:
-        _pack_end(tr, comm, t0, n * plan.itemsize)
-    return value
-
-
-def _unpack_rows(comm, out, n: int, plan: Plan):
-    """``_unpack`` for an alltoall: trim each source block's pad."""
-    tr = comm.state.tracer
-    t0 = tr.lap() if tr is not None and tr.phase else 0
-    size = comm.size
-    res = out.reshape(size, plan.total // size)[:, :n // size].reshape(-1)
-    if t0:
-        _unpack_end(tr, comm, t0, n * plan.itemsize)
-    return res
 
 
 def _plans_of(comm) -> OrderedDict:
@@ -385,10 +392,10 @@ def _resolve(comm, pkey, builder) -> Plan:
 
 # -- mesh plans -------------------------------------------------------------
 
-def _compile_mesh(alg: str, mesh, size: int, nsegs: int, seg: int,
-                  np_dtype, opname: str, native: bool, donate: bool):
-    """The ONE jitted program covering the whole multi-segment
-    schedule: global (size*nsegs*seg,) in P("r"), replicated out."""
+def _compile_mesh(alg: str, mesh, size: int, total: int, opname: str,
+                  native: bool, donate: bool):
+    """The ONE jitted program covering the whole schedule: global
+    (size*total,) in P("r"), replicated out."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -403,36 +410,34 @@ def _compile_mesh(alg: str, mesh, size: int, nsegs: int, seg: int,
         else:
             body = lambda x: lax.pmin(x, "r")  # noqa: E731
     elif alg == "segring":
-        # the full reduce-scatter + allgather ring, batched over the
-        # leading nsegs axis
+        # the full reduce-scatter + allgather ring over one segment of
+        # `total`: size stripes of m, sliced out of the 1-D payload
         ring = [(j, (j + 1) % size) for j in range(size)]
-        m = seg // size
+        m = total // size
 
         def body(x):
             i = lax.axis_index("r")
-            stripes = x.reshape(nsegs, size, m)
 
             def stripe(idx):
-                return lax.dynamic_slice_in_dim(
-                    stripes, idx, 1, axis=1)[:, 0]
+                return lax.dynamic_slice_in_dim(x, idx * m, m)
 
             acc = stripe(i)
             for t in range(size - 1):
                 acc = lax.ppermute(acc, "r", perm=ring)
                 acc = binop(acc, stripe((i - t - 1) % size))
             # rank i now owns fully-reduced stripe (i+1) % size
-            out = jnp.zeros((nsegs, size, m), x.dtype)
             out = lax.dynamic_update_slice_in_dim(
-                out, acc[:, None], (i + 1) % size, axis=1)
+                jnp.zeros((total,), x.dtype), acc,
+                ((i + 1) % size) * m, axis=0)
             cur = acc
             for t in range(size - 1):
                 cur = lax.ppermute(cur, "r", perm=ring)
                 out = lax.dynamic_update_slice_in_dim(
-                    out, cur[:, None], (i - t) % size, axis=1)
-            return out.reshape(nsegs * seg)
+                    out, cur, ((i - t) % size) * m, axis=0)
+            return out
     else:
-        # recursive doubling over the whole padded vector — the
-        # schedule is elementwise, so batching over segments is free
+        # recursive doubling over the whole vector: the schedule is
+        # elementwise, so any length serves
         def body(x):
             i = lax.axis_index("r")
             acc = x
@@ -453,30 +458,24 @@ def _compile_mesh(alg: str, mesh, size: int, nsegs: int, seg: int,
     return jax.jit(fn)
 
 
-def _build_mesh_plan(comm, alg: str, nsegs: int, seg: int, np_dtype,
-                     opname: str, donate: bool) -> Plan:
+def _build_mesh_plan(comm, alg: str, n: int, total: int, np_dtype,
+                     opname: str, native: bool, donate: bool) -> Plan:
     mesh = comm.mesh()
     size = comm.size
-    devs = list(mesh.devices.reshape(-1))
-    dev_key = tuple(d.id for d in devs)
-    native = bool(_native_var.value) and opname in _NATIVE_OPS
+    dev_key = tuple(d.id for d in mesh.devices.reshape(-1))
     # native programs are alg-independent — one compile serves both
-    # segring and segrd picks for the same geometry
-    if native:
-        ckey = ("plan_native", dev_key, (nsegs * seg,), np_dtype.str,
-                opname, donate)
-    else:
-        ckey = ("plan_" + alg, dev_key, (nsegs, seg), np_dtype.str,
-                opname, donate)
+    # segring and segrd picks for the same length
+    ckey = ("plan_native" if native else "plan_" + alg, dev_key,
+            (total,), np_dtype.str, opname, donate)
     jfn = _dev.compile_cache.get(
-        ckey, lambda: _compile_mesh(alg, mesh, size, nsegs, seg,
-                                    np_dtype, opname, native, donate))
-    return Plan(alg, nsegs, seg, np_dtype,
-                _pad_value(opname, np_dtype),
+        ckey, lambda: _compile_mesh(alg, mesh, size, total, opname,
+                                    native, donate))
+    nsegs = _plan_segments(n, segment_elems(comm, np_dtype.itemsize))
+    return Plan(alg, nsegs, np_dtype,
                 _mesh_meet_fn(mesh, size, jfn), _dev.meet,
-                devs[comm.rank],
                 _ig.spec_static("allreduce", opname,
-                                np.empty(0, np_dtype)))
+                                np.empty(0, np_dtype)),
+                *_pad_trim(n, total, np_dtype, opname))
 
 
 def _mesh_meet_fn(mesh, size: int, jfn):
@@ -496,37 +495,43 @@ def _mesh_meet_fn(mesh, size: int, jfn):
     return fn
 
 
-def mesh_reduce(module, comm, x, op, alg: str):
-    """Plan-path segmented allreduce over the mesh: resolve (or reuse)
-    the plan for this payload's geometry, then one pack / one
-    rendezvous / one unpack."""
-    import jax.numpy as jnp
-
-    # 1-D payloads (the common case) flow through UNTOUCHED: a
-    # same-shape jnp reshape is a fresh dispatch whose result lands
-    # uncommitted on the default device, and _assemble would then
-    # re-place 7 of 8 shards with a device_put on EVERY op
+def _flat(module, comm, x):
+    """(shape or None, the payload as 1-D).  1-D payloads (the common
+    case) flow through UNTOUCHED: a same-shape reshape is a fresh
+    dispatch whose result lands uncommitted on the default device, and
+    _assemble would then re-place 7 of 8 shards with a device_put on
+    EVERY op."""
     if getattr(x, "ndim", None) == 1:
-        shape, flat = None, x
-    else:
-        shape = x.shape
-        flat = jnp.asarray(x).reshape(-1)
+        return None, x
+    return x.shape, module._deposit(comm, x).reshape(-1)
+
+
+def mesh_reduce(module, comm, x, op, alg: str):
+    """Plan-path allreduce over the mesh: resolve (or reuse) the plan
+    for this payload's length, then one rendezvous and one program.
+    The native lowering and recursive doubling take any length; the
+    hop-explicit ring takes one that divides by the comm size and
+    pads the others (``_execute``).
+
+    Nothing is donated at the payload's own length: the caller's array
+    goes straight in, and it is the caller's.  Donation is sound only
+    where the pad program made the buffer the plan consumes, and never
+    while the integrity plane is armed: after a mismatch it re-reads
+    every deposited operand."""
+    shape, flat = _flat(module, comm, x)
     n = int(flat.shape[0])
     np_dtype = np.dtype(flat.dtype)
-    nsegs, seg = _plan_segments(
-        comm, n, segment_elems(comm, np_dtype.itemsize))
-    # donation is only sound when the pack stage owns the padded
-    # buffer; exact-fit payloads flow the caller's array straight in.
-    # Never while the integrity plane is armed: after a mismatch it
-    # re-reads every deposited operand
-    donate = nsegs * seg != n and not _ig.on
-    pkey = ("mesh", alg, nsegs, seg, np_dtype.str, op.name, donate)
+    native = bool(_native_var.value) and op.name in _NATIVE_OPS
+    total = n if native or alg != "segring" \
+        else _stripe_total(n, comm.size)
+    donate = total != n and not _ig.on
+    pkey = ("mesh", alg, n, np_dtype.str, op.name, donate)
     plan = _resolve(
         comm, pkey,
-        lambda: _build_mesh_plan(comm, alg, nsegs, seg, np_dtype,
-                                 op.name, donate))
-    pv_segments.add(nsegs)
-    out = plan.execute(module, comm, flat, n)
+        lambda: _build_mesh_plan(comm, alg, n, total, np_dtype, op.name,
+                                 native, donate))
+    pv_segments.add(plan.nsegs)
+    out = _execute(plan, module, comm, flat, n)
     return out if shape is None else out.reshape(shape)
 
 
@@ -584,95 +589,73 @@ def _compile_mesh_move(alg: str, mesh, size: int, total: int, root):
                                  out_specs=out_specs, check_vma=False))
 
 
-def _build_move_plan(comm, alg: str, nsegs: int, seg: int, np_dtype,
+def _build_move_plan(comm, alg: str, n: int, total: int, np_dtype,
                      root) -> Plan:
     mesh = comm.mesh()
     size = comm.size
-    devs = list(mesh.devices.reshape(-1))
-    dev_key = tuple(d.id for d in devs)
-    ckey = ("plan_" + alg, dev_key, (nsegs * seg,), np_dtype.str, root)
+    dev_key = tuple(d.id for d in mesh.devices.reshape(-1))
+    ckey = ("plan_" + alg, dev_key, (total,), np_dtype.str, root)
     jfn = _dev.compile_cache.get(
-        ckey, lambda: _compile_mesh_move(alg, mesh, size, nsegs * seg,
-                                         root))
+        ckey, lambda: _compile_mesh_move(alg, mesh, size, total, root))
     kind = "bcast" if alg == "segbcast" else "alltoall"
-    return Plan(alg, nsegs, seg, np_dtype, np_dtype.type(0),
+    nsegs = _plan_segments(n, segment_elems(comm, np_dtype.itemsize))
+    return Plan(alg, nsegs, np_dtype,
                 _mesh_meet_fn(mesh, size, jfn), _dev.meet,
-                devs[comm.rank],
                 _ig.spec_static(kind, "", np.empty(0, np_dtype),
-                                root or 0))
+                                root or 0),
+                *_pad_trim(n, total, np_dtype, None))
 
 
 def mesh_move(module, comm, x, alg: str, root=None):
     """Plan-path mesh bcast (``segbcast``, from ``root``) or alltoall
-    (``sega2a``): one program over the whole payload behind one
-    rendezvous.  A size that is not a whole number of segments is
-    zero-padded to the plan's shape (an alltoall's blocks each at
-    their own end) and trimmed after, so the compiled programs stay
-    keyed by segment count, never by message size."""
-    import jax.numpy as jnp
-
-    if getattr(x, "ndim", None) == 1:
-        shape, flat = None, x  # no same-shape reshape dispatch
-    else:
-        shape = x.shape
-        flat = jnp.asarray(x).reshape(-1)
+    (``sega2a``): one program over the whole payload, at its own
+    length, behind one rendezvous.  An alltoall's blocks are equal, so
+    its length always divides by the comm size; a bcast whose length
+    does not is zero-padded to the next multiple and trimmed
+    (``_execute``).  A new length compiles a new program, as
+    under the tier's crossover: the plan LRU and the compile cache
+    bound what is held, not what a sweep of sizes can ask for."""
+    shape, flat = _flat(module, comm, x)
     n = int(flat.shape[0])
     np_dtype = np.dtype(flat.dtype)
-    nsegs, seg = _plan_segments(
-        comm, n, segment_elems(comm, np_dtype.itemsize))
-    pkey = ("mesh", alg, nsegs, seg, np_dtype.str, root)
+    total = _stripe_total(n, comm.size)
+    pkey = ("mesh", alg, n, np_dtype.str, root)
     plan = _resolve(
         comm, pkey,
-        lambda: _build_move_plan(comm, alg, nsegs, seg, np_dtype, root))
-    if alg == "sega2a" and n != plan.total:
-        out = _unpack_rows(
-            comm, plan.execute(module, comm,
-                               _pack_rows(comm, flat, n, plan), plan.total),
-            n, plan)
-    else:
-        out = plan.execute(module, comm, flat, n)
+        lambda: _build_move_plan(comm, alg, n, total, np_dtype, root))
+    out = _execute(plan, module, comm, flat, n)
     return out if shape is None else out.reshape(shape)
 
 
 # -- hbm (intra-chip) plans -------------------------------------------------
 
-def _build_hbm_plan(module, comm, nsegs: int, seg: int, np_dtype,
-                    opname: str, device_hint) -> Plan:
+def _build_hbm_plan(module, comm, n: int, np_dtype, opname: str) -> Plan:
     size = comm.size
-    jbody, out_map = module._stacked("allreduce", opname, size,
-                                     (nsegs * seg,), np_dtype)
+    jbody, out_map = module._stacked("allreduce", opname, size, (n,),
+                                     np_dtype)
 
     def fn(shards, _j=jbody, _o=out_map, _n=size):
         return _o(_j(*shards), _n)
 
     fn.traced = functools.partial(_dev._stacked_exec, jbody, out_map, size)
 
-    return Plan("hbm", nsegs, seg, np_dtype,
-                _pad_value(opname, np_dtype), fn, _dev.meet,
-                device_hint,
+    nsegs = _plan_segments(n, segment_elems(comm, np_dtype.itemsize))
+    return Plan("hbm", nsegs, np_dtype, fn, _dev.meet,
                 _ig.spec_static("allreduce", opname,
                                 np.empty(0, np_dtype)))
 
 
 def hbm_reduce(module, comm, x, op):
     """Plan-path intra-chip allreduce: the stacked whole-payload
-    kernel behind exactly one rendezvous."""
-    x = module._deposit(comm, x)
-    if getattr(x, "ndim", None) == 1:
-        shape, flat = None, x  # no same-shape reshape dispatch
-    else:
-        shape = x.shape
-        flat = x.reshape(-1)
+    kernel (an elementwise fold of the deposits, any length) behind
+    exactly one rendezvous."""
+    shape, flat = _flat(module, comm, module._deposit(comm, x))
     n = int(flat.shape[0])
     np_dtype = np.dtype(flat.dtype)
-    nsegs, seg = _plan_segments(
-        comm, n, segment_elems(comm, np_dtype.itemsize))
-    pkey = ("hbm", nsegs, seg, np_dtype.str, op.name)
-    dev = getattr(x, "device", None)
+    pkey = ("hbm", n, np_dtype.str, op.name)
     plan = _resolve(
         comm, pkey,
-        lambda: _build_hbm_plan(module, comm, nsegs, seg, np_dtype,
-                                op.name, dev))
-    pv_segments.add(nsegs)
+        lambda: _build_hbm_plan(module, comm, n, np_dtype, op.name))
+    pv_segments.add(plan.nsegs)
     out = plan.execute(module, comm, flat, n)
     return out if shape is None else out.reshape(shape)

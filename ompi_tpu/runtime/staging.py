@@ -2,10 +2,9 @@
 
 Hoisted from osc/device.py (the zero-copy DMA path) so every
 subsystem that stages host memory into device buffers — one-sided
-windows, the coll plan executor's pack bypass, and the pml, should it
-grow a staged eager path — shares ONE alignment rule, ONE runtime
-aliasing probe and ONE mirror pool, instead of growing private copies
-that drift.
+windows, and the pml, should it grow a staged eager path — shares ONE
+alignment rule, ONE runtime aliasing probe and ONE mirror pool,
+instead of growing private copies that drift.
 
 Three pieces:
 
@@ -16,11 +15,11 @@ Three pieces:
 * ``runtime_zero_copy()``: probes ONCE per process whether
   ``device_put`` of an aligned host buffer ALIASES it (the CPU runtime
   does; an accelerator with discrete HBM copies).  Write-through
-  mirrors, deferred-decouple puts and the coll pack bypass are only
-  sound when it does; otherwise callers degrade to compose-and-upload.
+  mirrors and deferred-decouple puts are only sound when it does;
+  otherwise callers degrade to compose-and-upload.
 * ``MirrorPool``: a bounded free-list of displaced staging buffers, so
-  steady-state re-mirroring (osc decoupling copies, repeated ragged
-  packs) never pays fresh-page faults.
+  steady-state re-mirroring (osc decoupling copies) never pays
+  fresh-page faults.
 """
 
 from __future__ import annotations

@@ -343,8 +343,44 @@ def test_manifest_is_valid_with_six_cells():
         assert [m["name"] for m in spec["end_to_end"]] == ["setup_s",
                                                            "iter_us"]
         due = {m["name"] for m in spec["per_layer"]}
-        assert {"move_roofline", "inflight_segments", "segments_per_iter",
-                "pack_unpack_us", "kernel_us"} <= due
-        assert "collective_roofline" not in due
+        # the one share of a roofline (PR 38), due here as in every
+        # collective cell; the five metrics PR 38 took out due nowhere
+        assert {"collective_roofline", "kernel_us"} <= due
+        assert not due & GONE_SINCE_PR38
         assert spec["config"]["guarantees"]["delivery"].startswith(
             "bit-exact")
+    assert not {m["name"] for m in man["per_layer"]} & GONE_SINCE_PR38
+
+
+GONE_SINCE_PR38 = {"move_roofline", "typed_roofline", "inflight_segments",
+                   "segments_per_iter", "pack_unpack_us"}
+
+
+def test_the_ragged_cell_in_the_manifest():
+    """The cell of ISSUE 39: an allreduce off the segment grid on the
+    one-chip deployment, data files alone, due exactly the per-layer
+    metrics of the 256 MiB cell and the pack's own counter."""
+    name = "allreduce-ragged-24000012B.hbm8"
+    man = manifest.manifest(REPO)
+    entry = next(w for w in man["workloads"] if w["name"] == name)
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        "osu-hbm8", "allreduce-sum-24000012B", 1)
+    spec = manifest.cell(name, REPO)
+    # not on iter_p95_us's list: the parent's pooled p95 spread 9.8%
+    assert [m["name"] for m in spec["end_to_end"]] == ["setup_s", "iter_us"]
+    due = {m["name"] for m in spec["per_layer"]}
+    big = {m["name"] for m in manifest.cell("allreduce-256MiB.hbm8",
+                                            REPO)["per_layer"]}
+    assert due == big | {"pack_unpack_per_iter_us"} and len(due) == 17
+    assert {"collective_roofline", "kernel_us", "rdv_per_iter",
+            "device_idle_pct", "traced_iter_us"} <= due
+    mix, pairing = spec["traffic"], spec["pairing"]
+    assert mix["bytes_per_rank"] == 24000012 and mix["bytes_per_rank"] % (
+        1 << 20) != 0
+    big_spec = manifest.cell("allreduce-256MiB.hbm8", REPO)
+    assert {k: v for k, v in mix.items()
+            if k not in ("name", "bytes_per_rank")} == {
+        k: v for k, v in big_spec["traffic"].items()
+        if k not in ("name", "bytes_per_rank")}
+    assert (pairing["check"], pairing["kernel_events"]) == (
+        big_spec["pairing"]["check"], big_spec["pairing"]["kernel_events"])

@@ -116,22 +116,24 @@ def test_hierarchical_allreduce():
 # cache bounds and observability
 # ---------------------------------------------------------------------------
 
-def test_plan_cache_not_blown_by_message_sizes():
-    """The eviction-pressure satellite: a sweep of distinct message
-    sizes is identity-padded to whole segments, so the CompiledLRU
-    holds one plan program per segment COUNT (or pow2 shape under a
-    segment), never one per size; the hits pvar climbs and eviction
-    pressure stays flat."""
+def test_plan_cache_holds_one_program_a_length():
+    """A plan runs at the payload's own length (ISSUE 39): a sweep of
+    distinct message sizes compiles one program a size, exactly as a
+    sweep under the tier's crossover does, each of the payload's shape
+    and none of a padded one; a second identical sweep compiles
+    nothing, the hits pvar climbs and nothing is evicted (the
+    CompiledLRU bounds what is held)."""
     from ompi_tpu.coll.device import compile_cache
 
     pv_hits = registry.register_pvar("coll", "device", "cache_hits")
     pv_evict = registry.register_pvar("coll", "device",
                                       "cache_evictions")
+    sizes = [513 * n + n % 3 for n in range(1, 13)]   # 12, one dtype
 
     def fn(comm):
         tot = 0.0
-        for n in range(1, 13):  # 12 distinct message sizes, one dtype
-            x = _put(comm, jnp.ones((513 * n + n % 3,), jnp.float32))
+        for n in sizes:
+            x = _put(comm, jnp.ones((n,), jnp.float32))
             tot += float(np.asarray(
                 comm.allreduce_arr(x, mpi_op.SUM))[0])
         return tot
@@ -147,18 +149,16 @@ def test_plan_cache_not_blown_by_message_sizes():
         assert compile_cache.builds == builds0
         assert pv_hits.read() > hits0
         assert pv_evict.read() == evict0
-        # the plan programs are keyed by padded shape, not message
-        # size: 513 to 6,156 floats are 1 to 7 segments of 1,024 (the
-        # one size under a segment quantizes to the segment), so the
-        # 12 sizes share 7 shapes (other tests' own sizes on this
-        # 4-device world may add theirs, never one per size here)
-        mine = {(n * 1024,) for n in range(1, 8)}
-        seg_keys = [k for k in list(compile_cache._d)
-                    if isinstance(k, tuple) and k
-                    and k[0] == "plan_native" and len(k[1]) == 4
-                    and k[2] in mine and k[3] == "<f4"
-                    and k[4] == "MPI_SUM"]
-        assert 7 <= len(seg_keys) <= 2 * 7    # donated or not
+        # 513 to 6,156 floats: twelve programs, each its payload's
+        # length, none donated (nothing was padded)
+        keys = [k for k in list(compile_cache._d)
+                if isinstance(k, tuple) and k
+                and k[0] == "plan_native" and len(k[1]) == 4
+                and k[2] in {(n,) for n in sizes} and k[3] == "<f4"
+                and k[4] == "MPI_SUM"]
+        assert len(keys) == 12 and not any(k[5] for k in keys)
+        assert not [k for k in list(compile_cache._d)
+                    if k[0] == "plan_pad_trim" and k[1] in sizes]
     finally:
         _restore(saved)
 

@@ -1,12 +1,11 @@
 """The one way through the large-message tier (ISSUE 30, DESIGN.md
 §12): ``pipeline.maybe_device_coll`` as a table, ``plan._plan_segments``
-as a pure function, the identity pad of every reduction the tier
-plans, the thread a planned collective computes on, and the error path
+and ``_stripe_total`` as pure functions, the identity pad of every
+reduction the tier still pads, the thread a planned collective computes on, and the error path
 of the one ``Rendezvous.begin`` body."""
 
 import functools
 import threading
-from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -128,29 +127,31 @@ def test_router_table(module, kind, enable):
 # -- (b) plan._plan_segments as a pure function -----------------------------
 
 @pytest.mark.parametrize("size,n,seg,want", [
-    (4, 1, 1024, (1, 4)),           # one element: a comm-size multiple
-    (4, 100, 1024, (1, 128)),       # under a segment: the next pow2
-    (3, 100, 1023, (1, 129)),       # ... rounded up to a multiple of 3
-    (3, 1000, 1023, (1, 1023)),     # ... and never above the segment
+    (4, 1, 1024, (1, 4)),           # one element: one segment; a ring
+    (4, 100, 1024, (1, 100)),       # of 4 runs it at a multiple of 4
+    (3, 100, 1023, (1, 102)),       # ... of 3 at the next multiple of 3
+    (3, 1000, 1023, (1, 1002)),
     (4, 1023, 1024, (1, 1024)),
     (4, 1024, 1024, (1, 1024)),     # exactly one segment
-    (4, 5 * 1024, 1024, (5, 1024)),         # k segments
-    (4, 5 * 1024 + 1, 1024, (6, 1024)),     # k segments and one more
-    (3, 2 * 1023 + 2, 1023, (3, 1023)),
+    (4, 5 * 1024, 1024, (5, 5 * 1024)),         # k segments
+    (4, 5 * 1024 + 1, 1024, (6, 5 * 1024 + 4)),  # k segments and one more
+    (3, 2 * 1023 + 2, 1023, (3, 2 * 1023 + 3)),
 ])
-def test_plan_segments_is_log_bounded_and_covers(size, n, seg, want):
-    comm = SimpleNamespace(size=size)
-    nsegs, s = plan._plan_segments(comm, n, seg)
-    assert (nsegs, s) == want
-    assert nsegs * s >= n and s <= seg and s % size == 0
-    # a sweep of sizes under a segment lands on log2(seg) shapes at most
-    shapes = {plan._plan_segments(comm, k, seg) for k in range(1, seg)}
-    assert len(shapes) <= seg.bit_length()
+def test_plan_segments_is_a_count_and_stripes_pad_to_the_comm(
+        size, n, seg, want):
+    """What coll_pipeline_segments advances by (a count: no program
+    has a segment's shape), and the length a stripe schedule runs an
+    n-element payload at: n itself where it divides by the comm size,
+    else the next multiple, never a segment's."""
+    nsegs, total = plan._plan_segments(n, seg), plan._stripe_total(n, size)
+    assert (nsegs, total) == want
+    assert (nsegs - 1) * seg < n <= nsegs * seg
+    assert 0 <= total - n < size and total % size == 0
 
 
 # -- (c) the identity pad of every planned reduction ------------------------
 
-N_RAGGED = SEG_ELEMS + 3     # two segments, 1,021 padded elements
+N_RAGGED = SEG_ELEMS + 3     # 1,027 over four ranks: one padded element
 
 
 def _ragged_inputs(case):

@@ -44,7 +44,12 @@ def _one_chip(rank):
 
 
 def _idle_fd_readable(progress):
-    return bool(select.select(list(progress._idle_sel.get_map()), [], [], 0)[0])
+    # poll, not select.select: a world leaves its ranks' idle fds open,
+    # so late in a long worker process these number above FD_SETSIZE
+    poller = select.poll()
+    for fd in progress._idle_sel.get_map():
+        poller.register(fd, select.POLLIN)
+    return bool(poller.poll(0))
 
 
 @contextlib.contextmanager

@@ -67,8 +67,9 @@ N_FUSED, N_SEG = 24, 6
 def period4(tmp_path_factory):
     """One 4-rank world at period 4 in every operation category:
     24 allreduces under the tier's crossover and 6 planned ones (4,099
-    elements: ragged, so packed and unpacked); every rank's events,
-    counters and dump."""
+    elements through the hop-explicit ring, which pads what four ranks
+    cannot split: packed and unpacked); every rank's events, counters
+    and dump."""
     dumps = tmp_path_factory.mktemp("period4")
 
     def fn(comm):
@@ -97,7 +98,7 @@ def period4(tmp_path_factory):
                        and e["args"].get("cid") == comm.cid],
         }
 
-    knobs = dict(PIPE_ON, **TRACE_ON)
+    knobs = dict(PIPE_ON, coll_plan_native_reduce=False, **TRACE_ON)
     knobs.update(trace_dump_path=str(dumps), trace_sample_spec=",".join(
         f"{c}:4" for c in OP_CATS))
     res = _world(4, fn, knobs, devices=True)
@@ -359,31 +360,36 @@ def test_layer_account_closes_over_blocking_allreduces():
     assert all(d["launch"] == 0 for _w, d in res)
 
 
-@pytest.mark.parametrize("where", ["mesh", "one_chip"])
+@pytest.mark.parametrize("where", ["mesh", "mesh_ring", "one_chip"])
 def test_layer_account_on_the_plan_path(where):
     """The compiled plans (the benchmark's large allreduce shapes,
     test-sized), over four devices and on one chip: one rendezvous per
     operation exactly, closure within 3%.  A plan brings its traced
     twin, so the one publisher of each rendezvous banks the launch and
-    the split.  The mesh case is ragged (4,099 elements): pack and
-    unpack sum with the rest, the publisher assembles, and
-    coll_pipeline_segments advances by the plan's 5 segments an
-    operation; the one-chip case fits (4,096), packs nothing and
-    assembles nothing (the shards are the kernel's arguments)."""
+    the split.  The mesh cases are ragged (4,099 elements): the
+    publisher assembles, and coll_pipeline_segments advances by 5
+    segments an operation.  Under the native lowering the plan runs at
+    the payload's own length and packs nothing; through the
+    hop-explicit ring (mesh_ring) four ranks cannot split 4,099, so a
+    pad and a trim sum with the rest.  The one-chip case (4,096) packs
+    nothing and assembles nothing (the shards are the kernel's
+    arguments)."""
     from ompi_tpu.coll import plan
-    mesh = where == "mesh"
+    mesh = where != "one_chip"
+    padded = where == "mesh_ring"
     n_ops, n_elems = (30, 4099) if mesh else (100, 4096)
-    n0 = plan.pv_segments.read()
+    n0, p0 = plan.pv_segments.read(), plan.pv_padded.read()
     res = _closure_world(
         n_ops, lambda comm: jax.device_put(
             jnp.arange(n_elems, dtype=jnp.float32) + comm.rank,
             comm.device),
-        PIPE_ON, **({"devices": True} if mesh else {"device_map": _one_dev}))
+        dict(PIPE_ON, coll_plan_native_reduce=not padded),
+        **({"devices": True} if mesh else {"device_map": _one_dev}))
     for wall, d in res:
         total = sum(d[k] for k in trace.LAYER_CLOSURE)
         assert abs(total - wall) <= 0.03 * wall, (total, wall, d)
         assert d["rendezvous"] == n_ops
-        assert (d["pack"] > 0 and d["unpack"] > 0) if mesh else (
+        assert (d["pack"] > 0 and d["unpack"] > 0) if padded else (
             d["pack"] == 0 and d["unpack"] == 0)
     launch = sum(d["launch"] for _w, d in res)
     serve = max(d["rdv_serve"] for _w, d in res)
@@ -396,6 +402,8 @@ def test_layer_account_on_the_plan_path(where):
     nsegs = 5 if mesh else 4
     assert abs((plan.pv_segments.read() - n0)
                - 4 * (n_ops + 5) * nsegs) <= 3 * nsegs
+    moved = plan.pv_padded.read() - p0
+    assert abs(moved - 4 * (n_ops + 5)) <= 3 if padded else moved == 0
 
 
 def test_layer_account_on_one_chip_alltoall_above_threshold():
