@@ -64,6 +64,7 @@ COUNTERS = {
     "plan_hits": "coll_plan_hits",
     "fused": "coll_device_fused_collectives",
     "typed": "coll_typed_device_ops",
+    "ragged": "coll_alltoallv_device_ops",
     "d2d": "btl_tpu_d2d_sends",
     "staged": "btl_tpu_staged_sends",
     "moved": "btl_tpu_recv_moves",
@@ -267,6 +268,46 @@ class Smoke:
             lambda r: np.concatenate(
                 [gen(self.seed, oid, s, p * m, np.float32)
                  [r * m:(r + 1) * m] for s in range(p)]))
+
+    def alltoallv(self):
+        """The key exchange of NAS Parallel Benchmarks IS at class S
+        (2**16 keys, MAX_KEY 2**11, 2**9 buckets): every rank buckets
+        its keys, the ranks split the buckets so that each owns about
+        NUM_KEYS keys, and one ``alltoallv_arr`` moves every key to its
+        owner.  The counts are host integers and differ for every pair;
+        coll/hbm serves the call with one program whose counts are
+        operands (``coll_alltoallv_device_ops`` moves once a rank-call).
+        The receive buffer is IS's SIZE_OF_BUFFERS; what lies past the
+        received keys is not part of the result, so the comparison
+        takes the received keys alone."""
+        p, oid = self.size, self.op_id + 1
+        nkeys, shift, nb = (1 << 16) // p, 2, 1 << 9
+        cap = 3 * nkeys // 2
+        ks = [np.random.default_rng([self.seed, oid, r]).integers(
+            0, 1 << 9, (4, nkeys)).sum(0).astype(np.int32)
+            for r in range(p)]
+        cum = np.cumsum([np.bincount(k >> shift, minlength=nb)
+                         for k in ks], axis=1)
+        # owner j ends at the first bucket where the running total of
+        # all ranks' keys reaches (j + 1) * NUM_KEYS
+        last = np.searchsorted(cum.sum(0), (np.arange(p) + 1) * nkeys)
+        upto = np.concatenate(
+            [np.zeros((p, 1), np.int64), cum[:, np.minimum(last, nb - 1)]],
+            axis=1)
+        counts = np.diff(upto, axis=1)        # [source, destination]
+        buff1 = [k[np.argsort(k >> shift, kind="stable")] for k in ks]
+        at = np.cumsum(counts, axis=1) - counts
+        me = self.rank
+        self.collective(
+            "alltoallv_is_class_s", "alltoallv_arr", nkeys * 4, np.int32,
+            lambda r: buff1[r],
+            lambda x: self.comm.alltoallv_arr(
+                x, counts[me], counts[:, me],
+                capacity=cap)[:int(counts[:, me].sum())],
+            lambda r: np.concatenate(
+                [buff1[s][at[s, r]:at[s, r] + counts[s, r]]
+                 for s in range(p)]),
+            counter="ragged")
 
     def reduce_scatter(self):
         p, oid = self.size, self.op_id + 1
@@ -569,6 +610,12 @@ def main():
     sm.fused_batch()
     sm.bcast()
     sm.alltoall()
+    if comm.coll.providers.get("alltoallv_arr") == "hbm":
+        sm.alltoallv()
+    else:
+        sm.say("alltoallv_is_class_s: skipped, one rank a chip, where "
+               "alltoallv_arr is host-staged until its mesh half exists "
+               "(ROADMAP M2)")
     sm.reduce_scatter()
     c5 = sm.config5()
     sm.allgather()

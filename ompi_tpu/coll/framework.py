@@ -38,6 +38,8 @@ COLL_FUNCS = (
     # primitive (ring attention / pipeline parallelism)
     "allreduce_arr", "bcast_arr", "reduce_arr", "allgather_arr",
     "alltoall_arr", "reduce_scatter_block_arr", "ppermute_arr",
+    # the ragged exchange: counts and displacements a rank (coll/ragged)
+    "alltoallv_arr",
     # nonblocking device-array collectives: the fusion surface
     # (coll/fusion coalesces pending small ops into one XLA call)
     "iallreduce_arr", "ibcast_arr",

@@ -899,6 +899,24 @@ class Communicator:
     def alltoall_arr(self, x):
         return self.coll.alltoall_arr(self, x)
 
+    def alltoallv_arr(self, x, scounts, rcounts, sdispls=None, rdispls=None,
+                      capacity=None):
+        """MPI_Alltoallv on a 1-D device array.  ``scounts`` /
+        ``rcounts`` (and the displacements, which default to their
+        exclusive prefix sums) are ``size`` host integers each, in
+        elements, with MPI's contract: what rank i states it sends to j
+        is what j states it receives from i.  ``capacity`` is the
+        static length of the result (MPI's receive buffer is the
+        user's to size); a receive that would pass it raises
+        MPI_ERR_TRUNCATE.  Returns ``capacity`` elements of ``x``'s
+        dtype on this rank's device: ``[rdispls[i], rdispls[i] +
+        rcounts[i])`` is what rank i sent here, bit for bit; the rest
+        is not part of the result.  A new count matrix compiles
+        nothing (coll/ragged.py)."""
+        from ompi_tpu.coll.ragged import alltoallv_arr
+        return alltoallv_arr(self, self.coll.alltoallv_arr, x, scounts,
+                             rcounts, sdispls, rdispls, capacity)
+
     def reduce_scatter_arr(self, x, op, datatype=None, count=None):
         if datatype is None:
             return self.coll.reduce_scatter_block_arr(self, x, op)

@@ -520,6 +520,10 @@ def flip_value(value):
 
 
 def _flip_inner(value):
+    flipped = getattr(value, "flipped", None)
+    if flipped is not None:
+        # a deposit that is more than an array (coll/ragged.Deposit)
+        return flipped(_flip_array)
     if isinstance(value, tuple) and len(value) == 2 \
             and isinstance(value[1], list) and value[1]:
         arrays = list(value[1])

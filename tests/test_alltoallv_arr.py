@@ -1,0 +1,431 @@
+"""``comm.alltoallv_arr`` (ISSUE 40, DESIGN.md section 12): MPI_Alltoallv
+on device arrays, served by coll/hbm in ONE program whose counts are
+operands.  NAS Parallel Benchmarks IS class S on 8 ranks against
+cellbench/reference_ragged.py, exact, then IS's full verification; the
+argument contract case by case; 40 count matrices, one program; the
+host-staged fallback; the counters and the span."""
+
+import numpy as np
+import pytest
+
+from cellbench import reference_ragged as ref
+from ompi_tpu import errhandler as eh
+from ompi_tpu.coll import device as dev
+from ompi_tpu.coll import ragged
+from ompi_tpu.mca.params import registry
+from ompi_tpu.testing import run_ranks
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+
+def one_chip(P, fn, **kw):
+    """P rank-threads on one device: coll/hbm's layout."""
+    return run_ranks(P, fn, device_map=lambda r: jax.devices()[0], **kw)
+
+
+def pvar(name):
+    """A counter's reading; 0 before its module registered it."""
+    return next((int(p.read()) for p in registry.all_pvars()
+                 if p.full_name == name), 0)
+
+
+def packed(counts):
+    """Exclusive prefix sums along the last axis."""
+    return np.cumsum(counts, axis=-1) - counts
+
+
+def expected(xs, counts, sdispls, rdispls, rank, cap):
+    """(answer, mask of the elements that are part of it)."""
+    out = np.zeros(cap, xs[0].dtype)
+    live = np.zeros(cap, bool)
+    for i in range(len(xs)):
+        c = counts[i][rank]
+        out[rdispls[rank][i]:rdispls[rank][i] + c] = \
+            xs[i][sdispls[i][rank]:sdispls[i][rank] + c]
+        live[rdispls[rank][i]:rdispls[rank][i] + c] = True
+    return out, live
+
+
+def exchange(P, xs, counts, cap, sdispls=None, rdispls=None, world=one_chip):
+    """Run one exchange; assert every rank's answer; return providers."""
+    counts = np.asarray(counts)
+    sd = packed(counts) if sdispls is None else np.asarray(sdispls)
+    rd = packed(counts.T) if rdispls is None else np.asarray(rdispls)
+
+    def fn(comm):
+        r = comm.rank
+        out = comm.alltoallv_arr(
+            jax.device_put(xs[r], comm.device), counts[r], counts[:, r],
+            None if sdispls is None else sd[r],
+            None if rdispls is None else rd[r], capacity=cap)
+        assert isinstance(out, jax.Array) and comm.device in out.devices()
+        return np.asarray(out), comm.coll.providers["alltoallv_arr"]
+
+    res = world(P, fn)
+    for r, (out, _prov) in enumerate(res):
+        want, live = expected(xs, counts, sd, rd, r, cap)
+        assert out.shape == (cap,) and out.dtype == xs[0].dtype
+        assert np.array_equal(out[live].view(np.uint8),
+                              want[live].view(np.uint8)), r
+    return {prov for _o, prov in res}
+
+
+def random_counts(rng, P, n, zeros=0.0):
+    counts = np.zeros((P, P), np.int64)
+    for i in range(P):
+        cuts = np.sort(rng.integers(0, n + 1, P - 1))
+        counts[i] = np.diff(np.concatenate([[0], cuts, [n]]))
+        drop = rng.random(P) < zeros
+        counts[i][drop] = 0
+    return counts
+
+
+def test_npb_is_class_s_exact_then_fully_verified():
+    """IS class S, 8 ranks: the exchange of both parities through the
+    library equals the reference's key_buff2; then IS's full
+    verification: the received keys, sorted on each rank and
+    concatenated in rank order, are globally sorted and are the
+    multiset that was sent."""
+    P, cls, seed = 8, ref.CLASSES["S"], 4000000007
+    cap = ref.size_of_buffers(cls, P)
+    exs = [ref.exchange(seed, parity, cls, P) for parity in (0, 1)]
+    before = pvar("coll_arr_host_staged_collectives")
+
+    def fn(comm):
+        outs = []
+        for ex in exs:
+            c = ex["counts"]
+            outs.append(np.asarray(comm.alltoallv_arr(
+                jax.device_put(ex["buff1"][comm.rank], comm.device),
+                c[comm.rank], c[:, comm.rank], capacity=cap)))
+        return outs, comm.coll.providers["alltoallv_arr"]
+
+    res = one_chip(P, fn)
+    assert pvar("coll_arr_host_staged_collectives") == before
+    for parity, ex in enumerate(exs):
+        got = []
+        for r in range(P):
+            outs, prov = res[r]
+            assert prov == "hbm"
+            owed = ref.owed(ex, r)
+            assert ref.gap(outs[parity][:owed.size], owed) == 0.0
+            lo, hi = ref.owned(ex["last"], r)
+            b = owed >> ref.shift_of(cls)
+            assert not owed.size or (b.min() >= lo and b.max() <= hi)
+            got.append(np.sort(outs[parity][:owed.size]))
+        whole = np.concatenate(got)
+        assert np.all(np.diff(whole) >= 0)
+        sent = np.sort(np.concatenate(
+            [ref.keys(seed, r, parity, cls, P) for r in range(P)]))
+        assert np.array_equal(whole, sent)
+
+
+CASES = {
+    "random-with-zeros-8": (8, "int32", "zeros"),
+    "random-4": (4, "int32", "random"),
+    "a-rank-sends-nothing": (4, "int32", "silent"),
+    "a-rank-receives-nothing": (4, "int32", "deaf"),
+    "all-to-one": (8, "int32", "one"),
+    "float32": (4, "float32", "random"),
+    "bfloat16": (4, "bfloat16", "random"),
+    # blocks of several chunks at the real tile (chunk 8,192 here)
+    "long-blocks": (4, "int32", "long"),
+    "long-blocks-bfloat16": (4, "bfloat16", "long"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_argument_contract(case):
+    P, dtype, how = CASES[case]
+    rng = np.random.default_rng(sorted(CASES).index(case))
+    n = 40000 if how == "long" else 600
+    counts = random_counts(rng, P, n, zeros=0.4 if how == "zeros" else 0.0)
+    if how == "silent":
+        counts[1] = 0
+    elif how == "deaf":
+        counts[:, 2] = 0
+    elif how == "one":
+        counts[:] = 0
+        counts[:, 3] = n
+    xs = [rng.integers(0, 1 << 15, n).astype(np.int32) for _ in range(P)]
+    if dtype != "int32":
+        xs = [np.asarray(jnp.asarray(x, jnp.float32).astype(dtype))
+              for x in xs]
+    cap = int(counts.sum(0).max()) + 7
+    assert exchange(P, xs, counts, cap) == {"hbm"}
+
+
+def test_uint64_bit_patterns_and_the_refusal_without_x64():
+    """An 8-byte element travels whole where the job enabled x64, and
+    is refused (MPI_ERR_TYPE) where jax would narrow it."""
+    P, n = 4, 300
+    rng = np.random.default_rng(64)
+    counts = random_counts(rng, P, n)
+    xs = [rng.integers(0, 1 << 63, n, dtype=np.uint64) | (1 << 63)
+          for _ in range(P)]
+    cap = int(counts.sum(0).max())
+
+    def fn(comm):
+        r = comm.rank
+        with pytest.raises(eh.MPIException) as e:
+            comm.alltoallv_arr(xs[r], counts[r], counts[:, r], capacity=cap)
+        assert e.value.error_class == eh.ERR_TYPE
+        with jax.enable_x64(True):
+            out = comm.alltoallv_arr(
+                jax.device_put(xs[r], comm.device), counts[r],
+                counts[:, r], capacity=cap)
+            return np.asarray(out), comm.coll.providers["alltoallv_arr"]
+
+    sd, rd = packed(counts), packed(counts.T)
+    for r, (out, prov) in enumerate(one_chip(P, fn)):
+        want, live = expected(xs, counts, sd, rd, r, cap)
+        assert prov == "hbm" and out.dtype == np.uint64
+        assert np.array_equal(out[live], want[live])
+
+
+def test_displacements_with_gaps_in_any_order():
+    """Explicit displacements: gaps on both sides, the receive blocks
+    in falling order; what no block covers is not compared."""
+    P, n = 4, 400
+    rng = np.random.default_rng(5)
+    counts = rng.integers(0, 60, (P, P))
+    sd = packed(counts) + 9 * np.arange(P)            # gaps of 9
+    slots = 70 * np.arange(P)[::-1]                   # falling order
+    rd = np.tile(slots, (P, 1))
+    xs = [rng.integers(0, 1 << 20, n).astype(np.int32) for _ in range(P)]
+    assert exchange(P, xs, counts, 300, sd, rd) == {"hbm"}
+
+
+def test_capacity_met_exactly_and_passed_by_one():
+    P, n = 4, 200
+    rng = np.random.default_rng(6)
+    counts = random_counts(rng, P, n)
+    xs = [rng.integers(0, 99, n).astype(np.int32) for _ in range(P)]
+    need = counts.sum(0)                 # what each rank receives
+    cap = int(need.max())
+    assert exchange(P, xs, counts, cap) == {"hbm"}
+
+    def fn(comm):
+        r = comm.rank
+        with pytest.raises(eh.MPIException) as e:
+            comm.alltoallv_arr(xs[r], counts[r], counts[:, r],
+                               capacity=int(need[r]) - 1)
+        return e.value.error_class
+
+    assert set(one_chip(P, fn)) == {eh.ERR_TRUNCATE}
+
+
+def test_what_the_entry_refuses():
+    """A count matrix of the wrong length, a negative count, a send
+    block past the buffer, no capacity, a buffer that is not 1-D: each
+    raises on the caller's own side, before any rank waits."""
+    x = np.arange(10, dtype=np.int32)
+
+    def fn(comm):
+        seen = []
+        for args, kw in (
+                (([5, 5, 0], [5, 5]), dict(capacity=10)),
+                (([5, -5], [5, 5]), dict(capacity=10)),
+                (([5, 6], [5, 5]), dict(capacity=10)),
+                (([5, 5], [5, 5]), dict()),
+                (([5, 5], [5, 5]), dict(capacity=10, x=x.reshape(2, 5)))):
+            with pytest.raises(eh.MPIException) as e:
+                comm.alltoallv_arr(kw.pop("x", x), *args, **kw)
+            seen.append(e.value.error_class)
+        return seen
+
+    assert one_chip(2, fn)[0] == [eh.ERR_COUNT, eh.ERR_COUNT, eh.ERR_BUFFER,
+                                  eh.ERR_ARG, eh.ERR_BUFFER]
+
+
+def test_counts_that_do_not_meet_raise_on_every_rank():
+    """What rank 0 sends rank 1 is not what rank 1 expects: only the
+    meeting can see it, and every rank hears of it."""
+    x = np.arange(8, dtype=np.int32)
+
+    def fn(comm):
+        sc = [4, 4] if comm.rank == 0 else [4, 4]
+        rc = [4, 4] if comm.rank == 0 else [3, 4]
+        with pytest.raises(RuntimeError, match="expects 3"):
+            comm.alltoallv_arr(x, sc, rc, capacity=8)
+        return True
+
+    assert one_chip(2, fn) == [True, True]
+
+
+def test_forty_count_matrices_build_one_program(monkeypatch):
+    """40 calls, 40 different count matrices, long blocks and short
+    (the chunk is 64 and the tile 16 here, so the chunked middles, the
+    edges and the short blocks' windows all run): ONE
+    program built, in the compile cache and in the comm's plans; every
+    answer right."""
+    P, n, cap, calls = 8, 1024, 8 * 1024, 40
+    monkeypatch.setattr(ragged, "CHUNK", 64)
+    monkeypatch.setattr(ragged, "TILE", 16)
+    rng = np.random.default_rng(40)
+    mats = [random_counts(rng, P, n, zeros=0.2 * (k % 3))
+            for k in range(calls)]
+    assert len({m.tobytes() for m in mats}) == calls
+    xs = [rng.integers(0, 1 << 30, n).astype(np.int32) for _ in range(P)]
+    dev.compile_cache.clear()
+    builds = dev.compile_cache.builds
+
+    def fn(comm):
+        x = jax.device_put(xs[comm.rank], comm.device)
+        outs = [np.asarray(comm.alltoallv_arr(
+            x, m[comm.rank], m[:, comm.rank], capacity=cap)) for m in mats]
+        return outs, len(comm.__dict__["_hbm_plans"])
+
+    res = one_chip(P, fn)
+    assert dev.compile_cache.builds - builds == 1
+    for r, (outs, plans) in enumerate(res):
+        assert plans == 1
+        for m, out in zip(mats, outs):
+            want, live = expected(xs, m, packed(m), packed(m.T), r, cap)
+            assert np.array_equal(out[live], want[live])
+
+
+def test_ranks_of_different_lengths_and_capacities():
+    """A rank sizes its own buffers: the program is keyed by all of
+    them."""
+    P = 4
+    rng = np.random.default_rng(9)
+    lens = [100, 0, 257, 64]
+    counts = np.zeros((P, P), np.int64)
+    for i, n in enumerate(lens):
+        counts[i] = random_counts(rng, P, n)[0] if n else 0
+    caps = [int(c) + 3 * r for r, c in enumerate(counts.sum(0))]
+    xs = [rng.integers(0, 1 << 20, n).astype(np.int32) for n in lens]
+    sd, rd = packed(counts), packed(counts.T)
+
+    def fn(comm):
+        r = comm.rank
+        return np.asarray(comm.alltoallv_arr(
+            jax.device_put(xs[r], comm.device), counts[r], counts[:, r],
+            capacity=caps[r]))
+
+    for r, out in enumerate(one_chip(P, fn)):
+        want, live = expected(xs, counts, sd, rd, r, caps[r])
+        assert out.shape == (caps[r],)
+        assert np.array_equal(out[live], want[live])
+
+
+@pytest.mark.parametrize("why", ["one-byte-elements", "ranks-across-devices"])
+def test_the_fallback_gives_the_same_answer_and_is_counted(why):
+    """What the device does not serve (an element size outside
+    coll/ragged.ITEMSIZES; a comm whose ranks own different devices,
+    coll/tpu's, whose mesh half is not built) goes to HostArrModule:
+    the same answer, counted host-staged once a rank-call, the device
+    counters at rest."""
+    P, n = 4, 300
+    rng = np.random.default_rng(11)
+    counts = random_counts(rng, P, n, zeros=0.2)
+    xs = [rng.integers(0, 100, n).astype(
+        np.int8 if why == "one-byte-elements" else np.int32)
+        for _ in range(P)]
+    cap = int(counts.sum(0).max()) + 1
+    world = one_chip if why == "one-byte-elements" else (
+        lambda P, fn: run_ranks(P, fn, devices=True))
+    staged = pvar("coll_arr_host_staged_collectives")
+    ops = pvar("coll_alltoallv_device_ops")
+    provs = exchange(P, xs, counts, cap, world=world)
+    assert provs == {"hbm" if why == "one-byte-elements" else "tpu"}
+    assert pvar("coll_arr_host_staged_collectives") - staged == P
+    assert pvar("coll_alltoallv_device_ops") == ops
+
+
+def test_the_two_pvars_and_the_span():
+    """``coll_alltoallv_device_ops`` moves once a rank-call,
+    ``coll_alltoallv_elems`` by the sum of scounts (no padded bound);
+    the call's ``coll`` span carries the elements sent and the
+    capacity."""
+    P, n, cap = 4, 500, 900
+    rng = np.random.default_rng(12)
+    counts = random_counts(rng, P, n, zeros=0.3)
+    xs = [rng.integers(0, 100, n).astype(np.int32) for _ in range(P)]
+    ops, elems = pvar("coll_alltoallv_device_ops"), \
+        pvar("coll_alltoallv_elems")
+    saved = registry.get("trace_enable")
+    registry.set("trace_enable", True)
+    try:
+        def fn(comm):
+            r = comm.rank
+            comm.alltoallv_arr(xs[r], counts[r], counts[:, r], capacity=cap)
+            return [e for e in comm.state.tracer.snapshot()
+                    if e.get("name") == "alltoallv_arr"]
+
+        res = one_chip(P, fn)
+    finally:
+        registry.set("trace_enable", saved)
+    assert pvar("coll_alltoallv_device_ops") - ops == P
+    assert pvar("coll_alltoallv_elems") - elems == int(counts.sum())
+    for r, spans in enumerate(res):
+        assert len(spans) == 1
+        assert spans[0]["args"]["elems"] == int(counts[r].sum())
+        assert spans[0]["args"]["capacity"] == cap
+
+
+def test_the_sdc_injector_flips_a_ragged_deposit():
+    """The integrity plane does not sample the call (DESIGN.md section
+    25), but its fault injector reaches every deposit: a ragged one
+    flips its array and keeps its counts."""
+    from ompi_tpu.obs import integrity
+    meta = ragged.arguments(2, 8, [3, 5], [4, 4], None, None, 16)
+    dep = ragged.Deposit(np.arange(8, dtype=np.int32), meta, 16)
+    bad = integrity.flip_value(dep)
+    assert bad.meta is meta and bad.capacity == 16
+    assert int(np.sum(np.asarray(bad.x) != dep.x)) == 1
+
+
+def test_the_chunk_of_a_pair():
+    """Half a balanced block as a power of two, a whole number of
+    tiles, at most CHUNK and at least one tile, never longer than
+    either buffer; 0 where a buffer holds no whole tile."""
+    t = ragged.TILE
+    assert ragged.chunk_of(1 << 24, 3 << 23, 8, ragged.CHUNK, t) == 1 << 19
+    assert ragged.chunk_of(1 << 24, 3 << 23, 8, 1 << 21, t) == 1 << 20
+    assert ragged.chunk_of(8192, 12288, 8, ragged.CHUNK, t) == 1024
+    assert ragged.chunk_of(100, 12288, 8, ragged.CHUNK, t) == 0
+    assert ragged.chunk_of(8192, 1000, 8, ragged.CHUNK, t) == 0
+    assert ragged.chunk_of(300000, 5000, 4, ragged.CHUNK, t) == 4096
+    assert ragged.chunk_of(1 << 20, 1 << 20, 4, ragged.CHUNK, 2 * t) == 1 << 17
+
+
+# -- the program, compiled here for a described v5e (no chip) -------------
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_program_compiles_for_the_chip_to_one_pass_a_chunk(one_v5e):
+    """What the body's speed rests on (PERF.md section 5): the chip's
+    compiler sees the chunk's destination offset as a multiple of the
+    tile (1,023 known zero bits) and fuses the dynamic_slice into the
+    in-place dynamic_update_slice, one pass a chunk; no whole buffer is
+    copied; the program is named ompi_alltoallv."""
+    import re
+    P, n = 4, 1 << 22
+    cap = 3 * n // 2
+    meta = jax.ShapeDtypeStruct((3, P, P), jnp.int32, sharding=one_v5e)
+    xs = [jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_v5e)] * P
+    txt = jax.jit(ragged.body((cap,) * P)).lower(meta, *xs).compile() \
+        .as_text()
+    assert "jit_ompi_alltoallv" in txt
+    assert len(re.findall(r" while\(", txt)) == P * P
+    # every chunk loop's body is ONE fusion whose root is the update
+    fused = re.findall(
+        r"ROOT %dynamic_update_slice\S* = \S+ dynamic-update-slice\("
+        r"[^\n]*?\"zeroes\":\"1023\"", txt)
+    assert len(fused) >= P * P
+    assert not re.search(r"s32\[(%d|%d)\]\S* copy" % (n, cap), txt)

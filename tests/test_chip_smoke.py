@@ -64,6 +64,10 @@ def test_dev_mode_every_operation_served_by_a_device_module(devices,
                  "config5_reduce_scatter_block_max_vector", "allgather",
                  "ppermute_ring"):
         assert name in ops, (name, sorted(ops))
+    # the ragged exchange runs where a device serves it: one chip
+    assert ("alltoallv_is_class_s" in ops) == (module == "hbm")
+    if module == "hbm":
+        assert ops["alltoallv_is_class_s"][0][1]["ragged"] == 8 * 4
     for name, calls in ops.items():
         for provider, counters in calls:
             assert provider != "arr_host", (name, provider)
