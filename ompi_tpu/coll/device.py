@@ -1542,14 +1542,14 @@ class HbmCollModule(CollModule):
         progs: Dict[Tuple, Callable] = {}
 
         def program(*deposits):
-            lens = tuple(d.x.shape[0] for d in deposits)
-            caps = tuple(d.capacity for d in deposits)
-            jbody = progs.get((lens, caps))
+            xs = [d.x for d in deposits]
+            key = (*[x.shape[0] for x in xs], *[d.capacity for d in deposits])
+            jbody = progs.get(key)
             if jbody is None:
-                jbody = progs[(lens, caps)] = self._stacked(
-                    "alltoallv", "", len(deposits), lens, dtype, caps)[0]
-            return jbody(_ragged.operand(deposits),
-                         *[d.x for d in deposits])
+                P = len(xs)
+                jbody = progs[key] = self._stacked(
+                    "alltoallv", "", P, key[:P], dtype, key[P:])[0]
+            return jbody(_ragged.operand(deposits, max(key)), *xs)
 
         return program, _per_rank
 
@@ -1668,8 +1668,8 @@ class HbmCollModule(CollModule):
         program's operands, and what they must satisfy (inside the
         buffers, what i sends j is what j expects of i) is an error
         where it does not hold, not a fallback."""
-        return self._eligible(comm, x) \
-            and _dtype_of(x).itemsize in _ragged.ITEMSIZES
+        return x.dtype.itemsize in _ragged.ITEMSIZES \
+            and self._eligible(comm, x)
 
     def alltoallv_arr(self, comm, x, meta, capacity: int):
         """One rendezvous and one ``ompi_alltoallv`` program a call;
@@ -1678,7 +1678,7 @@ class HbmCollModule(CollModule):
             return self.fallback.alltoallv_arr(comm, x, meta, capacity)
         out = self._run(comm, "alltoallv", "", x, capacity, meta)
         _ragged.pv_device_ops.add(1)
-        _ragged.pv_elems.add(int(meta[0].sum()))
+        _ragged.pv_elems.add(meta[_ragged.SENT])
         return out
 
     def bcast_arr(self, comm, x, root: int):
@@ -1841,7 +1841,7 @@ class HostArrModule(CollModule):
         dt = a.dtype
         a = a.view(np.dtype(f"u{dt.itemsize}"))
         r = np.zeros(capacity, a.dtype)
-        sc, sd, rc, rd = (row.tolist() for row in meta)
+        sc, sd, rc, rd = meta[:_ragged.SENT]
         mpi_dt = self._dtype_of(a)
         self.p2p.alltoallv(comm, a, sc, sd, mpi_dt, r, rc, rd, mpi_dt)
         return self._back(comm, r.view(dt))

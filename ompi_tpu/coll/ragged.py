@@ -24,10 +24,16 @@ it was chosen over.
 """
 from __future__ import annotations
 
+import functools
+import struct
+from itertools import accumulate
+from operator import add
+
 import numpy as np
 
 from ompi_tpu import errhandler as _eh
 from ompi_tpu.mca.params import registry
+from ompi_tpu.runtime import x64 as _x64
 
 pv_device_ops = registry.register_pvar(
     "coll", "alltoallv", "device_ops",
@@ -48,21 +54,24 @@ TILE = 1024
 #: runtime/x64 states; the entry has refused what jax would narrow)
 ITEMSIZES = (2, 4, 8)
 _ROWS = ("scounts", "sdispls", "rcounts", "rdispls")
+#: ``meta[SENT]``: the elements a rank-call sends, summed once
+SENT = 4
+_SEQUENCES = (list, tuple)
 
 
 class Deposit:
     """What a rank brings to the meeting: its send buffer and its own
-    view of the exchange (``meta``: int64 rows scounts, sdispls,
-    rcounts, rdispls), with the length it wants back."""
+    view of the exchange (``meta``, ``arguments``'s), with the length
+    it wants back."""
 
     __slots__ = ("x", "meta", "capacity", "nbytes")
 
-    def __init__(self, x, meta: np.ndarray, capacity: int) -> None:
+    def __init__(self, x, meta: tuple, capacity: int) -> None:
         self.x = x
         self.meta = meta
         self.capacity = capacity
         # what the offload accounting reports as moved: the bytes sent
-        self.nbytes = int(meta[0].sum()) * np.dtype(x.dtype).itemsize
+        self.nbytes = meta[SENT] * x.dtype.itemsize
 
     def flipped(self, flip) -> "Deposit":
         """The deposit a corrupting chip would have made
@@ -71,66 +80,98 @@ class Deposit:
         return Deposit(flip(self.x), self.meta, self.capacity)
 
 
+def _row(given, size: int, row: int) -> tuple:
+    """``given`` as ``size`` Python ints: a list or tuple of ints as it
+    is, anything numpy reads as integers through ``tolist``; else
+    MPI_ERR_COUNT."""
+    if type(given) is np.ndarray:
+        v = given
+    elif type(given) in _SEQUENCES and len(given) == size \
+            and all(type(v) is int for v in given):
+        return tuple(given)
+    else:
+        v = np.asarray(given)
+    if v.shape != (size,) or v.dtype.kind not in "iu":
+        raise _eh.MPIException(
+            _eh.ERR_COUNT, f"alltoallv_arr: {_ROWS[row]} must be "
+            f"{size} integers, one a rank (MPI_ERR_COUNT)")
+    return tuple(v.tolist())
+
+
 def arguments(size: int, length: int, scounts, rcounts, sdispls, rdispls,
-              capacity) -> np.ndarray:
+              capacity) -> tuple:
     """MPI's argument contract, checked on the caller's own side: the
-    (4, size) int64 ``meta`` of a call.  Displacements default to the
-    exclusive prefix sums (packed blocks in rank order)."""
+    ``meta`` of a call, ``(scounts, sdispls, rcounts, rdispls, sent)``,
+    four rows of ``size`` Python ints and the sum of ``scounts``.
+    Displacements default to the exclusive prefix sums (packed blocks
+    in rank order)."""
     if capacity is None:
         raise _eh.MPIException(
             _eh.ERR_ARG, "alltoallv_arr: capacity (the static length of "
             "the result, MPI's receive buffer) must be given "
             "(MPI_ERR_ARG)")
-    meta = np.empty((4, size), np.int64)
-    for row, (given, of) in enumerate(
-            ((scounts, None), (sdispls, 0), (rcounts, None), (rdispls, 2))):
-        if given is None and of is not None:
-            meta[row, 0] = 0
-            np.cumsum(meta[of, :-1], out=meta[row, 1:])
-            continue
-        v = np.asarray(given)
-        if v.shape != (size,) or v.dtype.kind not in "iu":
-            raise _eh.MPIException(
-                _eh.ERR_COUNT, f"alltoallv_arr: {_ROWS[row]} must be "
-                f"{size} integers, one a rank (MPI_ERR_COUNT)")
-        meta[row] = v
-    if meta.min() < 0:
+    sc = _row(scounts, size, 0)
+    sd = (0, *accumulate(sc[:-1])) if sdispls is None \
+        else _row(sdispls, size, 1)
+    rc = _row(rcounts, size, 2)
+    rd = (0, *accumulate(rc[:-1])) if rdispls is None \
+        else _row(rdispls, size, 3)
+    if min(min(sc), min(sd), min(rc), min(rd)) < 0:
         raise _eh.MPIException(
             _eh.ERR_COUNT, "alltoallv_arr: a negative count or "
             "displacement (MPI_ERR_COUNT)")
-    if (meta[1] + meta[0]).max() > length:
+    end = max(map(add, sd, sc))
+    if end > length:
         raise _eh.MPIException(
             _eh.ERR_BUFFER, f"alltoallv_arr: a send block ends at "
-            f"{int((meta[1] + meta[0]).max())}, past the {length} "
-            "elements of the send buffer (MPI_ERR_BUFFER)")
-    if (meta[3] + meta[2]).max() > capacity:
+            f"{end}, past the {length} elements of the send buffer "
+            "(MPI_ERR_BUFFER)")
+    end = max(map(add, rd, rc))
+    if end > capacity:
         raise _eh.MPIException(
             _eh.ERR_TRUNCATE, f"alltoallv_arr: a receive block ends at "
-            f"{int((meta[3] + meta[2]).max())}, past the capacity of "
-            f"{capacity} elements (MPI_ERR_TRUNCATE)")
-    return meta
+            f"{end}, past the capacity of {capacity} elements "
+            "(MPI_ERR_TRUNCATE)")
+    return sc, sd, rc, rd, sum(sc)
 
 
-def operand(deposits) -> np.ndarray:
+def operand(deposits, longest: int) -> np.ndarray:
     """The program's int32 operand from the P deposits, indexed
     ``[what, source, destination]``: how many elements, from where in
     the source's buffer, to where in the destination's result.  Checks
     what only the meeting can: that what rank i states it sends to j is
-    what j states it receives from i."""
-    metas = np.stack([d.meta for d in deposits])         # (P, 4, P)
-    counts = metas[:, 0, :]
-    if not np.array_equal(counts, metas[:, 2, :].T):
-        i, j = np.argwhere(counts != metas[:, 2, :].T)[0]
+    what j states it receives from i, and that ``longest`` (of the
+    deposits' lengths and capacities) fits an int32."""
+    metas = [d.meta for d in deposits]
+    sends = [m[0] for m in metas]
+    expects = list(zip(*[m[2] for m in metas]))     # [i][j]: j's of i
+    if sends != expects:
+        i, j = next((i, j) for i, (s, e) in enumerate(zip(sends, expects))
+                    for j in range(len(s)) if s[j] != e[j])
         raise _eh.MPIException(
-            _eh.ERR_COUNT, f"alltoallv_arr: rank {i} sends {counts[i, j]} "
-            f"elements to rank {j}, which expects {metas[j, 2, i]} "
+            _eh.ERR_COUNT, f"alltoallv_arr: rank {i} sends {sends[i][j]} "
+            f"elements to rank {j}, which expects {expects[i][j]} "
             "(MPI_ERR_COUNT)")
-    if max(max(d.x.shape[0], d.capacity) for d in deposits) >= 1 << 31:
+    if longest >= 1 << 31:
         raise _eh.MPIException(
             _eh.ERR_COUNT, "alltoallv_arr: a buffer of 2**31 elements or "
             "more (MPI_ERR_COUNT)")
-    return np.stack([counts, metas[:, 1, :], metas[:, 3, :].T]).astype(
-        np.int32)
+    flat = []                   # row-major [what, source, destination]
+    for row in sends:
+        flat += row
+    for m in metas:
+        flat += m[1]
+    for column in zip(*[m[3] for m in metas]):
+        flat += column
+    P = len(metas)
+    return np.frombuffer(_int32s(3 * P * P).pack(*flat),
+                         np.int32).reshape(3, P, P)
+
+
+@functools.lru_cache(maxsize=None)
+def _int32s(n: int) -> struct.Struct:
+    """The packer of ``n`` native int32s (the format parsed once)."""
+    return struct.Struct(f"={n}i")
 
 
 def chunk_of(length: int, capacity: int, size: int, chunk: int,
@@ -246,23 +287,25 @@ def alltoallv_arr(comm, entry, x, scounts, rcounts, sdispls, rdispls,
     this device would not hold whole (runtime/x64), hand the shim the
     elements sent and the capacity for the call's ``coll`` span, and
     call the winning provider's entry with the call's ``meta``."""
-    if not hasattr(x, "dtype"):
+    dtype = getattr(x, "dtype", None)
+    if dtype is None:
         x = np.asarray(x)
-    if x.ndim != 1:
+        dtype = x.dtype
+    shape = x.shape
+    if len(shape) != 1:
         raise _eh.MPIException(
             _eh.ERR_BUFFER, "alltoallv_arr: the send buffer is a 1-D "
-            f"array of elements, not of shape {tuple(x.shape)} "
+            f"array of elements, not of shape {tuple(shape)} "
             "(MPI_ERR_BUFFER)")
-    if x.dtype.itemsize >= 8 and comm.state.device is not None:
-        from ompi_tpu.runtime import x64
-        x64.check(x.dtype, "alltoallv_arr")
-    meta = arguments(comm.size, x.shape[0], scounts, rcounts, sdispls,
+    if dtype.itemsize >= 8 and comm.state.device is not None:
+        _x64.check(dtype, "alltoallv_arr")
+    meta = arguments(comm.size, shape[0], scounts, rcounts, sdispls,
                      rdispls, capacity)
     capacity = int(capacity)
     tr = comm.state.tracer
     if tr is None:
         return entry(comm, x, meta, capacity)
-    tr.coll_args = {"elems": int(meta[0].sum()), "capacity": capacity}
+    tr.coll_args = {"elems": meta[SENT], "capacity": capacity}
     try:
         return entry(comm, x, meta, capacity)
     finally:

@@ -55,6 +55,15 @@ MAX_RESPAWN_EPOCHS = 1024
 SESSION_CID_STRIDE = MAX_RESPAWN_EPOCHS * EPOCH_CID_STRIDE
 
 
+def _alltoallv_arr(*args):
+    """coll/ragged.alltoallv_arr, bound here on the first call: no
+    later call imports, and importing this module does not import the
+    coll package."""
+    global _alltoallv_arr
+    from ompi_tpu.coll.ragged import alltoallv_arr as _alltoallv_arr
+    return _alltoallv_arr(*args)
+
+
 class Group:
     """Dense ordered set of global ranks (ref: ompi/group)."""
 
@@ -913,9 +922,8 @@ class Communicator:
         rcounts[i])`` is what rank i sent here, bit for bit; the rest
         is not part of the result.  A new count matrix compiles
         nothing (coll/ragged.py)."""
-        from ompi_tpu.coll.ragged import alltoallv_arr
-        return alltoallv_arr(self, self.coll.alltoallv_arr, x, scounts,
-                             rcounts, sdispls, rdispls, capacity)
+        return _alltoallv_arr(self, self.coll.alltoallv_arr, x, scounts,
+                              rcounts, sdispls, rdispls, capacity)
 
     def reduce_scatter_arr(self, x, op, datatype=None, count=None):
         if datatype is None:

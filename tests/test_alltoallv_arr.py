@@ -3,7 +3,9 @@ on device arrays, served by coll/hbm in ONE program whose counts are
 operands.  NAS Parallel Benchmarks IS class S on 8 ranks against
 cellbench/reference_ragged.py, exact, then IS's full verification; the
 argument contract case by case; 40 count matrices, one program; the
-host-staged fallback; the counters and the span."""
+host-staged fallback; the counters and the span; the host path's
+plain-integer counts (ISSUE 42) against the numpy formulas they
+replaced, kept here as the reference."""
 
 import numpy as np
 import pytest
@@ -429,3 +431,184 @@ def test_the_program_compiles_for_the_chip_to_one_pass_a_chunk(one_v5e):
         r"[^\n]*?\"zeroes\":\"1023\"", txt)
     assert len(fused) >= P * P
     assert not re.search(r"s32\[(%d|%d)\]\S* copy" % (n, cap), txt)
+
+
+def numpy_arguments(size, length, scounts, rcounts, sdispls, rdispls,
+                    capacity):
+    """The (4, size) int64 ``meta`` and the refusals of PR 40's
+    arguments(), in numpy, as the reference of ragged.arguments."""
+    if capacity is None:
+        raise eh.MPIException(
+            eh.ERR_ARG, "alltoallv_arr: capacity (the static length of "
+            "the result, MPI's receive buffer) must be given "
+            "(MPI_ERR_ARG)")
+    meta = np.empty((4, size), np.int64)
+    for row, (given, of) in enumerate(
+            ((scounts, None), (sdispls, 0), (rcounts, None), (rdispls, 2))):
+        if given is None and of is not None:
+            meta[row, 0] = 0
+            np.cumsum(meta[of, :-1], out=meta[row, 1:])
+            continue
+        v = np.asarray(given)
+        if v.shape != (size,) or v.dtype.kind not in "iu":
+            raise eh.MPIException(
+                eh.ERR_COUNT, f"alltoallv_arr: {ragged._ROWS[row]} must be "
+                f"{size} integers, one a rank (MPI_ERR_COUNT)")
+        meta[row] = v
+    if meta.min() < 0:
+        raise eh.MPIException(
+            eh.ERR_COUNT, "alltoallv_arr: a negative count or "
+            "displacement (MPI_ERR_COUNT)")
+    if (meta[1] + meta[0]).max() > length:
+        raise eh.MPIException(
+            eh.ERR_BUFFER, f"alltoallv_arr: a send block ends at "
+            f"{int((meta[1] + meta[0]).max())}, past the {length} "
+            "elements of the send buffer (MPI_ERR_BUFFER)")
+    if (meta[3] + meta[2]).max() > capacity:
+        raise eh.MPIException(
+            eh.ERR_TRUNCATE, f"alltoallv_arr: a receive block ends at "
+            f"{int((meta[3] + meta[2]).max())}, past the capacity of "
+            f"{capacity} elements (MPI_ERR_TRUNCATE)")
+    return meta
+
+
+def numpy_operand(metas, longest):
+    """PR 40's operand(): the (3, P, P) int32 from the P (4, P) int64
+    metas, with its two refusals."""
+    metas = np.stack(metas)                               # (P, 4, P)
+    counts = metas[:, 0, :]
+    if not np.array_equal(counts, metas[:, 2, :].T):
+        i, j = np.argwhere(counts != metas[:, 2, :].T)[0]
+        raise eh.MPIException(
+            eh.ERR_COUNT, f"alltoallv_arr: rank {i} sends {counts[i, j]} "
+            f"elements to rank {j}, which expects {metas[j, 2, i]} "
+            "(MPI_ERR_COUNT)")
+    if longest >= 1 << 31:
+        raise eh.MPIException(
+            eh.ERR_COUNT, "alltoallv_arr: a buffer of 2**31 elements or "
+            "more (MPI_ERR_COUNT)")
+    return np.stack([counts, metas[:, 1, :], metas[:, 3, :].T]).astype(
+        np.int32)
+
+
+def outcome(fn, *args):
+    """(result, None) or (None, (error class, message))."""
+    try:
+        return fn(*args), None
+    except eh.MPIException as e:
+        return None, (e.error_class, str(e))
+
+
+FORMS = {
+    "numpy-int64": lambda v: np.asarray(v, np.int64),
+    "numpy-int32": lambda v: np.asarray(v, np.int32),
+    "numpy-uint32": lambda v: np.asarray(v, np.uint32),
+    "list": lambda v: [int(c) for c in v],
+    "tuple": lambda v: tuple(int(c) for c in v),
+    "list-of-numpy-ints": lambda v: list(np.asarray(v, np.int64)),
+}
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_every_form_of_the_counts_gives_one_meta_and_one_answer(form):
+    """Counts and displacements given as numpy int64, int32 or uint32,
+    as a list or a tuple: the same plain-integer ``meta`` as the numpy
+    reference, and the same answers on the device."""
+    P, n = 4, 300
+    rng = np.random.default_rng(sorted(FORMS).index(form) + 420)
+    counts = random_counts(rng, P, n, zeros=0.2)
+    sd = packed(counts) + 3 * np.arange(P)
+    rd = packed(counts.T)
+    cap = int(rd[:, -1].max() + counts[-1].max()) + 5
+    to = FORMS[form]
+    for r in range(P):
+        for sdispls, rdispls in ((None, None), (to(sd[r]), to(rd[r]))):
+            meta = ragged.arguments(P, n + 3 * P, to(counts[r]),
+                                    to(counts[:, r]), sdispls, rdispls, cap)
+            want = numpy_arguments(P, n + 3 * P, counts[r], counts[:, r],
+                                   None if sdispls is None else sd[r],
+                                   None if rdispls is None else rd[r], cap)
+            assert len(meta) == 5 and meta[ragged.SENT] == int(want[0].sum())
+            assert all(type(v) is int for row in meta[:4] for v in row)
+            assert np.array_equal(np.array(meta[:4]), want)
+    xs = [rng.integers(0, 1 << 20, n + 3 * P).astype(np.int32)
+          for _ in range(P)]
+
+    def fn(comm):
+        r = comm.rank
+        out = comm.alltoallv_arr(
+            jax.device_put(xs[r], comm.device), to(counts[r]),
+            to(counts[:, r]), to(sd[r]), to(rd[r]), capacity=cap)
+        return np.asarray(out), comm.coll.providers["alltoallv_arr"]
+
+    for r, (out, prov) in enumerate(one_chip(P, fn)):
+        want, live = expected(xs, counts, sd, rd, r, cap)
+        assert prov == "hbm" and np.array_equal(out[live], want[live])
+
+
+REFUSALS = {
+    # (scounts, rcounts, sdispls, rdispls, capacity) of a 2-rank call
+    # whose send buffer is 10 elements
+    "wrong-length": ([5, 5, 0], [5, 5], None, None, 10),
+    "wrong-length-rdispls": ([5, 5], [5, 5], None, [0, 5, 9], 10),
+    "a-float-array": (np.array([5.0, 5.0]), [5, 5], None, None, 10),
+    "bools": ([True, False], [5, 5], None, None, 10),
+    "a-negative-count": ([5, -5], [5, 5], None, None, 10),
+    "a-negative-displacement": ([5, 5], [5, 5], [-1, 5], None, 10),
+    "past-the-buffer": ([5, 6], [5, 5], None, None, 10),
+    "past-the-buffer-by-displacement": ([5, 5], [5, 5], [0, 6], None, 10),
+    "past-the-capacity": ([5, 5], [5, 6], None, None, 10),
+    "past-the-capacity-by-displacement": ([5, 5], [5, 5], None, [6, 0], 10),
+    "no-capacity": ([5, 5], [5, 5], None, None, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REFUSALS))
+def test_every_refusal_keeps_its_class_and_message(case):
+    """Each refusal of the entry raises the numpy reference's MPI error
+    class and message, on the caller's own rank, before any rank
+    waits."""
+    sc, rc, sd, rd, cap = REFUSALS[case]
+    _, want = outcome(numpy_arguments, 2, 10, sc, rc, sd, rd, cap)
+    assert want is not None
+    assert outcome(ragged.arguments, 2, 10, sc, rc, sd, rd, cap)[1] == want
+    x = np.arange(10, dtype=np.int32)
+
+    def fn(comm):
+        _, got = outcome(comm.alltoallv_arr, x, sc, rc, sd, rd, cap)
+        return got
+
+    assert one_chip(2, fn) == [want, want]
+
+
+@pytest.mark.parametrize("seed", range(40))
+def test_the_operand_is_the_numpy_formulas(seed):
+    """40 random count matrices (zeros, gaps, any order, every third
+    one a count that does not meet): the plain-integer operand equals
+    the numpy formula it replaced, and a refusal is the same
+    refusal."""
+    rng = np.random.default_rng(4200 + seed)
+    P = int(rng.integers(2, 9))
+    n = int(rng.integers(0, 5000))
+    counts = random_counts(rng, P, n, zeros=0.3 * (seed % 3))
+    sd = packed(counts) + rng.integers(0, 50, (P, P)).cumsum(1)
+    rd = np.stack([rng.permutation(P) for _ in range(P)]) * (n + 50)
+    rc = counts.T.copy()
+    if seed % 3 == 2 and n:
+        i, j = rng.integers(0, P, 2)
+        rc[j, i] += 1
+    length = int((sd + counts).max()) + 1
+    cap = int((rd + rc).max()) + 1
+    metas = [ragged.arguments(P, length, counts[r], rc[r], sd[r], rd[r],
+                              cap) for r in range(P)]
+    deps = [ragged.Deposit(np.zeros(length, np.int32), m, cap)
+            for m in metas]
+    ref = [numpy_arguments(P, length, counts[r], rc[r], sd[r], rd[r], cap)
+           for r in range(P)]
+    for longest in (max(length, cap), 1 << 31):
+        got, err = outcome(ragged.operand, deps, longest)
+        want, werr = outcome(numpy_operand, ref, longest)
+        assert err == werr
+        if werr is None:
+            assert got.dtype == np.int32 and got.shape == (3, P, P)
+            assert np.array_equal(got, want)
