@@ -33,6 +33,7 @@ import numpy as np
 
 import ompi_tpu
 from ompi_tpu import osc
+from ompi_tpu.coll import ragged
 from ompi_tpu.mca.params import registry
 from ompi_tpu.op import op as mpi_op
 
@@ -275,11 +276,14 @@ class Smoke:
         its keys, the ranks split the buckets so that each owns about
         NUM_KEYS keys, and one ``alltoallv_arr`` moves every key to its
         owner.  The counts are host integers and differ for every pair;
-        coll/hbm serves the call with one program whose counts are
-        operands (``coll_alltoallv_device_ops`` moves once a rank-call).
+        coll/hbm on one chip, and coll/tpu over a mesh of chips, serve
+        the call with one program whose counts are operands
+        (``coll_alltoallv_device_ops`` moves once a rank-call).
         The receive buffer is IS's SIZE_OF_BUFFERS; what lies past the
         received keys is not part of the result, so the comparison
-        takes the received keys alone."""
+        takes the received keys alone.  Over a mesh a key travels as a
+        row of 128 int32 (key x 128 + column): the narrowest row the
+        mesh program moves (coll/ragged.MESH_ROW_BYTES)."""
         p, oid = self.size, self.op_id + 1
         nkeys, shift, nb = (1 << 16) // p, 2, 1 << 9
         cap = 3 * nkeys // 2
@@ -297,10 +301,13 @@ class Smoke:
         counts = np.diff(upto, axis=1)        # [source, destination]
         buff1 = [k[np.argsort(k >> shift, kind="stable")] for k in ks]
         at = np.cumsum(counts, axis=1) - counts
+        if self.comm.coll.providers.get("alltoallv_arr") != "hbm":
+            cols = np.arange(128, dtype=np.int32)
+            buff1 = [b[:, None] * 128 + cols for b in buff1]
         me = self.rank
         self.collective(
-            "alltoallv_is_class_s", "alltoallv_arr", nkeys * 4, np.int32,
-            lambda r: buff1[r],
+            "alltoallv_is_class_s", "alltoallv_arr", buff1[0].nbytes,
+            np.int32, lambda r: buff1[r],
             lambda x: self.comm.alltoallv_arr(
                 x, counts[me], counts[:, me],
                 capacity=cap)[:int(counts[:, me].sum())],
@@ -610,12 +617,12 @@ def main():
     sm.fused_batch()
     sm.bcast()
     sm.alltoall()
-    if comm.coll.providers.get("alltoallv_arr") == "hbm":
+    if comm.coll.providers.get("alltoallv_arr") == "hbm" \
+            or platform not in ragged.NO_LOWERING:
         sm.alltoallv()
     else:
-        sm.say("alltoallv_is_class_s: skipped, one rank a chip, where "
-               "alltoallv_arr is host-staged until its mesh half exists "
-               "(ROADMAP M2)")
+        sm.say("alltoallv_is_class_s: skipped, one rank a device of "
+               "XLA:CPU, which cannot lower coll/tpu's ragged-all-to-all")
     sm.reduce_scatter()
     c5 = sm.config5()
     sm.allgather()

@@ -1211,8 +1211,10 @@ class TpuCollModule(CollModule):
         return _x64.put(arr, comm.state.device, "device collective")
 
     def _run(self, comm, value, fn, ck=None):
-        out = meet(comm, self._deposit(comm, value), fn,
-                   self._abort_check(comm), ck)
+        return self._meet(comm, self._deposit(comm, value), fn, ck)
+
+    def _meet(self, comm, value, fn, ck=None):
+        out = meet(comm, value, fn, self._abort_check(comm), ck)
         self.pvar_offload.add(1)
         return out
 
@@ -1324,11 +1326,88 @@ class TpuCollModule(CollModule):
         ck = _ig.spec("alltoall", "", x) if _ig.on else None
         return self._run(comm, x, fn, ck)
 
+    def _ragged_eligible(self, comm, x) -> bool:
+        """THE rule of what the mesh serves of ``alltoallv_arr``: every
+        rank on its own chip of the comm's mesh, a platform whose
+        compiler lowers ``ragged-all-to-all`` (XLA:CPU does not),
+        elements of 2, 4 or 8 bytes and rows of at least
+        ``ragged.MESH_ROW_BYTES``, all of which MPI makes the same on
+        every rank.  Counts, displacements, lengths and capacities are
+        not asked: the meeting sees them all (``_ragged_mesh``)."""
+        if x.dtype.itemsize not in _ragged.ITEMSIZES \
+                or _ragged.row_elems(x) * x.dtype.itemsize \
+                < _ragged.MESH_ROW_BYTES \
+                or not self._eligible(comm, x):
+            return False
+        return comm.mesh().devices.flat[0].platform \
+            not in _ragged.NO_LOWERING
+
+    def _ragged_mesh(self, comm) -> Callable:
+        """The meeting's computation of a mesh ``alltoallv_arr``: ONE
+        ``ompi_alltoallv_mesh`` program whose executable is resolved at
+        the meeting from the deposits' shape and capacity and kept by
+        them alone (no count is in any key: a new count matrix builds
+        nothing), and whose counts are its operand.  A mesh program's
+        shards are of one shape: deposits that differ in length or
+        capacity are served through the host (``ragged.through_host``),
+        counted there.  The device counters move here, where the path
+        is known, for every rank-call of the meeting."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        mesh, size = comm.mesh(), comm.size
+        sharding = NamedSharding(mesh, P("r"))
+        devs = list(mesh.devices.reshape(-1))
+        dev_key = tuple(d.id for d in devs)
+        staged = self.fallback.pvar_staged
+
+        def uniform(deposits) -> bool:
+            x, cap = deposits[0].x, deposits[0].capacity
+            return all(d.x.shape == x.shape and d.capacity == cap
+                       for d in deposits)
+
+        def launch(deposits, g):
+            x, cap = deposits[0].x, deposits[0].capacity
+            jfn = compile_cache.get(
+                ("alltoallv_mesh", dev_key, x.shape, x.dtype.str, cap),
+                lambda: _ragged.mesh_program(mesh, cap, sharding))
+            w = _ragged.row_elems(x)
+            out = jfn(_ragged.mesh_operand(
+                deposits, max(x.shape[0], cap) * w), g)
+            rows = sum(d.meta[_ragged.SENT] for d in deposits)
+            _ragged.pv_device_ops.add(size)
+            _ragged.pv_elems.add(rows * w)
+            _ragged.pv_bytes.add(rows * w * x.dtype.itemsize)
+            return out
+
+        def fn(deposits):
+            if not uniform(deposits):
+                return _ragged.through_host(deposits, devs, staged)
+            g = _assemble(mesh, [d.x for d in deposits], sharding)
+            return _scatter_out(launch(deposits, g), mesh, size)
+
+        def traced(deposits, ph):
+            # the phase profiler's twin (_phase_fn): assemble, launch
+            # and scatter banked as a compiled mesh plan's are
+            if not uniform(deposits):
+                return _ragged.through_host(deposits, devs, staged)
+            return _mesh_exec(mesh, size, functools.partial(launch, deposits),
+                              sharding, [d.x for d in deposits], ph)
+
+        fn.traced = traced
+        return fn
+
     def alltoallv_arr(self, comm, x, meta, capacity: int):
-        # one rank a chip: the mesh half of the ragged exchange
-        # (lax.ragged_all_to_all, or all_to_all padded to a capacity)
-        # is not built; ICI has to judge it (ROADMAP M2, R2)
-        return self.fallback.alltoallv_arr(comm, x, meta, capacity)
+        """One rendezvous and one ``ompi_alltoallv_mesh`` program a
+        call over ICI; every rank's counts and offsets reach it as an
+        int32 operand (coll/ragged.py)."""
+        if not self._ragged_eligible(comm, x):
+            return self.fallback.alltoallv_arr(comm, x, meta, capacity)
+        fn = comm.__dict__.get("_tpu_ragged")
+        if fn is None:
+            fn = comm.__dict__["_tpu_ragged"] = self._ragged_mesh(comm)
+        return self._meet(
+            comm, _ragged.Deposit(self._deposit(comm, x), meta, capacity),
+            fn)
 
     def bcast_arr(self, comm, x, root: int):
         if not self._eligible(comm, x) \
@@ -1543,13 +1622,16 @@ class HbmCollModule(CollModule):
 
         def program(*deposits):
             xs = [d.x for d in deposits]
-            key = (*[x.shape[0] for x in xs], *[d.capacity for d in deposits])
+            key = (*[x.shape for x in xs], *[d.capacity for d in deposits])
             jbody = progs.get(key)
             if jbody is None:
                 P = len(xs)
                 jbody = progs[key] = self._stacked(
                     "alltoallv", "", P, key[:P], dtype, key[P:])[0]
-            return jbody(_ragged.operand(deposits, max(key)), *xs)
+            longest = max(max(x.shape[0] for x in xs),
+                          max(d.capacity for d in deposits))
+            return jbody(_ragged.operand(
+                deposits, longest * _ragged.row_elems(xs[0])), *xs)
 
         return program, _per_rank
 
@@ -1659,7 +1741,7 @@ class HbmCollModule(CollModule):
         return self._run(comm, "alltoall", "", x)
 
     def _ragged_eligible(self, comm, x) -> bool:
-        """THE rule of what the device serves of ``alltoallv_arr``:
+        """THE rule of what the chip serves of ``alltoallv_arr``:
         every rank on this one chip and elements of 2, 4 or 8 bytes
         (8 as the carrier runtime/x64 states; the entry refused what
         jax would narrow), both of which MPI makes the same on every
@@ -1673,12 +1755,15 @@ class HbmCollModule(CollModule):
 
     def alltoallv_arr(self, comm, x, meta, capacity: int):
         """One rendezvous and one ``ompi_alltoallv`` program a call;
-        the counts of all ranks reach it as an int32 operand."""
+        the counts of all ranks reach it as an int32 operand.  Rows
+        travel on the flat view (coll/ragged.body)."""
         if not self._ragged_eligible(comm, x):
             return self.fallback.alltoallv_arr(comm, x, meta, capacity)
         out = self._run(comm, "alltoallv", "", x, capacity, meta)
+        elems = meta[_ragged.SENT] * _ragged.row_elems(x)
         _ragged.pv_device_ops.add(1)
-        _ragged.pv_elems.add(meta[_ragged.SENT])
+        _ragged.pv_elems.add(elems)
+        _ragged.pv_bytes.add(elems * x.dtype.itemsize)
         return out
 
     def bcast_arr(self, comm, x, root: int):
@@ -1835,16 +1920,18 @@ class HostArrModule(CollModule):
     def alltoallv_arr(self, comm, x, meta, capacity: int):
         """Stage, run the p2p stack's alltoallv, put back.  The
         elements travel as unsigned integers of their width (data
-        movement: no element type the host's datatype engine lacks);
-        what no block covers comes back as zeros."""
-        a = self._np(x)
-        dt = a.dtype
-        a = a.view(np.dtype(f"u{dt.itemsize}"))
-        r = np.zeros(capacity, a.dtype)
-        sc, sd, rc, rd = meta[:_ragged.SENT]
+        movement: no element type the host's datatype engine lacks),
+        a row as that many of them; what no block covers comes back as
+        zeros."""
+        a = np.ascontiguousarray(self._np(x))
+        dt, row = a.dtype, a.shape[1:]
+        w = _ragged.row_elems(a)
+        a = a.reshape(-1).view(np.dtype(f"u{dt.itemsize}"))
+        r = np.zeros(capacity * w, a.dtype)
+        sc, sd, rc, rd = ([c * w for c in v] for v in meta[:_ragged.SENT])
         mpi_dt = self._dtype_of(a)
         self.p2p.alltoallv(comm, a, sc, sd, mpi_dt, r, rc, rd, mpi_dt)
-        return self._back(comm, r.view(dt))
+        return self._back(comm, r.view(dt).reshape((capacity, *row)))
 
     def reduce_scatter_block_arr(self, comm, x, op: Op, datatype=None,
                                  count=None):

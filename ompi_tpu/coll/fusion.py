@@ -303,6 +303,8 @@ def _build_fused_mesh(mesh, sig):
     def body(*xs):
         return tuple(_mesh_slot_outs(sig, xs))
 
+    # a stable program name for the device trace
+    body.__name__ = body.__qualname__ = "ompi_fused_mesh"
     nin = _mesh_nin(sig)
     return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("r"),) * nin,
@@ -366,6 +368,8 @@ def _build_fused_hbm(size, sig):
     def body(*xs):
         return tuple(_hbm_slot_outs(size, sig, xs))
 
+    # a stable program name for the device trace
+    body.__name__ = body.__qualname__ = "ompi_fused_hbm"
     return jax.jit(body)
 
 

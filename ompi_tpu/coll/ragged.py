@@ -4,23 +4,39 @@ MPI_Alltoallv on a functional array API.  The counts and displacements
 are host integers, as MPI's are; the receive buffer's size is a static
 ``capacity`` (MPI's receive buffer is the user's to size).  What a
 repartition by key calls every step (a bucket sort's key exchange, a
-shuffle, a join, expert-parallel token dispatch): the split is decided
-at run time and differs on every call.
+shuffle, a join, expert-parallel token dispatch and combine): the split
+is decided at run time and differs on every call.
+
+The send buffer may have rows: an array of any rank >= 1 whose leading
+dimension is what the counts count, as MPI counts in a contiguous row
+datatype (an expert-parallel token is a row of ``hidden`` features).
+Counts, displacements and ``capacity`` are in rows; the result is
+``(capacity, *x.shape[1:])``.  Every rank's rows have one shape and one
+element type (MPI's matching type signatures).
 
 So the counts are OPERANDS of the device program, never part of its
-key.  ``body`` builds the one program coll/hbm runs for P ranks on one
-chip: P x P block copies whose offsets and lengths come from an int32
-operand.  A copy of a dynamic length is a loop over chunks of a static
-length (``dynamic_slice`` fused into an in-place
-``dynamic_update_slice``), the last chunk laid back so that it ends
-where the copy ends, with the partial tiles at a block's two ends and
-every short block merged through a small window under a mask: nothing
-is read or written beyond a count.  The result buffers are never
-initialised: what lies outside the received blocks is not part of the
-result (MPI leaves it untouched).
+key, on either provider:
 
-PERF.md section 5 has the chip's readings of this body and of the ones
-it was chosen over.
+* coll/hbm (P ranks on one chip): ``body``, the ``ompi_alltoallv``
+  program, P x P block copies over the flat view of every buffer
+  (counts x elements a row) whose offsets and lengths come from an
+  int32 operand.  A copy of a dynamic length is a loop over chunks of
+  a static length (``dynamic_slice`` fused into an in-place
+  ``dynamic_update_slice``), the last chunk laid back so that it ends
+  where the copy ends, with the partial tiles at a block's two ends and
+  every short block merged through a small window under a mask:
+  nothing is read or written beyond a count.
+* coll/tpu (one rank a chip of a mesh): ``mesh_program``, the
+  ``ompi_alltoallv_mesh`` program, one ``lax.ragged_all_to_all`` over
+  ICI whose offsets and sizes are every rank's row of an int32 operand
+  (``mesh_operand``).  XLA:CPU cannot lower that collective
+  (``NO_LOWERING``), so a CPU mesh is served through the host.
+
+The result buffers are never initialised: what lies outside the
+received blocks is not part of the result (MPI leaves it untouched).
+
+PERF.md section 5 has the chip's readings of these bodies and of the
+ones they were chosen over.
 """
 from __future__ import annotations
 
@@ -37,12 +53,19 @@ from ompi_tpu.runtime import x64 as _x64
 
 pv_device_ops = registry.register_pvar(
     "coll", "alltoallv", "device_ops",
-    help="alltoallv_arr rank-calls served by the device program "
-         "(coll/hbm's ompi_alltoallv); once a rank-call")
+    help="alltoallv_arr rank-calls served by a device program "
+         "(coll/hbm's ompi_alltoallv on one chip, coll/tpu's "
+         "ompi_alltoallv_mesh over a mesh); once a rank-call")
 pv_elems = registry.register_pvar(
     "coll", "alltoallv", "elems",
     help="Elements the device-served alltoallv_arr rank-calls were asked "
-         "to send: the sum of their scounts, not of any padded bound")
+         "to send: the sum of their scounts times the elements of a row, "
+         "not of any padded bound")
+pv_bytes = registry.register_pvar(
+    "coll", "alltoallv", "bytes",
+    help="Bytes the device-served alltoallv_arr rank-calls were asked to "
+         "send: the sum of their scounts times the bytes of a row, not "
+         "of any padded bound")
 
 #: the longest chunk of a block copy, in elements (PERF.md section 5)
 CHUNK = 1 << 19
@@ -50,13 +73,29 @@ CHUNK = 1 << 19
 #: chunk whose DESTINATION offset is known to be a multiple of it is
 #: copied in one pass (``body``)
 TILE = 1024
-#: element sizes the device program moves (8-byte ones as the carrier
+#: element sizes the device programs move (8-byte ones as the carrier
 #: runtime/x64 states; the entry has refused what jax would narrow)
 ITEMSIZES = (2, 4, 8)
+#: platforms whose compiler cannot lower ``ragged-all-to-all``: a mesh
+#: of their devices is served through the host
+NO_LOWERING = ("cpu",)
+#: the narrowest row the mesh program moves, in bytes: one tile of 128
+#: 32-bit lanes.  The chip's ``ragged-all-to-all`` lays every row out on
+#: whole tiles, so a narrower row costs up to 128 times its bytes (a
+#: 1-D buffer of 16 Mi keys would not fit a chip)
+MESH_ROW_BYTES = 512
 _ROWS = ("scounts", "sdispls", "rcounts", "rdispls")
-#: ``meta[SENT]``: the elements a rank-call sends, summed once
+#: ``meta[SENT]``: the rows a rank-call sends, summed once
 SENT = 4
 _SEQUENCES = (list, tuple)
+
+
+def row_elems(x) -> int:
+    """Elements of one row of ``x`` (1 for a 1-D buffer)."""
+    n = 1
+    for d in x.shape[1:]:
+        n *= d
+    return n
 
 
 class Deposit:
@@ -71,7 +110,7 @@ class Deposit:
         self.meta = meta
         self.capacity = capacity
         # what the offload accounting reports as moved: the bytes sent
-        self.nbytes = meta[SENT] * x.dtype.itemsize
+        self.nbytes = meta[SENT] * row_elems(x) * x.dtype.itemsize
 
     def flipped(self, flip) -> "Deposit":
         """The deposit a corrupting chip would have made
@@ -135,13 +174,19 @@ def arguments(size: int, length: int, scounts, rcounts, sdispls, rdispls,
     return sc, sd, rc, rd, sum(sc)
 
 
-def operand(deposits, longest: int) -> np.ndarray:
-    """The program's int32 operand from the P deposits, indexed
-    ``[what, source, destination]``: how many elements, from where in
-    the source's buffer, to where in the destination's result.  Checks
-    what only the meeting can: that what rank i states it sends to j is
+def _checked(deposits, longest: int) -> list:
+    """The deposits' ``meta``s, after what only the meeting can check:
+    that every rank's rows have one shape and one element type (MPI's
+    matching type signatures), that what rank i states it sends to j is
     what j states it receives from i, and that ``longest`` (of the
-    deposits' lengths and capacities) fits an int32."""
+    deposits' lengths and capacities, in elements) fits an int32."""
+    x0 = deposits[0].x
+    for i, d in enumerate(deposits):
+        if d.x.shape[1:] != x0.shape[1:] or d.x.dtype != x0.dtype:
+            raise _eh.MPIException(
+                _eh.ERR_TYPE, f"alltoallv_arr: rank {i} sends rows of "
+                f"{tuple(d.x.shape[1:])} {d.x.dtype}, rank 0 of "
+                f"{tuple(x0.shape[1:])} {x0.dtype} (MPI_ERR_TYPE)")
     metas = [d.meta for d in deposits]
     sends = [m[0] for m in metas]
     expects = list(zip(*[m[2] for m in metas]))     # [i][j]: j's of i
@@ -156,9 +201,17 @@ def operand(deposits, longest: int) -> np.ndarray:
         raise _eh.MPIException(
             _eh.ERR_COUNT, "alltoallv_arr: a buffer of 2**31 elements or "
             "more (MPI_ERR_COUNT)")
+    return metas
+
+
+def operand(deposits, longest: int) -> np.ndarray:
+    """coll/hbm's int32 operand from the P deposits, indexed ``[what,
+    source, destination]``: how many rows, from where in the source's
+    buffer, to where in the destination's result (``_checked`` first)."""
+    metas = _checked(deposits, longest)
     flat = []                   # row-major [what, source, destination]
-    for row in sends:
-        flat += row
+    for m in metas:
+        flat += m[0]
     for m in metas:
         flat += m[1]
     for column in zip(*[m[3] for m in metas]):
@@ -166,6 +219,25 @@ def operand(deposits, longest: int) -> np.ndarray:
     P = len(metas)
     return np.frombuffer(_int32s(3 * P * P).pack(*flat),
                          np.int32).reshape(3, P, P)
+
+
+def mesh_operand(deposits, longest: int) -> np.ndarray:
+    """coll/tpu's int32 operand from the P deposits, ``(P, 4, P)``: rank
+    i's row is what ``lax.ragged_all_to_all`` asks of it, in rows: where
+    in its buffer each block starts, how long it is, where in the
+    receiver's result it goes (rank j's ``rdispls[i]``, which only the
+    meeting knows), and how many rows come from each rank
+    (``_checked`` first)."""
+    metas = _checked(deposits, longest)
+    flat = []
+    for m, lands in zip(metas, zip(*[m[3] for m in metas])):
+        flat += m[1]
+        flat += m[0]
+        flat += lands
+        flat += m[2]
+    P = len(metas)
+    return np.frombuffer(_int32s(4 * P * P).pack(*flat),
+                         np.int32).reshape(P, 4, P)
 
 
 @functools.lru_cache(maxsize=None)
@@ -206,8 +278,10 @@ def _merge(x, o, c, s, r, k: int):
 def body(capacities):
     """``ompi_alltoallv(meta, *xs) -> P results``: the program of one
     ragged exchange among P ranks of one chip.  ``capacities`` (a
-    result's length, a rank) and the lengths of ``xs`` are static;
-    every count and offset is read from ``meta`` (``operand``).
+    result's length, a rank) and the shapes of ``xs`` are static;
+    every count and offset is read from ``meta`` (``operand``).  Rows
+    are moved on the flat view of every buffer: the operand times the
+    elements of a row, the results shaped back to rows.
 
     A block x[s:s + c] -> out[r:r + c] is copied in three parts: a
     *middle* that starts at the first tile boundary of the DESTINATION
@@ -228,13 +302,13 @@ def body(capacities):
     import jax.numpy as jnp
     from jax import lax
 
-    def ompi_alltoallv(meta, *xs):
+    def flat(meta, xs, caps):
         size, dtype = len(xs), xs[0].dtype
         # the constants are read when the program is traced
         a = TILE * max(1, 4 // dtype.itemsize)
         aligned = jnp.uint32(~(a - 1) & 0xFFFFFFFF)
         outs = []
-        for j, cap in enumerate(capacities):
+        for j, cap in enumerate(caps):
             out = lax.empty((cap,), dtype)
             pairs = []
             for i, x in enumerate(xs):
@@ -278,25 +352,96 @@ def body(capacities):
             outs.append(out)
         return tuple(outs)
 
+    def ompi_alltoallv(meta, *xs):
+        row = xs[0].shape[1:]
+        if not row:
+            return flat(meta, xs, capacities)
+        w = row_elems(xs[0])
+        outs = flat(meta * w, [x.reshape(-1) for x in xs],
+                    [c * w for c in capacities])
+        return tuple(o.reshape((c, *row)) for o, c in zip(outs, capacities))
+
     return ompi_alltoallv
+
+
+def mesh_program(mesh, capacity: int, sharding):
+    """``ompi_alltoallv_mesh(meta, x)``: the ONE program of a ragged
+    exchange among the P ranks of ``mesh``, one a chip: every rank's
+    buffer is its shard of ``x`` and its row of ``meta``
+    (``mesh_operand``) its offsets and sizes, both on ``sharding``; the
+    result is a ``(capacity, *row)`` shard a rank.  An 8-byte element
+    travels as two 32-bit words (the compiler does not split a 64-bit
+    ragged-all-to-all), and a row as the ``(pack, words / pack)`` block
+    the chip's collective lays out on whole tiles, ``pack`` the 16-bit
+    words a 32-bit lane holds: one relayout copy in, one out."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    def ompi_alltoallv_mesh(meta, x):
+        m = meta[0]
+        n, dtype = x.shape[0], x.dtype
+        if dtype.itemsize == 8:
+            x = lax.bitcast_convert_type(x, jnp.uint32)
+        words = row_elems(x)
+        pack = 2 if x.dtype.itemsize == 2 and words % 2 == 0 else 1
+        block = (pack, words // pack)
+        out = _exchange(x.reshape((n, *block)),
+                        lax.empty((capacity, *block), x.dtype),
+                        m[0], m[1], m[2], m[3])
+        out = out.reshape((capacity, *x.shape[1:]))
+        return out if out.dtype == dtype \
+            else lax.bitcast_convert_type(out, dtype)
+
+    return jax.jit(jax.shard_map(ompi_alltoallv_mesh, mesh=mesh,
+                                 in_specs=(P("r"), P("r")),
+                                 out_specs=P("r"), check_vma=False),
+                   in_shardings=(sharding, sharding),
+                   out_shardings=sharding)
+
+
+def _exchange(x, out, offsets, sizes, lands, takes):
+    """The collective of ``ompi_alltoallv_mesh``, over the mesh axis."""
+    from jax import lax
+    return lax.ragged_all_to_all(x, out, offsets, sizes, lands, takes,
+                                 axis_name="r")
+
+
+def through_host(deposits, devices, staged) -> list:
+    """The meeting's answer where the deposits cannot be the shards of
+    one mesh program (MPI lets every rank size its own send buffer and
+    capacity): each block copied through host memory, each result put
+    back on its rank's device; ``staged`` counts the rank-calls."""
+    metas = _checked(deposits, 0)
+    xs = [np.asarray(d.x) for d in deposits]
+    outs = []
+    for j, d in enumerate(deposits):
+        out = np.zeros((d.capacity, *xs[j].shape[1:]), xs[j].dtype)
+        for i, (sc, sd, _rc, _rd, _n) in enumerate(metas):
+            r = metas[j][3][i]
+            out[r:r + sc[j]] = xs[i][sd[j]:sd[j] + sc[j]]
+        outs.append(_x64.put(out, devices[j], "alltoallv_arr"))
+    staged.add(len(deposits))
+    return outs
 
 
 def alltoallv_arr(comm, entry, x, scounts, rcounts, sdispls, rdispls,
                   capacity):
     """``comm.alltoallv_arr``: check the arguments, refuse an element
     this device would not hold whole (runtime/x64), hand the shim the
-    elements sent and the capacity for the call's ``coll`` span, and
-    call the winning provider's entry with the call's ``meta``."""
+    rows and elements sent, the bytes of a row and the capacity for the
+    call's ``coll`` span, and call the winning provider's entry with the
+    call's ``meta``."""
     dtype = getattr(x, "dtype", None)
     if dtype is None:
         x = np.asarray(x)
         dtype = x.dtype
     shape = x.shape
-    if len(shape) != 1:
+    if not shape:
         raise _eh.MPIException(
-            _eh.ERR_BUFFER, "alltoallv_arr: the send buffer is a 1-D "
-            f"array of elements, not of shape {tuple(shape)} "
-            "(MPI_ERR_BUFFER)")
+            _eh.ERR_BUFFER, "alltoallv_arr: the send buffer is an array "
+            "of rows, not a scalar (MPI_ERR_BUFFER)")
     if dtype.itemsize >= 8 and comm.state.device is not None:
         _x64.check(dtype, "alltoallv_arr")
     meta = arguments(comm.size, shape[0], scounts, rcounts, sdispls,
@@ -305,7 +450,9 @@ def alltoallv_arr(comm, entry, x, scounts, rcounts, sdispls, rdispls,
     tr = comm.state.tracer
     if tr is None:
         return entry(comm, x, meta, capacity)
-    tr.coll_args = {"elems": meta[SENT], "capacity": capacity}
+    w = row_elems(x)
+    tr.coll_args = {"rows": meta[SENT], "elems": meta[SENT] * w,
+                    "row_bytes": w * dtype.itemsize, "capacity": capacity}
     try:
         return entry(comm, x, meta, capacity)
     finally:

@@ -910,18 +910,20 @@ class Communicator:
 
     def alltoallv_arr(self, x, scounts, rcounts, sdispls=None, rdispls=None,
                       capacity=None):
-        """MPI_Alltoallv on a 1-D device array.  ``scounts`` /
-        ``rcounts`` (and the displacements, which default to their
-        exclusive prefix sums) are ``size`` host integers each, in
-        elements, with MPI's contract: what rank i states it sends to j
-        is what j states it receives from i.  ``capacity`` is the
-        static length of the result (MPI's receive buffer is the
-        user's to size); a receive that would pass it raises
-        MPI_ERR_TRUNCATE.  Returns ``capacity`` elements of ``x``'s
-        dtype on this rank's device: ``[rdispls[i], rdispls[i] +
-        rcounts[i])`` is what rank i sent here, bit for bit; the rest
-        is not part of the result.  A new count matrix compiles
-        nothing (coll/ragged.py)."""
+        """MPI_Alltoallv on a device array of rows: ``x`` of any rank
+        >= 1, whose leading dimension the counts count (a 1-D ``x`` is
+        rows of one element).  ``scounts`` / ``rcounts`` (and the
+        displacements, which default to their exclusive prefix sums)
+        are ``size`` host integers each, in rows, with MPI's contract:
+        what rank i states it sends to j is what j states it receives
+        from i, in rows of one shape and dtype.  ``capacity`` is the
+        static number of rows of the result (MPI's receive buffer is
+        the user's to size); a receive that would pass it raises
+        MPI_ERR_TRUNCATE.  Returns ``(capacity, *x.shape[1:])`` of
+        ``x``'s dtype on this rank's device: rows ``[rdispls[i],
+        rdispls[i] + rcounts[i])`` are what rank i sent here, bit for
+        bit; the rest is not part of the result.  A new count matrix
+        compiles nothing (coll/ragged.py)."""
         return _alltoallv_arr(self, self.coll.alltoallv_arr, x, scounts,
                               rcounts, sdispls, rdispls, capacity)
 

@@ -1,11 +1,19 @@
-"""``comm.alltoallv_arr`` (ISSUE 40, DESIGN.md section 12): MPI_Alltoallv
-on device arrays, served by coll/hbm in ONE program whose counts are
-operands.  NAS Parallel Benchmarks IS class S on 8 ranks against
+"""``comm.alltoallv_arr`` (DESIGN.md section 12): MPI_Alltoallv on
+device arrays of rows, served by coll/hbm on one chip and by coll/tpu
+over a mesh, each in ONE program whose counts are operands.  NAS
+Parallel Benchmarks IS class S on 8 ranks against
 cellbench/reference_ragged.py, exact, then IS's full verification; the
 argument contract case by case; 40 count matrices, one program; the
 host-staged fallback; the counters and the span; the host path's
-plain-integer counts (ISSUE 42) against the numpy formulas they
-replaced, kept here as the reference."""
+plain-integer counts against the numpy formulas they replaced, kept
+here as the reference.  Rows on both providers and the fallback; the
+mesh half's meeting (its operand against numpy formulas, one program
+for 40 count matrices, deposits of other shapes through the host) on
+CPU devices with its collective emulated, since XLA:CPU cannot lower
+``ragged-all-to-all``; and its program compiled for a described 2x2
+v5e."""
+
+import re
 
 import numpy as np
 import pytest
@@ -220,8 +228,8 @@ def test_capacity_met_exactly_and_passed_by_one():
 
 def test_what_the_entry_refuses():
     """A count matrix of the wrong length, a negative count, a send
-    block past the buffer, no capacity, a buffer that is not 1-D: each
-    raises on the caller's own side, before any rank waits."""
+    block past the buffer, no capacity, a scalar for a buffer of rows:
+    each raises on the caller's own side, before any rank waits."""
     x = np.arange(10, dtype=np.int32)
 
     def fn(comm):
@@ -231,7 +239,7 @@ def test_what_the_entry_refuses():
                 (([5, -5], [5, 5]), dict(capacity=10)),
                 (([5, 6], [5, 5]), dict(capacity=10)),
                 (([5, 5], [5, 5]), dict()),
-                (([5, 5], [5, 5]), dict(capacity=10, x=x.reshape(2, 5)))):
+                (([5, 5], [5, 5]), dict(capacity=10, x=x[0]))):
             with pytest.raises(eh.MPIException) as e:
                 comm.alltoallv_arr(kw.pop("x", x), *args, **kw)
             seen.append(e.value.error_class)
@@ -316,8 +324,9 @@ def test_ranks_of_different_lengths_and_capacities():
 @pytest.mark.parametrize("why", ["one-byte-elements", "ranks-across-devices"])
 def test_the_fallback_gives_the_same_answer_and_is_counted(why):
     """What the device does not serve (an element size outside
-    coll/ragged.ITEMSIZES; a comm whose ranks own different devices,
-    coll/tpu's, whose mesh half is not built) goes to HostArrModule:
+    coll/ragged.ITEMSIZES; a comm whose ranks own different devices of
+    XLA:CPU, which cannot lower coll/tpu's ragged-all-to-all) goes to
+    HostArrModule:
     the same answer, counted host-staged once a rank-call, the device
     counters at rest."""
     P, n = 4, 300
@@ -612,3 +621,345 @@ def test_the_operand_is_the_numpy_formulas(seed):
         if werr is None:
             assert got.dtype == np.int32 and got.shape == (3, P, P)
             assert np.array_equal(got, want)
+
+
+# -- rows, on both providers and through the fallback -------------------------
+
+def emulated(x, out, offsets, sizes, lands, takes):
+    """``lax.ragged_all_to_all``'s semantics in operations XLA:CPU
+    lowers (an all-gather and a masked gather a source), so that the
+    mesh half's meeting, operand, keys and layout run here: rank i's
+    rows ``[offsets[j], offsets[j] + sizes[j])`` land at ``lands[j]`` of
+    rank j's result."""
+    from jax import lax
+    me = lax.axis_index("r")
+    xs = lax.all_gather(x, "r")
+    offs, szs, lds = (lax.all_gather(v, "r") for v in (offsets, sizes, lands))
+    rows = lax.iota(jnp.int32, out.shape[0])
+    for i in range(xs.shape[0]):
+        o, c, at = offs[i, me], szs[i, me], lds[i, me]
+        src = jnp.clip(o + rows - at, 0, x.shape[0] - 1)
+        take = ((rows >= at) & (rows < at + c)).reshape(
+            (-1,) + (1,) * (out.ndim - 1))
+        out = jnp.where(take, xs[i][src], out)
+    return out
+
+
+@pytest.fixture
+def mesh_emulated(monkeypatch):
+    """coll/tpu's mesh half on this process's CPU devices, its one
+    collective emulated; the compile cache cleared on both sides."""
+    monkeypatch.setattr(ragged, "NO_LOWERING", ())
+    monkeypatch.setattr(ragged, "_exchange", emulated)
+    dev.compile_cache.clear()
+    yield
+    dev.compile_cache.clear()
+
+
+def mesh_world(P, fn, **kw):
+    """P ranks, each on its own device: coll/tpu's layout."""
+    return run_ranks(P, fn, devices=True, **kw)
+
+
+def rows_exchange(P, xs, counts, cap, sd=None, rd=None, world=one_chip):
+    """Run one exchange of rows; assert every rank's answer, shape and
+    device; return the providers."""
+    counts = np.asarray(counts)
+    sdp = packed(counts) if sd is None else np.asarray(sd)
+    rdp = packed(counts.T) if rd is None else np.asarray(rd)
+
+    def fn(comm):
+        r = comm.rank
+        out = comm.alltoallv_arr(
+            jax.device_put(xs[r], comm.device), counts[r], counts[:, r],
+            None if sd is None else sdp[r], None if rd is None else rdp[r],
+            capacity=cap)
+        assert isinstance(out, jax.Array) and comm.device in out.devices()
+        return np.asarray(out), comm.coll.providers["alltoallv_arr"]
+
+    res = world(P, fn)
+    for r, (out, _prov) in enumerate(res):
+        assert out.shape == (cap, *xs[0].shape[1:])
+        assert out.dtype == xs[0].dtype
+        want = np.zeros_like(out)
+        live = np.zeros(cap, bool)
+        for i in range(P):
+            c = counts[i][r]
+            want[rdp[r][i]:rdp[r][i] + c] = xs[i][sdp[i][r]:sdp[i][r] + c]
+            live[rdp[r][i]:rdp[r][i] + c] = True
+        assert np.array_equal(out[live].view(np.uint8),
+                              want[live].view(np.uint8)), r
+    return {prov for _o, prov in res}
+
+
+ROWS = {
+    # (row shape, dtype, how): one answer each; every row of 512 B or
+    # more but the 1-D one, which a mesh serves through the host
+    "1-D-int32": ((), "int32", "random"),
+    "rows-bfloat16": ((256,), "bfloat16", "random"),
+    "rows-uint32": ((2, 64), "uint32", "random"),
+    "rows-zero-counts": ((130,), "uint32", "zeros"),
+    "rows-with-gaps": ((160,), "float32", "gaps"),
+}
+PATHS = ("hbm", "mesh", "fallback")
+
+
+@pytest.mark.parametrize("path", PATHS)
+@pytest.mark.parametrize("case", sorted(ROWS))
+def test_rows_on_every_path(case, path, monkeypatch):
+    """A buffer of rows: counts, displacements and capacity in rows,
+    the result ``(capacity, *row)``, every received row bit for bit, on
+    coll/hbm (the flat view), coll/tpu's mesh program (its collective
+    emulated on CPU devices; a row under ``MESH_ROW_BYTES`` goes to the
+    host) and the host fallback (a CPU mesh, whose compiler cannot
+    lower the collective)."""
+    row, dtype, how = ROWS[case]
+    P, n = 4, 90
+    rng = np.random.default_rng(sorted(ROWS).index(case) + 4300)
+    counts = random_counts(rng, P, n, zeros=0.5 if how == "zeros" else 0.0)
+    if how == "zeros":
+        counts[1] = 0
+        counts[:, 2] = 0
+    xs = [rng.integers(0, 1 << 15, (n, *row)) for _ in range(P)]
+    xs = [np.asarray(jnp.asarray(x, jnp.float32).astype(dtype))
+          if dtype in ("bfloat16", "float32") else x.astype(dtype)
+          for x in xs]
+    sd = rd = None
+    cap = int(counts.sum(0).max()) + 5
+    if how == "gaps":
+        sd = packed(counts) + 2 * np.arange(P)
+        slot = int(counts.max()) + 3
+        rd = np.tile(slot * np.arange(P)[::-1], (P, 1))
+        xs = [np.concatenate([x, x[:2 * P]]) for x in xs]
+        cap = slot * P
+    if path == "mesh":
+        monkeypatch.setattr(ragged, "NO_LOWERING", ())
+        monkeypatch.setattr(ragged, "_exchange", emulated)
+        dev.compile_cache.clear()
+    staged = pvar("coll_arr_host_staged_collectives")
+    ops = pvar("coll_alltoallv_device_ops")
+    try:
+        provs = rows_exchange(P, xs, counts, cap, sd, rd,
+                              one_chip if path == "hbm" else mesh_world)
+    finally:
+        if path == "mesh":
+            dev.compile_cache.clear()
+    assert provs == {"hbm" if path == "hbm" else "tpu"}
+    on_device = path == "hbm" or (path == "mesh" and row)
+    assert pvar("coll_alltoallv_device_ops") - ops == (P if on_device else 0)
+    assert pvar("coll_arr_host_staged_collectives") - staged == (
+        0 if on_device else P)
+
+
+def test_the_counters_and_the_span_count_rows():
+    """``coll_alltoallv_elems`` moves by the rows sent times the
+    elements of a row, ``coll_alltoallv_bytes`` by the bytes (no padded
+    bound); the ``coll`` span carries the rows, the elements, the bytes
+    of a row and the capacity."""
+    P, n, cap, row = 4, 60, 150, (2, 7)
+    rng = np.random.default_rng(431)
+    counts = random_counts(rng, P, n, zeros=0.3)
+    xs = [rng.integers(0, 100, (n, *row)).astype(np.uint16)
+          for _ in range(P)]
+    ops, elems, nbytes = (pvar("coll_alltoallv_" + k)
+                          for k in ("device_ops", "elems", "bytes"))
+    saved = registry.get("trace_enable")
+    registry.set("trace_enable", True)
+    try:
+        def fn(comm):
+            r = comm.rank
+            comm.alltoallv_arr(xs[r], counts[r], counts[:, r], capacity=cap)
+            return [e for e in comm.state.tracer.snapshot()
+                    if e.get("name") == "alltoallv_arr"]
+
+        res = one_chip(P, fn)
+    finally:
+        registry.set("trace_enable", saved)
+    sent = int(counts.sum())
+    assert pvar("coll_alltoallv_device_ops") - ops == P
+    assert pvar("coll_alltoallv_elems") - elems == sent * 14
+    assert pvar("coll_alltoallv_bytes") - nbytes == sent * 28
+    for r, spans in enumerate(res):
+        args = spans[0]["args"]
+        assert (args["rows"], args["elems"], args["row_bytes"],
+                args["capacity"]) == (int(counts[r].sum()),
+                                      14 * int(counts[r].sum()), 28, cap)
+
+
+def test_rows_of_another_shape_are_refused_on_every_rank():
+    """Rows of another shape on one rank break MPI's matching type
+    signatures: only the meeting sees it, and every rank hears of it."""
+    def fn(comm):
+        x = np.zeros((8, 3 if comm.rank else 4), np.int32)
+        with pytest.raises(RuntimeError, match="MPI_ERR_TYPE"):
+            comm.alltoallv_arr(x, [4, 4], [4, 4], capacity=8)
+        return True
+
+    assert one_chip(2, fn) == [True, True]
+
+
+# -- the mesh half's meeting ---------------------------------------------------
+
+def numpy_mesh_operand(metas):
+    """What ``lax.ragged_all_to_all`` asks of rank i, from the (4, P)
+    numpy metas: its send displacements, its send counts, where in each
+    receiver's result its block lands (the receiver's rdispls, column
+    i), and its receive counts."""
+    metas = np.stack(metas)                               # (P, 4, P)
+    return np.stack([np.stack([m[1], m[0], metas[:, 3, i], m[2]])
+                     for i, m in enumerate(metas)]).astype(np.int32)
+
+
+@pytest.mark.parametrize("seed", range(20))
+def test_the_mesh_operand_is_the_numpy_formulas(seed):
+    """20 random count matrices (zeros, gaps, any order, every third
+    one a count that does not meet): the mesh operand equals the numpy
+    formula, and a refusal is the coll/hbm operand's refusal."""
+    rng = np.random.default_rng(4310 + seed)
+    P = int(rng.integers(2, 9))
+    n = int(rng.integers(0, 3000))
+    counts = random_counts(rng, P, n, zeros=0.3 * (seed % 3))
+    sd = packed(counts) + rng.integers(0, 40, (P, P)).cumsum(1)
+    rd = np.stack([rng.permutation(P) for _ in range(P)]) * (n + 40)
+    rc = counts.T.copy()
+    if seed % 3 == 2 and n:
+        i, j = rng.integers(0, P, 2)
+        rc[j, i] += 1
+    length = int((sd + counts).max()) + 1
+    cap = int((rd + rc).max()) + 1
+    metas = [ragged.arguments(P, length, counts[r], rc[r], sd[r], rd[r],
+                              cap) for r in range(P)]
+    deps = [ragged.Deposit(np.zeros((length, 3), np.uint16), m, cap)
+            for m in metas]
+    got, err = outcome(ragged.mesh_operand, deps, max(length, cap))
+    _, herr = outcome(ragged.operand, deps, max(length, cap))
+    assert err == herr
+    if err is None:
+        want = numpy_mesh_operand(
+            [numpy_arguments(P, length, counts[r], rc[r], sd[r], rd[r], cap)
+             for r in range(P)])
+        assert got.dtype == np.int32 and got.shape == (P, 4, P)
+        assert np.array_equal(got, want)
+
+
+def test_forty_count_matrices_build_one_mesh_program(mesh_emulated):
+    """40 calls over the mesh, 40 different count matrices: ONE
+    ompi_alltoallv_mesh program built and one meeting computation a
+    comm; every answer right; nothing host-staged."""
+    P, n, cap, calls, row = 4, 256, 1024, 40, (128,)
+    rng = np.random.default_rng(4340)
+    mats = [random_counts(rng, P, n, zeros=0.2 * (k % 3))
+            for k in range(calls)]
+    assert len({m.tobytes() for m in mats}) == calls
+    xs = [rng.integers(0, 1 << 30, (n, *row)).astype(np.int32)
+          for _ in range(P)]
+    builds = dev.compile_cache.builds
+    staged = pvar("coll_arr_host_staged_collectives")
+    ops = pvar("coll_alltoallv_device_ops")
+
+    def fn(comm):
+        x = jax.device_put(xs[comm.rank], comm.device)
+        outs = [np.asarray(comm.alltoallv_arr(
+            x, m[comm.rank], m[:, comm.rank], capacity=cap)) for m in mats]
+        return outs, comm.coll.providers["alltoallv_arr"]
+
+    res = mesh_world(P, fn)
+    assert dev.compile_cache.builds - builds == 1
+    assert pvar("coll_arr_host_staged_collectives") == staged
+    assert pvar("coll_alltoallv_device_ops") - ops == P * calls
+    for r, (outs, prov) in enumerate(res):
+        assert prov == "tpu"
+        for m, out in zip(mats, outs):
+            rd = packed(m.T)
+            for i in range(P):
+                c = m[i][r]
+                assert np.array_equal(
+                    out[rd[r][i]:rd[r][i] + c],
+                    xs[i][packed(m)[i][r]:packed(m)[i][r] + c])
+
+
+def test_deposits_of_other_lengths_meet_through_the_host(mesh_emulated):
+    """Ranks that size their own send buffers and capacities cannot be
+    the shards of one mesh program: the meeting serves them through the
+    host, every answer right and on its rank's device, counted
+    host-staged once a rank-call, the device counters at rest."""
+    P = 4
+    rng = np.random.default_rng(4350)
+    lens = [100, 0, 257, 64]
+    counts = np.zeros((P, P), np.int64)
+    for i, n in enumerate(lens):
+        counts[i] = random_counts(rng, P, n)[0] if n else 0
+    caps = [int(c) + 3 * r for r, c in enumerate(counts.sum(0))]
+    xs = [rng.integers(0, 1 << 20, (n, 128)).astype(np.int32) for n in lens]
+    sd, rd = packed(counts), packed(counts.T)
+    staged = pvar("coll_arr_host_staged_collectives")
+    ops = pvar("coll_alltoallv_device_ops")
+
+    def fn(comm):
+        r = comm.rank
+        out = comm.alltoallv_arr(
+            jax.device_put(xs[r], comm.device), counts[r], counts[:, r],
+            capacity=caps[r])
+        assert comm.device in out.devices()
+        return np.asarray(out)
+
+    for r, out in enumerate(mesh_world(P, fn)):
+        assert out.shape == (caps[r], 128)
+        for i in range(P):
+            c = counts[i][r]
+            assert np.array_equal(out[rd[r][i]:rd[r][i] + c],
+                                  xs[i][sd[i][r]:sd[i][r] + c])
+    assert pvar("coll_arr_host_staged_collectives") - staged == P
+    assert pvar("coll_alltoallv_device_ops") == ops
+
+
+# -- the mesh program, compiled here for a described 2x2 v5e (no chip) --------
+
+@pytest.fixture(scope="module")
+def v5e_mesh():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    mesh = Mesh(np.array(topo.devices), ("r",))
+    return mesh, NamedSharding(mesh, PartitionSpec("r"))
+
+
+MESH_SHAPES = {
+    # (rows a rank, row shape, dtype, the words the collective moves):
+    # the expert-parallel dispatch and combine rows at their published
+    # widths, the narrowest row served, and 8-byte elements
+    "dispatch-uint32-1864": (16384, (1864,), "uint32", "u32[16384,1,1864]"),
+    "combine-bfloat16-7168": (16384, (7168,), "bfloat16",
+                              "bf16[16384,2,3584]"),
+    "narrowest-int32-128": (1 << 16, (128,), "int32", "s32[65536,1,128]"),
+    "bit-patterns-uint64": (4096, (64,), "uint64", "u32[4096,1,128]"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MESH_SHAPES))
+def test_the_mesh_program_compiles_for_a_2x2_v5e(v5e_mesh, case):
+    """The chip's compiler takes ``ompi_alltoallv_mesh`` for four chips:
+    ONE ragged-all-to-all over the four devices and no other collective,
+    under the program's stable name, an 8-byte element as two 32-bit
+    words, each row one tile-shaped block."""
+    mesh, sharding = v5e_mesh
+    n, row, dtype, moved = MESH_SHAPES[case]
+    with jax.enable_x64(dtype == "uint64"):
+        prog = ragged.mesh_program(mesh, n, sharding)
+        txt = prog.lower(
+            jax.ShapeDtypeStruct((4, 4, 4), jnp.int32, sharding=sharding),
+            jax.ShapeDtypeStruct((4 * n, *row), jnp.dtype(dtype),
+                                 sharding=sharding)).compile().as_text()
+    assert "jit_ompi_alltoallv_mesh" in txt
+    ops = re.findall(r"= \S+ (ragged-all-to-all|all-to-all|all-gather|"
+                     r"all-reduce|collective-permute)\(", txt)
+    assert ops == ["ragged-all-to-all"]
+    assert "replica_groups={{0,1,2,3}}" in txt
+    line = next(ln for ln in txt.splitlines() if " ragged-all-to-all(" in ln)
+    assert line.split("=", 1)[1].strip().startswith(moved + "{"), line

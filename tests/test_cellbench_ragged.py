@@ -266,7 +266,10 @@ def test_manifest_is_valid_with_eleven_cells_four_on_four_chips():
     man = manifest.manifest(REPO)
     names = [w["name"] for w in man["workloads"]]
     assert names[9:11] == [AR, IS] and len(man["configs"]) >= 6
-    assert sum(w["chips"] == 4 for w in man["workloads"]) == 4
+    # what the name means, not a count that the next cell breaks: the
+    # four four-chip cells of then are there, at most half of them all
+    four = sum(w["chips"] == 4 for w in man["workloads"])
+    assert 4 <= four <= max(1, len(names) // 2)
     cell = manifest.cell(IS, REPO)
     cfg = cell["config"]
     assert (cell["entry"]["config"], cell["entry"]["chips"]) == (
@@ -304,7 +307,8 @@ def test_the_new_cells_are_on_the_lists_of_their_siblings():
             "ragged_elems_per_iter"} <= set(due)
     for name, pv in (("ragged_ops_per_iter", "coll_alltoallv_device_ops"),
                      ("ragged_elems_per_iter", "coll_alltoallv_elems")):
-        assert due[name]["workloads"] == [IS]
+        # the IS cell first; a later cell of the same call may follow
+        assert due[name]["workloads"][0] == IS
         spec = manifest.metric_spec(name, REPO)
         assert (spec["reader"], spec["pvars"]) == ("pvar_sum", [pv])
     ar = manifest.cell(AR, REPO)
