@@ -1350,8 +1350,12 @@ class TpuCollModule(CollModule):
         nothing), and whose counts are its operand.  A mesh program's
         shards are of one shape: deposits that differ in length or
         capacity are served through the host (``ragged.through_host``),
-        counted there.  The device counters move here, where the path
-        is known, for every rank-call of the meeting."""
+        counted there.  Which of the program's two bodies serves the key
+        is read once, when it is built, from the first deposit's own
+        layout (``ragged.slab_rows``: the slab body for a row-major
+        buffer, the row body for any other).  The device counters move
+        here, where the path is known, for every rank-call of the
+        meeting."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         mesh, size = comm.mesh(), comm.size
@@ -1365,16 +1369,32 @@ class TpuCollModule(CollModule):
             return all(d.x.shape == x.shape and d.capacity == cap
                        for d in deposits)
 
+        def build(x, cap):
+            t = _ragged.slab_rows(_ragged.layout_of(x), x.shape,
+                                  x.dtype.itemsize, cap)
+            return _ragged.mesh_program(mesh, cap, sharding, t), t
+
         def launch(deposits, g):
             x, cap = deposits[0].x, deposits[0].capacity
-            jfn = compile_cache.get(
+            jfn, t = compile_cache.get(
                 ("alltoallv_mesh", dev_key, x.shape, x.dtype.str, cap),
-                lambda: _ragged.mesh_program(mesh, cap, sharding))
+                lambda: build(x, cap))
             w = _ragged.row_elems(x)
-            out = jfn(_ragged.mesh_operand(
-                deposits, max(x.shape[0], cap) * w), g)
+            longest = max(x.shape[0], cap) * w
+            if not t:
+                meta = _ragged.mesh_operand(deposits, longest)
+            else:
+                meta = _ragged.slab_operand(deposits, longest, t)
+                if meta is None:
+                    # receive blocks that overlap: the host's answer,
+                    # handed back as the program's would be
+                    return _assemble(mesh, _ragged.through_host(
+                        deposits, devs, staged), sharding)
+            out = jfn(meta, g)
             rows = sum(d.meta[_ragged.SENT] for d in deposits)
             _ragged.pv_device_ops.add(size)
+            if t:
+                _ragged.pv_slab_ops.add(size)
             _ragged.pv_elems.add(rows * w)
             _ragged.pv_bytes.add(rows * w * x.dtype.itemsize)
             return out

@@ -28,9 +28,26 @@ key, on either provider:
   nothing is read or written beyond a count.
 * coll/tpu (one rank a chip of a mesh): ``mesh_program``, the
   ``ompi_alltoallv_mesh`` program, one ``lax.ragged_all_to_all`` over
-  ICI whose offsets and sizes are every rank's row of an int32 operand
-  (``mesh_operand``).  XLA:CPU cannot lower that collective
-  (``NO_LOWERING``), so a CPU mesh is served through the host.
+  ICI whose offsets and sizes are every rank's row of an int32 operand.
+  XLA:CPU cannot lower that collective (``NO_LOWERING``), so a CPU mesh
+  is served through the host.  The program has two bodies, and the
+  buffer's own layout on the chip chooses (``slab_rows``):
+
+  - the *slab body*, for a row-major buffer (a 2-D buffer of 2- or
+    4-byte elements whose row is a whole number of 128-element lanes,
+    laid out on ``(t, 128)`` tiles, ``t`` dividing its rows and the
+    capacity): the buffer viewed as slabs of ``t`` rows is the same
+    bytes, so the collective moves every slab a block touches, as it
+    lies, into a staging buffer, and ONE pass on arrival, a Pallas
+    kernel, moves each block's rows to its ``rdispls``
+    (``slab_operand``, ``arrival``);
+  - the *row body*, for any other buffer: each row travels as its own
+    tile-shaped block (``mesh_operand``), so the compiler relays the
+    whole buffer out to that shape and back.  The expert-parallel
+    dispatch's 1,864-word rows take it: the chip lays a buffer whose
+    row is not a whole number of lanes out column-major, 128 rows to a
+    tile column, and moving one of its rows is a transpose whatever
+    the body.
 
 The result buffers are never initialised: what lies outside the
 received blocks is not part of the result (MPI leaves it untouched).
@@ -41,6 +58,7 @@ ones they were chosen over.
 from __future__ import annotations
 
 import functools
+import math
 import struct
 from itertools import accumulate
 from operator import add
@@ -56,6 +74,12 @@ pv_device_ops = registry.register_pvar(
     help="alltoallv_arr rank-calls served by a device program "
          "(coll/hbm's ompi_alltoallv on one chip, coll/tpu's "
          "ompi_alltoallv_mesh over a mesh); once a rank-call")
+pv_slab_ops = registry.register_pvar(
+    "coll", "alltoallv", "slab_ops",
+    help="alltoallv_arr rank-calls that coll/tpu's ompi_alltoallv_mesh "
+         "served with its slab body (a row-major buffer moved as whole "
+         "tile rows, one pass on arrival; coll/ragged.slab_rows); "
+         "once a rank-call, with coll_alltoallv_device_ops")
 pv_elems = registry.register_pvar(
     "coll", "alltoallv", "elems",
     help="Elements the device-served alltoallv_arr rank-calls were asked "
@@ -240,6 +264,224 @@ def mesh_operand(deposits, longest: int) -> np.ndarray:
                          np.int32).reshape(P, 4, P)
 
 
+def layout_of(x):
+    """The layout ``x`` holds on its device (None where it states none)."""
+    return getattr(getattr(x, "format", None), "layout", None)
+
+
+def slab_rows(layout, shape: tuple, itemsize: int, capacity: int) -> int:
+    """THE rule of the mesh program's slab body: the rows ``t`` of one
+    slab where a buffer of ``shape`` laid out as ``layout`` is
+    row-major on ``(t, 128)`` tiles (minor-to-major ``{1,0}``, which the
+    chip gives a row of whole 128-element lanes), of 2- or 4-byte
+    elements, ``t`` dividing its rows and ``capacity``: viewed as
+    ``(rows / t, t, row)`` it is then the same bytes, and so is the
+    staging buffer viewed as rows.  0 for any other buffer, which the
+    row body serves (an 8-byte element, a row that is not a whole
+    number of lanes, which the chip lays out column-major, a row of
+    more than one dimension, a layout with no tiles)."""
+    tiling = getattr(layout, "tiling", None)
+    if len(shape) != 2 or itemsize not in (2, 4) or shape[1] % 128 \
+            or not tiling or tuple(layout.major_to_minor) != (0, 1):
+        return 0
+    t, lanes = tiling[0]
+    if lanes != 128 or t < 1 or shape[0] % t or capacity % t:
+        return 0
+    return t
+
+
+def _window(itemsize: int) -> int:
+    """Rows a window of ``arrival`` is aligned to: one tile of the chip's
+    memory, 8 rows of 32-bit words (a 2-byte element packs two rows a
+    word)."""
+    return 32 // itemsize
+
+
+def staging_slabs(capacity: int, t: int, size: int, itemsize: int) -> int:
+    """Slabs of ``t`` rows in the slab body's staging buffer, rounded to
+    whole windows of ``arrival``: room for what ``size`` senders' blocks
+    touch when they fill the capacity (a block of c rows touches at most
+    (c + 2t - 2) / t slabs), and for one chunk of ``arrival`` and its
+    window."""
+    a = _window(itemsize)
+    rows = max(capacity + 2 * size * (t - 1), -(-capacity // a) * a + a)
+    whole = math.lcm(t, a)
+    return -(-rows // whole) * whole // t
+
+
+def slab_operand(deposits, longest: int, t: int):
+    """coll/tpu's int32 operand for the slab body, ``(P, 7, P)``, from
+    the P deposits (``_checked`` first): rank i's row is, in slabs of
+    ``t`` rows, where in its buffer the slabs of each block start, how
+    many the block touches, where they land in each receiver's staging
+    buffer (after the slabs of every lower sender) and how many slabs
+    it receives from each rank; then, in rows and ordered by where they
+    land in its result, each received block's first row in the staging
+    buffer, its ``rdispls`` and its count (``arrival``'s ``meta``).
+    None where two of a rank's receive blocks overlap, which MPI
+    forbids and ``arrival`` does not serve: the meeting then serves the
+    call through the host."""
+    metas = _checked(deposits, longest)
+    P = len(metas)
+    starts, slabs = [], []
+    for sc, sd, _rc, _rd, _n in metas:
+        starts.append([d // t for d in sd])
+        slabs.append([-(-(d + c) // t) - d // t if c else 0
+                      for c, d in zip(sc, sd)])
+    lands = [[0] * P for _ in range(P)]            # [sender][receiver]
+    for j in range(P):
+        at = 0
+        for i in range(P):
+            lands[i][j] = at
+            at += slabs[i][j]
+    flat = []
+    for j, m in enumerate(metas):
+        rc, rd = m[2], m[3]
+        order = sorted(range(P), key=rd.__getitem__)
+        end = 0
+        for i in order:
+            if rc[i]:
+                if rd[i] < end:
+                    return None
+                end = rd[i] + rc[i]
+        flat += starts[j]
+        flat += slabs[j]
+        flat += lands[j]
+        flat += [slabs[i][j] for i in range(P)]
+        flat += [t * lands[i][j] + metas[i][1][j] % t for i in order]
+        flat += [rd[i] for i in order]
+        flat += [rc[i] for i in order]
+    return np.frombuffer(_int32s(7 * P * P).pack(*flat),
+                         np.int32).reshape(P, 7, P)
+
+
+#: the bits of a 32-bit word each of its rows holds, by rows a word
+_HALVES = {1: (0xFFFFFFFF,), 2: (0xFFFF, 0xFFFF0000)}
+#: output rows a step of ``arrival`` writes, and the most bytes of the
+#: window it reads (PERF.md section 5)
+ARRIVAL_ROWS = 128
+ARRIVAL_WINDOW_BYTES = 2 << 20
+
+
+def _items(meta, rows: int, capacity: int, k: int, a: int):
+    """``arrival``'s work list, ``(5, G)`` int32, one column a step: the
+    aligned first row of the step's window in the staging buffer, the
+    shift that puts it in place, the chunk of ``k`` result rows it
+    writes, and the rows ``[lo, hi)`` of that chunk its block covers.
+    Steps go in result order, a block's chunks in turn; steps past the
+    last repeat it with nothing to cover."""
+    import jax.numpy as jnp
+
+    P = meta.shape[1]
+    first, rd, rc = meta[0], meta[1], meta[2]
+    c0 = rd // k
+    cnt = jnp.where(rc > 0, (rd + rc - 1) // k - c0 + 1, 0)
+    ends = jnp.cumsum(cnt)
+    total = ends[-1]
+    g = jnp.arange(-(-capacity // k) + 2 * P, dtype=jnp.int32)
+    at = jnp.minimum(g, jnp.maximum(total - 1, 0))
+    b = jnp.minimum(jnp.sum(at[:, None] >= ends[None, :], axis=1), P - 1)
+    # a rank that receives nothing still has steps: they write no row,
+    # and a chunk inside the result
+    chunk = jnp.minimum(c0[b] + at - (ends[b] - cnt[b]),
+                        -(-capacity // k) - 1)
+    src = chunk * k - rd[b] + first[b]
+    base = jnp.clip(src // a, 0, (rows - k - a) // a) * a
+    live = g < total
+    lo = jnp.where(live, jnp.maximum(rd[b], chunk * k), 0)
+    hi = jnp.where(live, jnp.minimum(rd[b] + rc[b], chunk * k + k), 0)
+    return jnp.stack([base, src - base, chunk, lo, hi]).astype(jnp.int32)
+
+
+def arrival(staged, meta, capacity: int, interpret: bool = False):
+    """The slab body's one pass on arrival: the ``(capacity, row)``
+    result from the staging buffer ``staged``, each received block's
+    rows moved to its ``rdispls``.  ``meta`` is ``(3, P)`` int32, a
+    block a column in result order (``slab_operand``): its first row in
+    ``staged``, its ``rdispls``, its count.  Rows outside every block
+    are not part of the result.
+
+    A Pallas kernel whose steps (``_items``, made on the device from
+    the P columns: nothing capacity-long crosses from the host) each
+    write ``ARRIVAL_ROWS`` result rows of one block: the pipeline reads
+    a window of the staging buffer aligned to the chip's tile, the
+    kernel rolls it into place in 32-bit words (two rows of a 2-byte
+    element share a word, so an odd shift joins the halves of
+    neighbouring words) and stores it, under a mask where the block
+    covers part of the chunk.  Bits only: no float value is converted
+    or computed on.  ``interpret`` runs it on a CPU (the tests)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    given = staged.dtype
+    if interpret and jnp.issubdtype(given, jnp.floating):
+        # XLA:CPU may quiet a NaN it moves as a float; the chip does not
+        staged = lax.bitcast_convert_type(
+            staged, jnp.dtype(f"uint{8 * given.itemsize}"))
+    rows, width = staged.shape
+    dtype = staged.dtype
+    pack = 4 // dtype.itemsize
+    a = _window(dtype.itemsize)
+    k = min(ARRIVAL_ROWS, -(-capacity // a) * a)
+    lanes = width // 128
+    wb = 128 * max(d for d in range(1, lanes + 1) if lanes % d == 0 and (
+        d == 1 or (k + a) * d * 128 * dtype.itemsize <= ARRIVAL_WINDOW_BYTES))
+    words = (k + a) // pack
+    items = _items(meta, rows, capacity, k, a)
+
+    def window(lb, g, it):
+        return (pl.multiple_of(it[0, g], a), pl.multiple_of(lb * wb, 128))
+
+    def chunk(lb, g, it):
+        return (it[2, g], lb)
+
+    def step(it, src, out):
+        g = pl.program_id(1)
+        shift, lo, hi = it[1, g], it[3, g], it[4, g]
+        at = it[2, g] * k
+        w = pltpu.bitcast(src[...], jnp.uint32)
+        if pack == 1:
+            v = pltpu.roll(w, (words - shift % words) % words, 0)[:k]
+        else:
+            half = shift // 2
+            w0 = pltpu.roll(w, (words - half % words) % words, 0)
+            w1 = pltpu.roll(w0, words - 1, 0)
+            v = jnp.where(shift % 2 == 1, (w0 >> 16) | (w1 << 16),
+                          w0)[:k // 2]
+
+        @pl.when((lo == at) & (hi == at + k))
+        def _():
+            out[...] = pltpu.bitcast(v, dtype)
+
+        @pl.when((hi > lo) & ((lo != at) | (hi != at + k)))
+        def _():
+            # the row of each word's first half; its h-th half holds row + h
+            row = pack * lax.broadcasted_iota(jnp.int32, v.shape, 0) + at
+            keep = jnp.zeros(v.shape, jnp.uint32)
+            for h, bits in enumerate(_HALVES[pack]):
+                keep = keep | jnp.where((row + h >= lo) & (row + h < hi),
+                                        jnp.uint32(bits), jnp.uint32(0))
+            old = pltpu.bitcast(out[...], jnp.uint32)
+            out[...] = pltpu.bitcast((v & keep) | (old & ~keep), dtype)
+
+    out = pl.pallas_call(
+        step, out_shape=jax.ShapeDtypeStruct((capacity, width), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(width // wb, items.shape[1]),
+            in_specs=[pl.BlockSpec((pl.Element(k + a), pl.Element(wb)),
+                                   window)],
+            out_specs=pl.BlockSpec((k, wb), chunk)),
+        # two windows, two result blocks and the 32-bit copies of a
+        # window pass the default scoped limit
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
+        interpret=interpret, name="ompi_alltoallv_arrival",
+    )(items, staged)
+    return out if out.dtype == given else lax.bitcast_convert_type(out, given)
+
+
 @functools.lru_cache(maxsize=None)
 def _int32s(n: int) -> struct.Struct:
     """The packer of ``n`` native int32s (the format parsed once)."""
@@ -364,23 +606,48 @@ def body(capacities):
     return ompi_alltoallv
 
 
-def mesh_program(mesh, capacity: int, sharding):
+def mesh_program(mesh, capacity: int, sharding, slab: int = 0):
     """``ompi_alltoallv_mesh(meta, x)``: the ONE program of a ragged
     exchange among the P ranks of ``mesh``, one a chip: every rank's
-    buffer is its shard of ``x`` and its row of ``meta``
-    (``mesh_operand``) its offsets and sizes, both on ``sharding``; the
-    result is a ``(capacity, *row)`` shard a rank.  An 8-byte element
-    travels as two 32-bit words (the compiler does not split a 64-bit
-    ragged-all-to-all), and a row as the ``(pack, words / pack)`` block
-    the chip's collective lays out on whole tiles, ``pack`` the 16-bit
-    words a 32-bit lane holds: one relayout copy in, one out."""
+    buffer is its shard of ``x`` and its row of ``meta`` its offsets and
+    sizes, both on ``sharding``; the result is a ``(capacity, *row)``
+    shard a rank.  The program only moves bits.
+
+    With ``slab`` (``slab_rows``'s t) it is the slab body, ``meta`` the
+    ``slab_operand``: the buffer viewed as slabs of t rows (no copy on
+    a row-major buffer), ONE collective of whole slabs into a staging
+    buffer of ``staging_slabs``, ONE pass that moves each block's rows
+    into place (``arrival``).  A block may carry up to 2(t - 1) rows
+    beyond its count, which land in staging and never reach the
+    result.
+
+    Without, it is the row body, ``meta`` the ``mesh_operand``: an
+    8-byte element travels as two 32-bit words (the compiler does not
+    split a 64-bit ragged-all-to-all), and a row as the ``(pack, words /
+    pack)`` block the chip's collective lays out on whole tiles,
+    ``pack`` the 16-bit words a 32-bit lane holds: one relayout copy
+    of the whole buffer in, one out."""
     import jax
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
+    interpret = mesh.devices.flat[0].platform != "tpu"
+
+    def slab_body(m, x):
+        n, width = x.shape
+        room = staging_slabs(capacity, slab, mesh.devices.size,
+                             x.dtype.itemsize)
+        staged = _exchange(x.reshape((n // slab, slab, width)),
+                           lax.empty((room, slab, width), x.dtype),
+                           m[0], m[1], m[2], m[3])
+        return arrival(staged.reshape((room * slab, width)), m[4:7],
+                       capacity, interpret)
+
     def ompi_alltoallv_mesh(meta, x):
         m = meta[0]
+        if slab:
+            return slab_body(m, x)
         n, dtype = x.shape[0], x.dtype
         if dtype.itemsize == 8:
             x = lax.bitcast_convert_type(x, jnp.uint32)

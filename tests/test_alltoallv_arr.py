@@ -10,8 +10,9 @@ here as the reference.  Rows on both providers and the fallback; the
 mesh half's meeting (its operand against numpy formulas, one program
 for 40 count matrices, deposits of other shapes through the host) on
 CPU devices with its collective emulated, since XLA:CPU cannot lower
-``ragged-all-to-all``; and its program compiled for a described 2x2
-v5e."""
+``ragged-all-to-all``; the slab body's rule, operand and Pallas pass on
+arrival (interpreted) against the host's answer; and both bodies
+compiled for a described 2x2 v5e."""
 
 import re
 
@@ -913,6 +914,224 @@ def test_deposits_of_other_lengths_meet_through_the_host(mesh_emulated):
     assert pvar("coll_alltoallv_device_ops") == ops
 
 
+# -- the mesh program's slab body ----------------------------------------------
+
+def row_major(t):
+    """The layout the chip gives a row of whole 128-element lanes: rows
+    major, ``(t, 128)`` tiles."""
+    from jax.experimental.layout import Layout
+    return Layout(major_to_minor=(0, 1), tiling=((t, 128),))
+
+
+RULE = {
+    # (layout, shape, itemsize, capacity): the rows of a slab, 0 for
+    # the row body
+    "combine-bfloat16": (
+        "bf16", (16384, 7168), 2, 16384, 8),
+    "float32-rows": ("rm8", (16384, 4096), 4, 16384, 8),
+    "uint32-1920": ("rm8", (16384, 1920), 4, 16384, 8),
+    "dispatch-column-major": ("cm8", (16384, 1864), 4, 16384, 0),
+    "eight-byte-element": ("rm8", (4096, 128), 8, 4096, 0),
+    "row-not-whole-lanes": ("rm8", (4096, 200), 4, 4096, 0),
+    "rows-of-two-dims": ("rm8", (4096, 2, 128), 4, 4096, 0),
+    "rows-not-a-slab-multiple": ("rm8", (4100, 128), 4, 4096, 0),
+    "capacity-not-a-slab-multiple": ("rm8", (4096, 128), 4, 4100, 0),
+    "no-tiles": ("flat", (4096, 128), 4, 4096, 0),
+    "no-layout": (None, (4096, 128), 4, 4096, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RULE))
+def test_the_slab_rule_reads_the_layout(case):
+    """The slab body serves a 2-D buffer of 2- or 4-byte elements laid
+    out rows major on ``(t, 128)`` tiles, ``t`` dividing its rows and
+    the capacity; everything else is the row body's."""
+    from jax.experimental.layout import Layout
+    lay, shape, itemsize, cap, t = RULE[case]
+    layout = {
+        "bf16": Layout(major_to_minor=(0, 1), tiling=((8, 128), (2, 1))),
+        "rm8": row_major(8),
+        "cm8": Layout(major_to_minor=(1, 0), tiling=((8, 128),)),
+        "flat": Layout(major_to_minor=(0, 1), tiling=()),
+        None: None}[lay]
+    assert ragged.slab_rows(layout, shape, itemsize, cap) == t
+
+
+def slab_case(rng, P, t):
+    """One exchange whose blocks start and end at every phase of a slab:
+    counts with zeros and blocks shorter than a slab, send and receive
+    blocks in a random order with gaps, one block that ends at the
+    buffer's last row, a capacity beyond what arrives, and now and then
+    a rank that receives nothing; the ``(P,)`` metas, the rows and the
+    capacity."""
+    counts = rng.integers(0, 4 * t, (P, P))
+    counts[rng.random((P, P)) < 0.2] = 0
+    counts[rng.random((P, P)) < 0.2] %= t
+    sd = np.zeros((P, P), np.int64)
+    rd = np.zeros((P, P), np.int64)
+    for disp, c in ((sd, counts), (rd, counts.T)):
+        for i in range(P):
+            at = 0
+            for j in rng.permutation(P):
+                at += int(rng.integers(0, 2 * t))
+                disp[i, j] = at
+                at += c[i, j]
+    end = sd + counts
+    n = -(-int(end.max() + rng.integers(0, 2 * t)) // t) * t
+    i = int(np.argmax(end.max(1)))
+    j = int(np.argmax(end[i]))
+    sd[i, j] += n - end[i, j]                  # the buffer's last row
+    cap = -(-int((rd + counts.T).max() + rng.integers(0, 3 * t)) // t) * t
+    if rng.random() < 0.2:
+        counts[:, 0] = 0                        # a rank that gets nothing,
+        rd[0] = cap                             # its blocks at the end
+    metas = [ragged.arguments(P, n, counts[r], counts[:, r], sd[r], rd[r],
+                              cap) for r in range(P)]
+    return metas, n, cap
+
+
+def slab_payload(rng, n, dtype):
+    """``n`` rows of 128 seeded bit patterns: for bfloat16, subnormals
+    and NaNs of both signs among them."""
+    if dtype == "uint32":
+        return rng.integers(0, 1 << 32, (n, 128), dtype=np.uint64) \
+            .astype(np.uint32)
+    bits = rng.integers(0, 1 << 16, (n, 128)).astype(np.uint16)
+    odd = np.array([0x0001, 0x007F, 0x8001, 0x807F, 0x7FC1, 0xFFC1,
+                    0x7F81, 0xFF81], np.uint16)
+    bits.flat[::3] = odd[np.arange(bits.size)[::3] % len(odd)]
+    return bits.view(jnp.bfloat16)
+
+
+class _Count:
+    def add(self, n):
+        pass
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "uint32"])
+@pytest.mark.parametrize("seed", range(40))
+def test_the_slab_exchange_gives_the_host_answer(seed, dtype):
+    """40 count matrices on 4 ranks, every phase of the displacements
+    against slabs of 1 to 16 rows: the slab operand sends whole slabs
+    of the sender's buffer into its receivers' staging buffers without
+    overlap or overflow; the slab-granular exchange emulated here, then
+    the program's gather (``ragged.arrival``, run on the CPU), gives
+    every received row of ``ragged.through_host``'s answer bit for
+    bit."""
+    rng = np.random.default_rng(4500 + seed)
+    P, t = 4, (8, 8, 16, 4, 2, 1)[seed % 6]
+    metas, n, cap = slab_case(rng, P, t)
+    xs = [slab_payload(rng, n, dtype) for _ in range(P)]
+    deps = [ragged.Deposit(x, m, cap) for x, m in zip(xs, metas)]
+    op = ragged.slab_operand(deps, max(n, cap) * 128, t)
+    room = ragged.staging_slabs(cap, t, P, xs[0].dtype.itemsize)
+    assert op.dtype == np.int32 and op.shape == (P, 7, P)
+    start, slabs, lands, takes, first, rd, rc = op.transpose(1, 0, 2)
+    assert np.array_equal(takes, slabs.T)
+    assert (start + slabs <= n // t).all() and (lands + slabs <= room).all()
+    assert (np.diff(rd, axis=1) >= 0).all()        # in result order
+    devs = jax.devices()[:P]
+    want = ragged.through_host(deps, devs, _Count())
+    arrive = jax.jit(ragged.arrival, static_argnums=(2, 3))
+    for j in range(P):
+        staged = np.asarray(slab_payload(rng, room * t, dtype))
+        for i in range(P):
+            k, at, s = slabs[i, j], lands[i, j], start[i, j]
+            staged[at * t:(at + k) * t] = xs[i][s * t:(s + k) * t]
+        got = np.asarray(arrive(staged, op[j, 4:], cap, True))
+        assert got.shape == (cap, 128) and got.dtype == xs[0].dtype
+        live = np.zeros(cap, bool)
+        for i in range(P):
+            live[rd[j, i]:rd[j, i] + rc[j, i]] = True
+        words = np.uint16 if dtype == "bfloat16" else np.uint32
+        assert np.array_equal(got[live].view(words),
+                              np.asarray(want[j])[live].view(words)), j
+
+
+def test_receive_blocks_that_overlap_have_no_slab_operand():
+    """Receive blocks that overlap (MPI forbids them) get no slab
+    operand, and the meeting serves the call through the host; blocks
+    that touch, in any order, get one."""
+    P, t, n, cap = 4, 8, 72, 64
+    for lands, fits in (([0, 16, 32, 48], True), ([48, 0, 32, 16], True),
+                        ([0, 16, 31, 48], False), ([0] * P, False)):
+        counts = np.full((P, P), 16)
+        metas = [ragged.arguments(P, n, counts[r], counts[:, r],
+                                  [1 + 17 * j for j in range(P)], lands,
+                                  cap) for r in range(P)]
+        deps = [ragged.Deposit(np.zeros((n, 128), np.uint32), m, cap)
+                for m in metas]
+        op = ragged.slab_operand(deps, n * 128, t)
+        assert (op is not None) == fits, lands
+
+
+SLAB_ROWS = {
+    # (row, dtype, the slab body serves it)
+    "combine-like-bfloat16": (256, "bfloat16", True),
+    "float32-rows": (128, "float32", True),
+    "dispatch-like-uint32": (200, "uint32", False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SLAB_ROWS))
+def test_the_mesh_program_chooses_its_body_by_the_layout(
+        case, mesh_emulated, monkeypatch):
+    """coll/tpu's mesh half with every deposit laid out rows major on
+    (8, 128) tiles, as a chip lays a row of whole lanes: 12 count
+    matrices, ONE program built; the slab body serves a row of whole
+    lanes (``coll_alltoallv_slab_ops`` moves with
+    ``coll_alltoallv_device_ops``), the row body any other; every
+    answer right; nothing host-staged."""
+    row, dtype, slab = SLAB_ROWS[case]
+    monkeypatch.setattr(ragged, "layout_of", lambda x: row_major(8))
+    P, n, cap, calls = 4, 96, 160, 12
+    rng = np.random.default_rng(4520 + sorted(SLAB_ROWS).index(case))
+    mats = [random_counts(rng, P, n - 20, zeros=0.2 * (k % 3))
+            for k in range(calls)]
+    xs = [np.asarray(jnp.asarray(rng.integers(0, 1 << 15, (n, row)),
+                                 jnp.float32).astype(dtype))
+          for _ in range(P)]
+    builds = dev.compile_cache.builds
+    staged = pvar("coll_arr_host_staged_collectives")
+    ops = pvar("coll_alltoallv_device_ops")
+    slabs = pvar("coll_alltoallv_slab_ops")
+    for m in mats:
+        sd = packed(m) + rng.integers(0, 5, (P, P)).cumsum(1)
+        assert rows_exchange(P, xs, m, cap, sd, None, mesh_world) == {"tpu"}
+    assert dev.compile_cache.builds - builds == 1
+    assert pvar("coll_arr_host_staged_collectives") == staged
+    assert pvar("coll_alltoallv_device_ops") - ops == P * calls
+    assert pvar("coll_alltoallv_slab_ops") - slabs == (
+        P * calls if slab else 0)
+
+
+def test_overlapping_receive_blocks_meet_through_the_host(
+        mesh_emulated, monkeypatch):
+    """Where receive blocks overlap, the slab body's meeting hands back
+    the host's answer (the last sender's rows win, as through the
+    host), counted host-staged."""
+    monkeypatch.setattr(ragged, "layout_of", lambda x: row_major(8))
+    P, n, cap = 4, 72, 16
+    rng = np.random.default_rng(4530)
+    xs = [rng.integers(0, 1 << 30, (n, 128)).astype(np.int32)
+          for _ in range(P)]
+    sd = [1 + 17 * j for j in range(P)]
+    staged = pvar("coll_arr_host_staged_collectives")
+    slabs = pvar("coll_alltoallv_slab_ops")
+
+    def fn(comm):
+        out = comm.alltoallv_arr(
+            jax.device_put(xs[comm.rank], comm.device), [16] * P, [16] * P,
+            sd, [0] * P, capacity=cap)
+        assert comm.device in out.devices()
+        return np.asarray(out)
+
+    for r, out in enumerate(mesh_world(P, fn)):
+        assert np.array_equal(out, xs[P - 1][sd[r]:sd[r] + 16])
+    assert pvar("coll_arr_host_staged_collectives") - staged == P
+    assert pvar("coll_alltoallv_slab_ops") == slabs
+
+
 # -- the mesh program, compiled here for a described 2x2 v5e (no chip) --------
 
 @pytest.fixture(scope="module")
@@ -930,15 +1149,28 @@ def v5e_mesh():
     return mesh, NamedSharding(mesh, PartitionSpec("r"))
 
 
+def chip_layout(sharding, shape, dtype):
+    """The layout the described chip gives a buffer of ``shape`` a rank
+    (one with no layout of its own: the default)."""
+    c = jax.jit(lambda a: a).lower(jax.ShapeDtypeStruct(
+        (4 * shape[0], *shape[1:]), dtype, sharding=sharding)).compile()
+    return c.input_formats[0][0].layout
+
+
 MESH_SHAPES = {
-    # (rows a rank, row shape, dtype, the words the collective moves):
-    # the expert-parallel dispatch and combine rows at their published
-    # widths, the narrowest row served, and 8-byte elements
-    "dispatch-uint32-1864": (16384, (1864,), "uint32", "u32[16384,1,1864]"),
-    "combine-bfloat16-7168": (16384, (7168,), "bfloat16",
-                              "bf16[16384,2,3584]"),
-    "narrowest-int32-128": (1 << 16, (128,), "int32", "s32[65536,1,128]"),
-    "bit-patterns-uint64": (4096, (64,), "uint64", "u32[4096,1,128]"),
+    # (rows a rank, row shape, dtype, the slab rows the chip's layout
+    # gives, the words the collective moves): the expert-parallel
+    # dispatch and combine rows at their published widths, the
+    # narrowest row served, and 8-byte elements.  A row of whole lanes
+    # travels in slabs of 8 rows (the slab body); the dispatch's
+    # 1,864-word rows, which the chip lays out column-major, and 8-byte
+    # elements travel a row a block (the row body)
+    "dispatch-uint32-1864": (16384, (1864,), "uint32", 0,
+                             "u32[16384,1,1864]"),
+    "combine-bfloat16-7168": (16384, (7168,), "bfloat16", 8,
+                              "bf16[2056,8,7168]"),
+    "narrowest-int32-128": (1 << 16, (128,), "int32", 8, "s32[8199,8,128]"),
+    "bit-patterns-uint64": (4096, (64,), "uint64", 0, "u32[4096,1,128]"),
 }
 
 
@@ -947,14 +1179,24 @@ def test_the_mesh_program_compiles_for_a_2x2_v5e(v5e_mesh, case):
     """The chip's compiler takes ``ompi_alltoallv_mesh`` for four chips:
     ONE ragged-all-to-all over the four devices and no other collective,
     under the program's stable name, an 8-byte element as two 32-bit
-    words, each row one tile-shaped block."""
+    words.  The slab rule, read from the layout the chip gives the
+    buffer, picks the body: the slab body moves whole 8-row slabs of a
+    row-major buffer as it lies, with no copy, reshape, transpose or
+    fusion over a whole buffer: the one pass on arrival, the Pallas
+    kernel ompi_alltoallv_arrival, writes the result; the row body
+    moves each row as one tile-shaped block."""
     mesh, sharding = v5e_mesh
-    n, row, dtype, moved = MESH_SHAPES[case]
+    n, row, dtype, t, moved = MESH_SHAPES[case]
     with jax.enable_x64(dtype == "uint64"):
-        prog = ragged.mesh_program(mesh, n, sharding)
+        dt = jnp.dtype(dtype)
+        got = ragged.slab_rows(chip_layout(sharding, (n, *row), dt),
+                               (n, *row), dt.itemsize, n)
+        assert got == t
+        prog = ragged.mesh_program(mesh, n, sharding, t)
         txt = prog.lower(
-            jax.ShapeDtypeStruct((4, 4, 4), jnp.int32, sharding=sharding),
-            jax.ShapeDtypeStruct((4 * n, *row), jnp.dtype(dtype),
+            jax.ShapeDtypeStruct((4, 7 if t else 4, 4), jnp.int32,
+                                 sharding=sharding),
+            jax.ShapeDtypeStruct((4 * n, *row), dt,
                                  sharding=sharding)).compile().as_text()
     assert "jit_ompi_alltoallv_mesh" in txt
     ops = re.findall(r"= \S+ (ragged-all-to-all|all-to-all|all-gather|"
@@ -963,3 +1205,14 @@ def test_the_mesh_program_compiles_for_a_2x2_v5e(v5e_mesh, case):
     assert "replica_groups={{0,1,2,3}}" in txt
     line = next(ln for ln in txt.splitlines() if " ragged-all-to-all(" in ln)
     assert line.split("=", 1)[1].strip().startswith(moved + "{"), line
+    if t:
+        entry = txt[txt.index("\nENTRY"):]
+        entry = entry[:entry.index("\n}")]
+        kind = moved.split("[")[0]
+        passes = re.findall(r"= %s\[([\d,]+)\]\S* (copy|reshape|transpose|"
+                            r"fusion|custom-call)\((?!\)[^\n]*AllocateBuffer)"
+                            % kind, entry)
+        assert [p for p in passes if p[0].endswith(",%d" % row[-1])] == [
+            ("%d,%d" % (n, row[-1]), "custom-call")]
+        root = next(ln for ln in entry.splitlines() if "ROOT" in ln)
+        assert "ompi_alltoallv_arrival" in root and "tpu_custom_call" in root

@@ -216,6 +216,42 @@ def test_the_traced_ep_cell_closes_the_layer_account(monkeypatch, tmp_path):
     assert abs(m["unaccounted_us"]) < 0.05 * m["traced_iter_us"]
 
 
+def chip_like_layout(x):
+    """The layout a v5e gives a 2-D buffer: rows major on (8, 128) tiles
+    where a row is a whole number of 128-element lanes, columns major
+    otherwise (tests/test_alltoallv_arr.py reads it from the described
+    chip)."""
+    from jax.experimental.layout import Layout
+    return Layout(major_to_minor=(0, 1) if x.shape[-1] % 128 == 0
+                  else (1, 0), tiling=((8, 128),))
+
+
+def test_the_combine_alone_takes_the_slab_body(monkeypatch, tmp_path):
+    """With the chip's layouts, the combine's bfloat16 rows of 7,168
+    lanes travel in the mesh program's slab body and the dispatch's
+    1,864-word rows in its row body: ``ragged_slab_ops_per_iter`` reads
+    exactly 1, ``ragged_ops_per_iter`` 2, and the run is correct with
+    the bytes it was asked to send."""
+    mesh_emulated(monkeypatch)
+    monkeypatch.setattr(ragged, "layout_of", chip_like_layout)
+    saved = {k: registry.get(k) for k in (
+        "trace_enable", "trace_phase_enable", "trace_buffer_events")}
+    for k, v in (("trace_enable", True), ("trace_phase_enable", True),
+                 ("trace_buffer_events", 65536)):
+        registry.set(k, v)
+    try:
+        r = drive(EP, blocking_ep, trace=1, out_dir=str(tmp_path))
+    finally:
+        for k, v in saved.items():
+            registry.set(k, v)
+    m = {k: v["value"] for k, v in r["metrics"].items()}
+    assert r["correct"] is True and r["failed"] == 0, r["checks"]
+    assert m["ragged_slab_ops_per_iter"] == 1.0
+    assert m["ragged_ops_per_iter"] == 2.0
+    assert m["ragged_bytes_per_iter"] == \
+        r["checks"]["device_bytes"]["value"] / r["attempted"]
+
+
 def one_iteration_late(comm, call):
     """Every answer is the exchange of the iteration before: the other
     routing set's rows."""
@@ -378,12 +414,13 @@ def test_the_two_cells_are_on_the_lists_of_their_metrics():
           "entry_exit_us", "rdv_skew_us", "rdv_wake_us", "serve_us",
           "launch_us", "assemble_scatter_us", "caller_us", "rdv_per_iter",
           "unaccounted_us", "traced_iter_us", "ragged_ops_per_iter",
-          "ragged_bytes_per_iter"}
+          "ragged_bytes_per_iter", "ragged_slab_ops_per_iter"}
     q = {"launch_s", "compile_or_load_s", "kernel_us", "device_idle_pct",
          "traced_iter_us", "collective_roofline", "fused_ops_per_iter"}
     assert {n for n, ws in lists.items() if EP in ws} == ep
     assert {n for n, ws in lists.items() if Q in ws} == q
     for name, pv in (("ragged_bytes_per_iter", "coll_alltoallv_bytes"),
+                     ("ragged_slab_ops_per_iter", "coll_alltoallv_slab_ops"),
                      ("fused_ops_per_iter", "coll_device_fused_collectives")):
         spec = manifest.metric_spec(name, REPO)
         assert (spec["reader"], spec["pvars"]) == ("pvar_sum", [pv])
