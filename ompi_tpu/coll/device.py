@@ -1353,9 +1353,13 @@ class TpuCollModule(CollModule):
         counted there.  Which of the program's two bodies serves the key
         is read once, when it is built, from the first deposit's own
         layout (``ragged.slab_rows``: the slab body for a row-major
-        buffer, the row body for any other).  The device counters move
-        here, where the path is known, for every rank-call of the
-        meeting."""
+        buffer, the row body for any other).  The counts cross to the
+        device once a meeting: ONE put of the operand onto the first
+        rank's chip, beside the zeros the key's program was built with
+        on every other chip (``ragged.spare_operand``), and the program
+        spreads it over ICI.  The device counters move here, where the
+        path is known, for every rank-call of the meeting."""
+        import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         mesh, size = comm.mesh(), comm.size
@@ -1372,11 +1376,12 @@ class TpuCollModule(CollModule):
         def build(x, cap):
             t = _ragged.slab_rows(_ragged.layout_of(x), x.shape,
                                   x.dtype.itemsize, cap)
-            return _ragged.mesh_program(mesh, cap, sharding, t), t
+            return (_ragged.mesh_program(mesh, cap, sharding, t), t,
+                    _ragged.spare_operand(devs, 7 if t else 4))
 
         def launch(deposits, g):
             x, cap = deposits[0].x, deposits[0].capacity
-            jfn, t = compile_cache.get(
+            jfn, t, spare = compile_cache.get(
                 ("alltoallv_mesh", dev_key, x.shape, x.dtype.str, cap),
                 lambda: build(x, cap))
             w = _ragged.row_elems(x)
@@ -1390,6 +1395,10 @@ class TpuCollModule(CollModule):
                     # handed back as the program's would be
                     return _assemble(mesh, _ragged.through_host(
                         deposits, devs, staged), sharding)
+            meta = jax.make_array_from_single_device_arrays(
+                (size * size, *meta.shape[1:]), sharding,
+                [_ragged.put_operand(meta, devs[0]), *spare])
+            _ragged.pv_operand_puts.add(1)
             out = jfn(meta, g)
             rows = sum(d.meta[_ragged.SENT] for d in deposits)
             _ragged.pv_device_ops.add(size)
@@ -1419,7 +1428,8 @@ class TpuCollModule(CollModule):
     def alltoallv_arr(self, comm, x, meta, capacity: int):
         """One rendezvous and one ``ompi_alltoallv_mesh`` program a
         call over ICI; every rank's counts and offsets reach it as an
-        int32 operand (coll/ragged.py)."""
+        int32 operand, put on the first rank's chip and spread by the
+        program (coll/ragged.py)."""
         if not self._ragged_eligible(comm, x):
             return self.fallback.alltoallv_arr(comm, x, meta, capacity)
         fn = comm.__dict__.get("_tpu_ragged")

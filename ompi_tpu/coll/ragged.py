@@ -49,6 +49,17 @@ key, on either provider:
     tile column, and moving one of its rows is a transpose whatever
     the body.
 
+  The operand crosses from the host ONCE a call, onto the first rank's
+  chip: the program's operand is ``(P * P, k, P)`` on the mesh, the
+  first chip's shard the meeting's matrix and every other chip's a
+  placeholder of zeros made once with the program and never written,
+  and the program's first step is an all-reduce of the P shards over
+  ICI (``mesh_program``), so every chip holds the matrix and takes its
+  own row.  A numpy operand would be sharded by the jit onto every chip
+  at every launch: P host-to-device transfers a call, each dear on the
+  host.  coll/hbm's one chip has no other chip to spread to and takes
+  its numpy operand as it is.
+
 The result buffers are never initialised: what lies outside the
 received blocks is not part of the result (MPI leaves it untouched).
 
@@ -90,6 +101,12 @@ pv_bytes = registry.register_pvar(
     help="Bytes the device-served alltoallv_arr rank-calls were asked to "
          "send: the sum of their scounts times the bytes of a row, not "
          "of any padded bound")
+pv_operand_puts = registry.register_pvar(
+    "coll", "alltoallv", "operand_puts",
+    help="Host-to-device transfers of the count operand of coll/tpu's "
+         "ompi_alltoallv_mesh: one a meeting, onto the first rank's "
+         "chip (the program spreads it to the others over ICI); none "
+         "for a meeting served through the host")
 
 #: the longest chunk of a block copy, in elements (PERF.md section 5)
 CHUNK = 1 << 19
@@ -609,9 +626,16 @@ def body(capacities):
 def mesh_program(mesh, capacity: int, sharding, slab: int = 0):
     """``ompi_alltoallv_mesh(meta, x)``: the ONE program of a ragged
     exchange among the P ranks of ``mesh``, one a chip: every rank's
-    buffer is its shard of ``x`` and its row of ``meta`` its offsets and
-    sizes, both on ``sharding``; the result is a ``(capacity, *row)``
-    shard a rank.  The program only moves bits.
+    buffer is its shard of ``x`` and its row of the operand its offsets
+    and sizes; the result is a ``(capacity, *row)`` shard a rank.  The
+    program only moves bits.
+
+    ``meta`` is ``(P * P, k, P)`` on ``sharding``, as ``x`` is: the
+    first chip's shard is the ``(P, k, P)`` operand, put there by the
+    host, and every other chip's is zeros (``spare_operand``).  The
+    program's first step is ONE all-reduce of those shards over ICI, so
+    every chip holds the operand without a transfer from the host of
+    its own, and takes its row.
 
     With ``slab`` (``slab_rows``'s t) it is the slab body, ``meta`` the
     ``slab_operand``: the buffer viewed as slabs of t rows (no copy on
@@ -645,7 +669,7 @@ def mesh_program(mesh, capacity: int, sharding, slab: int = 0):
                        capacity, interpret)
 
     def ompi_alltoallv_mesh(meta, x):
-        m = meta[0]
+        m = lax.psum(meta, "r")[lax.axis_index("r")]
         if slab:
             return slab_body(m, x)
         n, dtype = x.shape[0], x.dtype
@@ -666,6 +690,25 @@ def mesh_program(mesh, capacity: int, sharding, slab: int = 0):
                                  out_specs=P("r"), check_vma=False),
                    in_shardings=(sharding, sharding),
                    out_shardings=sharding)
+
+
+def put_operand(meta: np.ndarray, device):
+    """The one host-to-device transfer of a mesh meeting: the int32
+    operand onto ``device``, the first rank's chip, by its client's own
+    call (``jax.device_put`` of the same array cost 297 us of host time
+    against this call's 130 on a four-chip v5e host: PERF.md section
+    5)."""
+    return device.client.buffer_from_pyval(meta, device)
+
+
+def spare_operand(devices, k: int) -> list:
+    """The placeholders of ``mesh_program``'s operand, made once with
+    the program: a ``(P, k, P)`` int32 shard of zeros on every chip of
+    ``devices`` but the first.  The program reads them and adds nothing
+    with them; nothing writes or donates them."""
+    P = len(devices)
+    zeros = np.zeros((P, k, P), np.int32)
+    return [_x64.put(zeros, d, "alltoallv_arr") for d in devices[1:]]
 
 
 def _exchange(x, out, offsets, sizes, lands, takes):

@@ -11,7 +11,8 @@ mesh half's meeting (its operand against numpy formulas, one program
 for 40 count matrices, deposits of other shapes through the host) on
 CPU devices with its collective emulated, since XLA:CPU cannot lower
 ``ragged-all-to-all``; the slab body's rule, operand and Pallas pass on
-arrival (interpreted) against the host's answer; and both bodies
+arrival (interpreted) against the host's answer; the count operand's
+one transfer a meeting, onto the first rank's device; and both bodies
 compiled for a described 2x2 v5e."""
 
 import re
@@ -1132,6 +1133,124 @@ def test_overlapping_receive_blocks_meet_through_the_host(
     assert pvar("coll_alltoallv_slab_ops") == slabs
 
 
+# -- the mesh operand: one transfer from the host a meeting -------------------
+
+def spy_puts(monkeypatch):
+    """Every host array the library puts on a device, with the device,
+    in order: through runtime/x64.put, and the mesh meeting's operand
+    through ``ragged.put_operand``."""
+    from ompi_tpu.runtime import x64
+    puts = []
+
+    def spy(real):
+        def put(x, d, *what):
+            if not isinstance(x, jax.Array):
+                puts.append((np.array(x), d))
+            return real(x, d, *what)
+        return put
+
+    monkeypatch.setattr(x64, "put", spy(x64.put))
+    monkeypatch.setattr(ragged, "put_operand", spy(ragged.put_operand))
+    return puts
+
+
+@pytest.mark.parametrize("body", ["row", "slab"])
+def test_the_mesh_operand_crosses_once_a_meeting(body, mesh_emulated,
+                                                 monkeypatch):
+    """40 count matrices on 4 ranks through each body of the mesh
+    program: every answer equals the host's; ONE program built, with
+    its placeholders of zeros put once on every chip but the first;
+    then exactly one host array a meeting, the ``(P, k, P)`` int32
+    operand, put onto the first rank's chip, counted once a meeting by
+    ``coll_alltoallv_operand_puts``; nothing host-staged."""
+    if body == "slab":
+        monkeypatch.setattr(ragged, "layout_of", lambda x: row_major(8))
+    k = 7 if body == "slab" else 4
+    P, n, cap, calls = 4, 96, 384, 40
+    rng = np.random.default_rng(4600 + k)
+    mats = [random_counts(rng, P, n - 20, zeros=0.2 * (c % 3))
+            for c in range(calls)]
+    sds = [packed(m) + rng.integers(0, 5, (P, P)).cumsum(1) for m in mats]
+    xs = [rng.integers(0, 1 << 30, (n, 128)).astype(np.int32)
+          for _ in range(P)]
+    puts = spy_puts(monkeypatch)
+    builds = dev.compile_cache.builds
+    counted = pvar("coll_alltoallv_operand_puts")
+    staged = pvar("coll_arr_host_staged_collectives")
+    ops = pvar("coll_alltoallv_device_ops")
+    slabs = pvar("coll_alltoallv_slab_ops")
+
+    def fn(comm):
+        r = comm.rank
+        x = jax.device_put(xs[r], comm.device)
+        outs = [np.asarray(comm.alltoallv_arr(
+            x, m[r], m[:, r], sd[r], capacity=cap))
+            for m, sd in zip(mats, sds)]
+        return outs, comm.device
+
+    res = mesh_world(P, fn)
+    devices = [d for _outs, d in res]
+    assert dev.compile_cache.builds - builds == 1
+    assert pvar("coll_alltoallv_operand_puts") - counted == calls
+    assert pvar("coll_arr_host_staged_collectives") == staged
+    assert pvar("coll_alltoallv_device_ops") - ops == P * calls
+    assert pvar("coll_alltoallv_slab_ops") - slabs == (
+        P * calls if body == "slab" else 0)
+    spare = [(a, d) for a, d in puts if d != devices[0]]
+    assert [d for _a, d in spare] == devices[1:]
+    assert all(a.shape == (P, k, P) and a.dtype == np.int32
+               and not a.any() for a, _d in spare)
+    fresh = [a for a, d in puts if d == devices[0]]
+    assert len(fresh) == calls
+    assert all(a.shape == (P, k, P) and a.dtype == np.int32 and a.any()
+               for a in fresh)
+    for r, (outs, _d) in enumerate(res):
+        for m, sd, out in zip(mats, sds, outs):
+            rd = packed(m.T)
+            for i in range(P):
+                c = m[i][r]
+                assert np.array_equal(out[rd[r][i]:rd[r][i] + c],
+                                      xs[i][sd[i][r]:sd[i][r] + c])
+
+
+@pytest.mark.parametrize("why", ["overlapping-receive-blocks",
+                                 "other-lengths"])
+def test_a_meeting_through_the_host_puts_no_operand(why, mesh_emulated,
+                                                    monkeypatch):
+    """A meeting that the host serves (receive blocks that overlap, for
+    the slab body; deposits of other lengths, for any body) puts no
+    count operand on any chip: ``coll_alltoallv_operand_puts`` stays
+    where it was and no ``(P, k, P)`` int32 array reaches the first
+    rank's chip."""
+    monkeypatch.setattr(ragged, "layout_of", lambda x: row_major(8))
+    P, cap = 4, 16
+    rng = np.random.default_rng(4610)
+    lens = [72] * P if why == "overlapping-receive-blocks" \
+        else [72, 80, 72, 88]
+    xs = [rng.integers(0, 1 << 30, (n, 128)).astype(np.int32)
+          for n in lens]
+    sd = [1 + 17 * j for j in range(P)]
+    puts = spy_puts(monkeypatch)
+    counted = pvar("coll_alltoallv_operand_puts")
+    staged = pvar("coll_arr_host_staged_collectives")
+
+    def fn(comm):
+        out = comm.alltoallv_arr(
+            jax.device_put(xs[comm.rank], comm.device), [16] * P,
+            [16] * P, sd, [0] * P, capacity=cap)
+        assert comm.device in out.devices()
+        return np.asarray(out), comm.device
+
+    res = mesh_world(P, fn)
+    for r, (out, _d) in enumerate(res):
+        assert np.array_equal(out, xs[P - 1][sd[r]:sd[r] + 16])
+    assert pvar("coll_alltoallv_operand_puts") == counted
+    assert pvar("coll_arr_host_staged_collectives") - staged == P
+    first = res[0][1]
+    assert not [a for a, d in puts
+                if d == first and a.ndim == 3 and a.dtype == np.int32]
+
+
 # -- the mesh program, compiled here for a described 2x2 v5e (no chip) --------
 
 @pytest.fixture(scope="module")
@@ -1177,6 +1296,8 @@ MESH_SHAPES = {
 @pytest.mark.parametrize("case", sorted(MESH_SHAPES))
 def test_the_mesh_program_compiles_for_a_2x2_v5e(v5e_mesh, case):
     """The chip's compiler takes ``ompi_alltoallv_mesh`` for four chips:
+    its operand lowered at ``(P * P, k, P)``, ONE all-reduce of the
+    ``s32[4,k,4]`` operand (the spread of the first chip's counts) and
     ONE ragged-all-to-all over the four devices and no other collective,
     under the program's stable name, an 8-byte element as two 32-bit
     words.  The slab rule, read from the layout the chip gives the
@@ -1193,15 +1314,19 @@ def test_the_mesh_program_compiles_for_a_2x2_v5e(v5e_mesh, case):
                                (n, *row), dt.itemsize, n)
         assert got == t
         prog = ragged.mesh_program(mesh, n, sharding, t)
+        k = 7 if t else 4
         txt = prog.lower(
-            jax.ShapeDtypeStruct((4, 7 if t else 4, 4), jnp.int32,
+            jax.ShapeDtypeStruct((16, k, 4), jnp.int32,
                                  sharding=sharding),
             jax.ShapeDtypeStruct((4 * n, *row), dt,
                                  sharding=sharding)).compile().as_text()
     assert "jit_ompi_alltoallv_mesh" in txt
-    ops = re.findall(r"= \S+ (ragged-all-to-all|all-to-all|all-gather|"
+    ops = re.findall(r"= (\S+) (ragged-all-to-all|all-to-all|all-gather|"
                      r"all-reduce|collective-permute)\(", txt)
-    assert ops == ["ragged-all-to-all"]
+    assert sorted(op for _shape, op in ops) == [
+        "all-reduce", "ragged-all-to-all"], ops
+    spread = next(shape for shape, op in ops if op == "all-reduce")
+    assert spread.startswith("s32[4,%d,4]{" % k), spread
     assert "replica_groups={{0,1,2,3}}" in txt
     line = next(ln for ln in txt.splitlines() if " ragged-all-to-all(" in ln)
     assert line.split("=", 1)[1].strip().startswith(moved + "{"), line

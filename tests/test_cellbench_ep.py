@@ -414,13 +414,16 @@ def test_the_two_cells_are_on_the_lists_of_their_metrics():
           "entry_exit_us", "rdv_skew_us", "rdv_wake_us", "serve_us",
           "launch_us", "assemble_scatter_us", "caller_us", "rdv_per_iter",
           "unaccounted_us", "traced_iter_us", "ragged_ops_per_iter",
-          "ragged_bytes_per_iter", "ragged_slab_ops_per_iter"}
+          "ragged_bytes_per_iter", "ragged_slab_ops_per_iter",
+          "ragged_operand_puts_per_iter"}
     q = {"launch_s", "compile_or_load_s", "kernel_us", "device_idle_pct",
          "traced_iter_us", "collective_roofline", "fused_ops_per_iter"}
     assert {n for n, ws in lists.items() if EP in ws} == ep
     assert {n for n, ws in lists.items() if Q in ws} == q
     for name, pv in (("ragged_bytes_per_iter", "coll_alltoallv_bytes"),
                      ("ragged_slab_ops_per_iter", "coll_alltoallv_slab_ops"),
+                     ("ragged_operand_puts_per_iter",
+                      "coll_alltoallv_operand_puts"),
                      ("fused_ops_per_iter", "coll_device_fused_collectives")):
         spec = manifest.metric_spec(name, REPO)
         assert (spec["reader"], spec["pvars"]) == ("pvar_sum", [pv])
